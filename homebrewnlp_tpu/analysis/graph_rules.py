@@ -270,7 +270,7 @@ def _check_serve_donation(traces: ConfigTraces) -> typing.List[Finding]:
     and require their pooled arguments donated.  Without donation the
     decode loop copies the whole KV pool every step on device (the
     ROADMAP continuous-batching residual this rule ratchets)."""
-    from .trace import decode_traceable, trace_compat
+    from .trace import decode_traceable
     cfg = traces.cfg
     if not decode_traceable(cfg) or not traces.param_shapes:
         return []
@@ -313,20 +313,19 @@ def _check_serve_donation(traces: ConfigTraces) -> typing.List[Finding]:
                                                            n_lanes)
         dec_abs, pre_abs, chk_abs = engine.abstract_exec_args(cfg, params,
                                                               rows, n_lanes)
-        with trace_compat():
-            audits = (("decode", dec_jit.trace(*dec_abs),
-                       engine.DECODE_DONATE_ARGNUMS,
-                       engine.DECODE_DONATE_ARG_NAMES),
-                      ("prefill", pre_jit.trace(*pre_abs),
-                       engine.PREFILL_DONATE_ARGNUMS,
-                       engine.PREFILL_DONATE_ARG_NAMES))
-            if chk_jit is not None and chk_abs is not None:
-                # serve_prefill_chunk_tokens > 0: the chunk executable
-                # carries the same pooled state — audit it too (knob off
-                # keeps the audit, and the census goldens, byte-stable)
-                audits += (("prefill_chunk", chk_jit.trace(*chk_abs),
-                            engine.PREFILL_CHUNK_DONATE_ARGNUMS,
-                            engine.PREFILL_CHUNK_DONATE_ARG_NAMES),)
+        audits = (("decode", dec_jit.trace(*dec_abs),
+                   engine.DECODE_DONATE_ARGNUMS,
+                   engine.DECODE_DONATE_ARG_NAMES),
+                  ("prefill", pre_jit.trace(*pre_abs),
+                   engine.PREFILL_DONATE_ARGNUMS,
+                   engine.PREFILL_DONATE_ARG_NAMES))
+        if chk_jit is not None and chk_abs is not None:
+            # serve_prefill_chunk_tokens > 0: the chunk executable
+            # carries the same pooled state — audit it too (knob off
+            # keeps the audit, and the census goldens, byte-stable)
+            audits += (("prefill_chunk", chk_jit.trace(*chk_abs),
+                        engine.PREFILL_CHUNK_DONATE_ARGNUMS,
+                        engine.PREFILL_CHUNK_DONATE_ARG_NAMES),)
     except Exception as e:
         return findings + [Finding(
             "donation", "warning", _loc(traces, "serve"),
@@ -474,25 +473,12 @@ def check_quant_dtype(traces: ConfigTraces) -> typing.List[Finding]:
     return findings
 
 
-#: jax API names whose absence marks a known toolchain gap (older jax than
-#: the parallel modules target), as opposed to a real defect in model code
-_TOOLCHAIN_GAP_APIS = ("shard_map", "get_abstract_mesh", "pcast", "typeof",
-                       "pvary", "CompilerParams")
-
-
 def check_trace_errors(traces: ConfigTraces) -> typing.List[Finding]:
-    """Trace failures are findings too: severity depends on whether the
-    failure is a known toolchain gap (a specific missing jax API -> warning,
-    the config is simply not analyzable on this toolchain) or a real defect
-    (error)."""
-    findings: typing.List[Finding] = []
-    for step, err in sorted(traces.errors.items()):
-        toolchain = ("has no attribute" in err and any(
-            f"'{api}'" in err for api in _TOOLCHAIN_GAP_APIS))
-        findings.append(Finding(
-            "trace", "warning" if toolchain else "error",
-            _loc(traces, step), f"step failed to trace: {err}"))
-    return findings
+    """Trace failures are findings too: a step that does not trace on the
+    one supported toolchain is an error."""
+    return [Finding("trace", "error", _loc(traces, step),
+                    f"step failed to trace: {err}")
+            for step, err in sorted(traces.errors.items())]
 
 
 def _config_tpu_size(name: str) -> typing.Optional[int]:

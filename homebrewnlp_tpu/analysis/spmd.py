@@ -970,8 +970,7 @@ def compile_train_hlo(cfg) -> str:
     from ..parallel import make_mesh
     from ..parallel.sharding import spec_for
     from ..train.state import Trainer, TrainState
-    from .trace import _micro_sds, abstract_batch, abstract_params, \
-        trace_compat
+    from .trace import _micro_sds, abstract_batch, abstract_params
     n = max(1, int(cfg.tpu_size))
     mesh = make_mesh(cfg, devices=jax.devices()[:n], quiet=True)
     batch = abstract_batch(cfg)
@@ -1001,7 +1000,7 @@ def compile_train_hlo(cfg) -> str:
         rng.shape, rng.dtype,
         sharding=NamedSharding(mesh, PartitionSpec()))
     step = trainer._make_step()
-    with trace_compat(), mesh:
+    with mesh:
         compiled = step.trace(state, sbatch, rng,
                               *trainer.step_extra_args()).lower().compile()
     return compiled.as_text()

@@ -11,21 +11,11 @@ laptop.  The resulting :class:`StepTrace` bundles expose:
   ``jax.stages.ArgInfo`` for the step's arguments
 - ``mesh``       — the concrete mesh the step was traced under
 
-Toolchain compatibility: the pipeline/ring modules target the jax >= 0.8
-``jax.shard_map`` API (``axis_names=``, vma typing, ``jax.lax.pcast``).  On
-older toolchains those attributes are missing and the parallel-composed
-configs could not even be *traced* — so :func:`trace_compat` provides
-TRACE-ONLY shims (``jax.experimental.shard_map`` with ``auto=``, identity
-``pcast``) inside a restoring context manager.  The shims are sufficient for
-staging out the jaxpr and counting collectives; they are NOT numerically
-faithful for execution (untyped transpose semantics) and are never installed
-outside an active trace.  Census counts exclude the vma-typing bookkeeping
-primitives (``pvary``/``pbroadcast``) so goldens generated under the shims
-match newer toolchains.
+Census counts exclude the vma-typing bookkeeping primitives
+(``pvary``/``pbroadcast``): they move no data.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import typing
 
@@ -92,51 +82,6 @@ class ConfigTraces:
         dataclasses.field(default_factory=dict))
     slot_axes: typing.Dict[str, typing.Dict[str, typing.Tuple[str, ...]]] = (
         dataclasses.field(default_factory=dict))
-
-
-@contextlib.contextmanager
-def trace_compat():
-    """Install trace-only jax API shims for toolchains older than the
-    ``jax.shard_map`` / vma-typing surface the parallel modules target; a
-    no-op (beyond bookkeeping) when the real APIs exist.  Always restores."""
-    saved: typing.List[typing.Tuple[typing.Any, str, typing.Any, bool]] = []
-
-    def patch(obj, name, value):
-        saved.append((obj, name, getattr(obj, name, None), hasattr(obj, name)))
-        setattr(obj, name, value)
-
-    try:
-        if not hasattr(jax, "shard_map"):
-            from jax.experimental.shard_map import shard_map as _sm
-
-            def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                          axis_names=None, check_vma=None, **kw):
-                if mesh is None:
-                    from jax._src.mesh import thread_resources
-                    mesh = thread_resources.env.physical_mesh
-                auto = frozenset()
-                if axis_names is not None:
-                    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-                return _sm(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False, auto=auto)
-
-            patch(jax, "shard_map", shard_map)
-        if not hasattr(jax.lax, "pcast"):
-            patch(jax.lax, "pcast", lambda x, axes, to=None: x)
-        if not hasattr(jax, "typeof"):
-            patch(jax, "typeof", lambda x: jax.core.get_aval(x))
-        if not hasattr(jax.sharding, "get_abstract_mesh"):
-            class _NoManual:
-                manual_axes = ()
-
-            patch(jax.sharding, "get_abstract_mesh", lambda: _NoManual())
-        yield
-    finally:
-        for obj, name, old, existed in reversed(saved):
-            if existed:
-                setattr(obj, name, old)
-            else:
-                delattr(obj, name)
 
 
 def iter_eqns(jaxpr) -> typing.Iterator:
@@ -268,7 +213,7 @@ def trace_train(cfg: Config, mesh=None
     state = TrainState(params, opt_state,
                        jax.ShapeDtypeStruct((), jnp.int32))
     step = trainer._make_step()
-    with trace_compat(), mesh:
+    with mesh:
         # step_extra_args: telemetry-enabled configs take a grad_scale input
         traced = step.trace(state, batch, jax.random.key(0),
                             *trainer.step_extra_args())
@@ -302,7 +247,7 @@ def trace_eval(cfg: Config, params, mesh=None, axes=None) -> StepTrace:
         ctx = Ctx(cfg, params=p, train=False, rng=None, mesh=mesh)
         return build(ctx, b).loss
 
-    with trace_compat(), mesh:
+    with mesh:
         jaxpr = jax.make_jaxpr(eval_fn)(params, batch)
     in_axes = (_param_in_axes(params, axes or {})
                + _dict_axes(batch, lambda k: tuple(batch[k].names)))
@@ -332,9 +277,8 @@ def trace_prefill(cfg: Config, params, mesh=None, axes=None) -> StepTrace:
     def prefill(p, t):
         return _decode_logits(cfg, p, t, jnp.int32(0), {}, seq, names)
 
-    with trace_compat():
-        jaxpr = jax.make_jaxpr(prefill)(
-            params, jnp.zeros(toks.shape, toks.dtype))
+    jaxpr = jax.make_jaxpr(prefill)(
+        params, jnp.zeros(toks.shape, toks.dtype))
     in_axes = _param_in_axes(params, axes or {}) + [tuple(names)]
     return StepTrace("prefill", jaxpr, mesh,
                      in_axes=_check_in_axes(jaxpr, in_axes))
@@ -368,14 +312,13 @@ def trace_prefill_chunk(cfg: Config, params, mesh=None,
             cfg, p, jnp.zeros((1, 1, cfg.token_patch_size), jnp.int32),
             jnp.int32(0), {}, seq, names)[1]
 
-    with trace_compat():
-        caches = jax.eval_shape(probe, params)
+    caches = jax.eval_shape(probe, params)
 
-        def chunk_step(p, t, c):
-            return _decode_logits(cfg, p, t, jnp.int32(0), c, seq, names)
+    def chunk_step(p, t, c):
+        return _decode_logits(cfg, p, t, jnp.int32(0), c, seq, names)
 
-        jaxpr = jax.make_jaxpr(chunk_step)(
-            params, jnp.zeros(chunk.shape, chunk.dtype), caches)
+    jaxpr = jax.make_jaxpr(chunk_step)(
+        params, jnp.zeros(chunk.shape, chunk.dtype), caches)
     in_axes = (_param_in_axes(params, axes or {}) + [tuple(names)]
                + [None] * len(jax.tree_util.tree_leaves(caches)))
     return StepTrace("prefill_chunk", jaxpr, mesh,
@@ -399,13 +342,12 @@ def trace_decode(cfg: Config, params, mesh=None, axes=None) -> StepTrace:
         return _decode_logits(cfg, p, jnp.zeros(row.shape, row.dtype),
                               jnp.int32(0), {}, seq, names)[1]
 
-    with trace_compat():
-        caches = jax.eval_shape(probe, params)
+    caches = jax.eval_shape(probe, params)
 
-        def decode_step(p, r, c):
-            return _decode_logits(cfg, p, r, jnp.int32(1), c, seq, names)
+    def decode_step(p, r, c):
+        return _decode_logits(cfg, p, r, jnp.int32(1), c, seq, names)
 
-        jaxpr = jax.make_jaxpr(decode_step)(params, row, caches)
+    jaxpr = jax.make_jaxpr(decode_step)(params, row, caches)
     in_axes = (_param_in_axes(params, axes or {}) + [tuple(names)]
                + [None] * len(jax.tree_util.tree_leaves(caches)))
     return StepTrace("decode", jaxpr, mesh,

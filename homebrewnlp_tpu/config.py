@@ -294,10 +294,6 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     use_checkpointing=False,
     max_checkpoints_keep=1,
     model_path="runs/default",
-    # persistent XLA compilation cache directory (None = env var or per-user
-    # default, "" = disabled; consumed at the CLI/bench entry points via
-    # utils.enable_compilation_cache)
-    compilation_cache_dir=None,
     # serving codec for tools/train_tokenizer.py artifacts: when set, the
     # query/REST/sample text paths encode+decode through this tokenizer
     # (serve/interface.py::HbnlpBpeTokenizer) instead of bytes/GPT-2
@@ -549,12 +545,12 @@ class Config:
         if self.target_device:
             # a typoed device kind would silently skip the OOM-before-compile
             # gate; surface it at config load (devices.py is a leaf import)
-            from .devices import known_kinds, resolve_device
-            if resolve_device(self.target_device) is None:
-                raise ValueError(
-                    f"unknown target_device {self.target_device!r}; known "
-                    f"kinds: {', '.join(known_kinds())} (or \"\" to skip "
-                    f"the HBM capacity gate)")
+            from .devices import resolve_device
+            try:
+                resolve_device(self.target_device)
+            except ValueError as e:
+                raise ValueError(f"target_device: {e} (or \"\" to skip the "
+                                 f"HBM capacity gate)") from None
         if int(self.mesh_search_top_k) < 1:
             raise ValueError("mesh_search_top_k must be >= 1 (the rank the "
                              "hand-written mesh must reach in the searcher's "

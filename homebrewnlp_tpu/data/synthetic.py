@@ -55,14 +55,45 @@ def synthetic_video_batch(cfg: Config, step: int = 0, seed: int = 0
     return out
 
 
+def learnable_tokens(rng: np.random.Generator, n_tokens: int
+                     ) -> np.ndarray:
+    """``n_tokens`` bytes of a seeded toy language: words drawn Zipf-wise
+    from a fixed lexicon of 64 lowercase words, separated by spaces.  Unlike
+    uniform noise — which pins any model at ln(vocab) and makes a loss check
+    meaningless — its byte distribution is far from uniform (27 of 256
+    symbols) and its words repeat, so a byte-level model's loss falls below
+    ln(256) within a few updates and keeps falling."""
+    lex_rng = np.random.default_rng(0)  # the lexicon never changes
+    letters = np.arange(ord("a"), ord("z") + 1)
+    letter_p = 1.0 / np.arange(1, len(letters) + 1)
+    letter_p /= letter_p.sum()
+    words = [bytes(lex_rng.choice(letters, size=int(lex_rng.integers(2, 9)),
+                                  p=letter_p).tolist()) + b" "
+             for _ in range(64)]
+    word_p = 1.0 / np.arange(1, len(words) + 1)
+    word_p /= word_p.sum()
+    out = bytearray()
+    while len(out) < n_tokens:
+        for i in rng.choice(len(words), size=256, p=word_p):
+            out += words[i]
+    return np.frombuffer(bytes(out[:n_tokens]), np.uint8)
+
+
 def write_text_tfrecords(directory: str, n_files: int, records_per_file: int,
                          tokens_per_record: int, vocab: int = 256,
-                         seed: int = 0, int64: bool = False
+                         seed: int = 0, int64: bool = False,
+                         draw: typing.Optional[typing.Callable[
+                             [np.random.Generator, int], np.ndarray]] = None
                          ) -> typing.List[str]:
     """Write synthetic text shards; filenames carry the token count the way
     the reference's run-log replay expects (``..._<n_tokens>.tfrecord``,
-    inputs.py:34)."""
+    inputs.py:34).  ``draw(rng, n)`` supplies each record's tokens (default:
+    uniform noise over ``vocab``; :func:`learnable_tokens` for a stream a
+    model can learn)."""
     rng = np.random.default_rng(seed)
+    if draw is None:
+        def draw(rng, n):
+            return rng.integers(0, vocab, n)
     os.makedirs(directory, exist_ok=True)
     paths = []
     total = records_per_file * tokens_per_record
@@ -71,7 +102,7 @@ def write_text_tfrecords(directory: str, n_files: int, records_per_file: int,
         path = os.path.join(directory, f"shard{kind}{i:04d}_{total}.tfrecord")
         with RecordWriter(path) as w:
             for _ in range(records_per_file):
-                tokens = rng.integers(0, vocab, tokens_per_record)
+                tokens = draw(rng, tokens_per_record)
                 if int64:
                     w.write(encode_example({"text": [int(t) for t in tokens]}))
                 else:

@@ -247,8 +247,14 @@ def _train_loop(cfg, args, obs, grace) -> None:
                         prefetch=False)
         first_np = next(iter(probe))
     else:
-        color_print("no dataset files found; using synthetic data")
         first_np = synthetic_text_batch(cfg, 0)
+    # which source feeds the run is never silent: it is printed, and rides
+    # the run-start marker below so a reader of metrics.jsonl (the chip
+    # smoke) can refuse a run that fell back to noise
+    data_source = "dataset_files" if have_data else "synthetic"
+    color_print(f"data source: {data_source}"
+                + (f" ({[d['path'] for d in cfg.dataset_configs]})"
+                   if have_data else " (no dataset files found)"))
     template_gb = to_global(first_np, cfg, mesh)
     trainer, state, ckpt, data_state = _build_state(cfg, template_gb, mesh)
     if int(state.step) == 0 and cfg.current_step > 0:
@@ -327,7 +333,10 @@ def _train_loop(cfg, args, obs, grace) -> None:
     cfg_hash = config_hash(cfg)
     # Obs.identity is cfg-resolved (env overrides the dist_* knobs): the
     # marker must agree with the /healthz identity block
-    writer.write_run_start(step0, cfg_hash, identity=obs.identity)
+    writer.write_run_start(step0, cfg_hash, identity=obs.identity,
+                           data_source=data_source,
+                           mesh={k: int(v) for k, v in mesh.shape.items()},
+                           n_devices=n_avail)
     run_log = RunLog(cfg.model_path)
     # train_steps (and the step counter) count macro slices, reference
     # run.py:155,249: one optimizer update advances the counter by
@@ -651,15 +660,22 @@ def query(cfg, args) -> None:
     repl(cfg, _params_for_serving(cfg))
 
 
+def start_web_api(cfg, args):
+    """The server ``--run_mode web_api`` runs — restored or fresh-init params
+    behind ``serve.serve`` on a background thread — returned live, so the
+    chip smoke drives exactly what :func:`web_api` parks on."""
+    from .serve import serve as rest_serve
+    return rest_serve(cfg, _params_for_serving(cfg), port=args.port,
+                      obs_port=getattr(args, "obs_port", None),
+                      background=True)
+
+
 def web_api(cfg, args) -> None:
     import signal
     import threading
 
-    from .serve import serve as rest_serve
     print(f"serving on :{args.port}", flush=True)
-    server = rest_serve(cfg, _params_for_serving(cfg), port=args.port,
-                        obs_port=getattr(args, "obs_port", None),
-                        background=True)
+    server = start_web_api(cfg, args)
     grace = float(getattr(args, "grace_deadline_s", 30.0))
     stopped = threading.Event()
 
@@ -805,7 +821,7 @@ def main(argv: typing.Optional[typing.Sequence[str]] = None) -> None:
               f"{EXIT_PEER_LOST} for a lockstep fleet relaunch")
         raise SystemExit(EXIT_PEER_LOST) from e
     from .utils import enable_compilation_cache
-    enable_compilation_cache(cfg.compilation_cache_dir)
+    enable_compilation_cache()
     if args.debug_grad:
         cfg.debug_gradients = True
     if args.workers is not None:  # reference src/main.py:60

@@ -2,26 +2,42 @@
 
 Lazily builds the shared library with ``make -C native`` on first use (the
 reference ships equivalent compile_*.sh scripts for its Cython components)
-and degrades to pure-Python fallbacks when no toolchain is available.
+and falls back to the pure-Python implementations when no toolchain is
+available, logging once which of the two is in use.  The binary's file name
+carries a hash of its source, so a library built from another
+``hbnlp_native.cc`` is never loaded in its place.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
+import logging
 import os
 import subprocess
-import threading
 import typing
 
 import numpy as np
 
 from ..sync import make_lock
 
+LOG = logging.getLogger("homebrewnlp_tpu.native")
+
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO, "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libhbnlp_native.so")
+_SOURCE = os.path.join(_NATIVE_DIR, "hbnlp_native.cc")
 _lock = make_lock("native._lock")
 _lib: typing.Optional[ctypes.CDLL] = None
 _build_failed = False
+
+
+def lib_path() -> str:
+    """``native/libhbnlp_native.<sha256(source)[:12]>.so``: the binary is
+    tied to the source it was built from (the ``.so`` is git-ignored, so a
+    checkout can hold one built from an older ``.cc``)."""
+    with open(_SOURCE, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_NATIVE_DIR, f"libhbnlp_native.{digest}.so")
 
 
 def _load() -> typing.Optional[ctypes.CDLL]:
@@ -29,25 +45,37 @@ def _load() -> typing.Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_LIB_PATH):
+        path = lib_path()
+        if not os.path.exists(path):
             # build to a process-unique name then atomically rename, so
             # concurrent workers (tools/text2tfrecord.py pool) never load a
             # partially-written .so
-            tmp = f"{_LIB_PATH}.{os.getpid()}"
+            tmp = f"{path}.{os.getpid()}"
             try:
                 subprocess.run(
                     ["make", "-C", _NATIVE_DIR,
                      f"TARGET={os.path.basename(tmp)}"],
                     check=True, capture_output=True)
-                os.replace(tmp, _LIB_PATH)
-            except Exception:
+                os.replace(tmp, path)
+            except (OSError, subprocess.CalledProcessError) as e:
                 _build_failed = True
+                stderr = (getattr(e, "stderr", None) or b"").decode(
+                    errors="replace")
+                LOG.warning("native library build failed (%s) %s; using the "
+                            "pure-Python implementations", e, stderr[-300:])
                 return None
+            for stale in glob.glob(os.path.join(_NATIVE_DIR,
+                                                "libhbnlp_native*.so")):
+                if stale != path:  # built from a source that is gone
+                    os.remove(stale)
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+            lib = ctypes.CDLL(path)
+        except OSError as e:
             _build_failed = True
+            LOG.warning("native library %s failed to load (%s); using the "
+                        "pure-Python implementations", path, e)
             return None
+        LOG.info("native library in use: %s", path)
         lib.hb_crc32c.restype = ctypes.c_uint32
         lib.hb_crc32c.argtypes = [ctypes.c_char_p, ctypes.c_size_t]
         lib.hb_masked_crc.restype = ctypes.c_uint32

@@ -124,19 +124,31 @@ def mesh_factorizations(cfg: Config, n_devices: int,
 def make_mesh(cfg: Config,
               devices: typing.Optional[typing.Sequence[jax.Device]] = None,
               quiet: bool = False) -> Mesh:
+    """Mesh over ``devices`` (default: every device JAX sees).  When the
+    batch cannot shard over the data axis the axis drops to the largest
+    batch divisor and the surplus devices stay out of the mesh — which on an
+    accelerator is an error (a chip that is paid for and idle, and a
+    per-chip throughput divided by the wrong count) unless the caller
+    passes ``quiet`` and deals with the smaller mesh itself (the elastic
+    degraded resume, the static analysis).  The CPU test mesh only warns."""
     devices = list(devices if devices is not None else jax.devices())
     sizes = axis_sizes(cfg, len(devices), quiet=quiet)
     batch = cfg.train_batch_size
     if batch % sizes[DATA_AXIS]:
-        # the data axis cannot exceed what the batch can shard over; drop to
-        # the largest batch divisor and leave surplus devices out of the mesh
         data = max(d for d in range(1, sizes[DATA_AXIS] + 1)
                    if batch % d == 0)
         if not quiet:
-            print(f"WARNING: data axis shrunk from {sizes[DATA_AXIS]} to {data} "
-                  f"(train_batch_size={batch}); "
-                  f"{(sizes[DATA_AXIS] - data) * sizes[SEQ_AXIS] * sizes[PIPE_AXIS] * sizes[MODEL_AXIS]}"
-                  " device(s) left unused")
+            unused = ((sizes[DATA_AXIS] - data) * sizes[SEQ_AXIS]
+                      * sizes[PIPE_AXIS] * sizes[MODEL_AXIS])
+            msg = (f"data axis shrunk from {sizes[DATA_AXIS]} to {data} "
+                   f"(train_batch_size={batch}); {unused} device(s) left "
+                   f"unused")
+            if devices[0].platform != "cpu":
+                raise ValueError(
+                    f"{msg} — pick a batch the data axis divides (`python "
+                    f"tools/graftmesh.py --config <config> --world "
+                    f"{len(devices)}` searches layouts)")
+            print(f"WARNING: {msg}")
         sizes[DATA_AXIS] = data
     names = (DATA_AXIS, SEQ_AXIS, PIPE_AXIS, MODEL_AXIS)
     n_used = 1

@@ -141,16 +141,10 @@ def settings(cfg=None) -> typing.Optional[DistSettings]:
 
 def _jax_initialize(s: DistSettings) -> None:
     import jax
-    try:
-        jax.distributed.initialize(
-            s.coordinator, num_processes=s.num_processes,
-            process_id=s.process_id,
-            initialization_timeout=s.attempt_timeout_s)
-    except TypeError:
-        # older jax without the initialization_timeout kwarg
-        jax.distributed.initialize(
-            s.coordinator, num_processes=s.num_processes,
-            process_id=s.process_id)
+    jax.distributed.initialize(
+        s.coordinator, num_processes=s.num_processes,
+        process_id=s.process_id,
+        initialization_timeout=s.attempt_timeout_s)
 
 
 def initialize(cfg=None, *,

@@ -289,20 +289,15 @@ def aot_cache_key(cfg: Config, params: dict, n_lanes: int) -> str:
     from ..train.metrics import config_hash
     leaves = [f"{k}:{tuple(v.shape)}:{jnp.asarray(v).dtype}"
               for k, v in sorted(params.items())]
+    import jaxlib
     dev = jax.devices()[0]
-    try:
-        import jaxlib.version
-        jaxlib_v = jaxlib.version.__version__
-    except Exception:  # noqa: BLE001 - toolchain without the module
-        jaxlib_v = ""
     doc = json.dumps({
         "config": config_hash(cfg),
         "params": hashlib.sha256("|".join(leaves).encode()).hexdigest()[:16],
         "lanes": int(n_lanes),
-        "mesh": [dev.platform, getattr(dev, "device_kind", ""),
-                 jax.device_count()],
+        "mesh": [dev.platform, dev.device_kind, jax.device_count()],
         "jax": jax.__version__,
-        "jaxlib": jaxlib_v,
+        "jaxlib": jaxlib.__version__,
         "format": AOT_FORMAT,
     }, sort_keys=True, default=str)
     return hashlib.sha256(doc.encode()).hexdigest()[:24]
@@ -1002,10 +997,8 @@ class BatchEngine:
             out = np.asarray(self._toks[lane]).reshape(-1)[:req.end]
         rec = req.rec
         if req.tag:
-            try:  # flush the in-flight TTFT callback before unrouting
-                jax.effects_barrier()
-            except Exception:  # noqa: BLE001 - older toolchains
-                pass
+            # flush the in-flight TTFT callback before unrouting
+            jax.effects_barrier()
             slo.unregister_first_token(req.tag)
         # settle + engine-done BEFORE publishing (the stream close below or
         # the out-queue put): the waiting handler's finish() runs the

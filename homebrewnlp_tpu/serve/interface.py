@@ -383,10 +383,8 @@ class CompletionEngine:
                 rstream.flush_final(out[:end])
         finally:
             if tag:
-                try:  # flush any in-flight debug callback before unrouting
-                    jax.effects_barrier()
-                except Exception:  # noqa: BLE001 - older toolchains
-                    pass
+                # flush any in-flight debug callback before unrouting
+                jax.effects_barrier()
                 slo.unregister_first_token(tag)
                 if streaming:
                     slo.unregister_token_sink(tag)

@@ -510,12 +510,11 @@ def serve(cfg: Config, params: dict, host: str = "127.0.0.1",
         from ..obs import usage as usage_mod
         pricing = (usage_mod.price_serve_executables(cfg, params)
                    if params is not None else None)
-        try:
-            from ..analysis.cost_model import serve_capacity_ceiling
-            capacity = serve_capacity_ceiling()
-        except Exception:  # noqa: BLE001 - the ceiling is evidence
-            capacity = None
-        meter = usage_mod.UsageMeter(usage_top_k, capacity=capacity,
+        # an unknown accelerator raises here (devices.py): a capacity
+        # report priced from a guessed or missing peak is worse than none
+        from ..analysis.cost_model import serve_capacity_ceiling
+        meter = usage_mod.UsageMeter(usage_top_k,
+                                     capacity=serve_capacity_ceiling(),
                                      pricing=pricing)
         usage_registry = registry if registry is not None else REGISTRY
         usage_registry.register_collector(meter.prom_lines)
@@ -774,6 +773,7 @@ def serve(cfg: Config, params: dict, host: str = "127.0.0.1",
             LOG.debug("%s %s", self.address_string(), fmt % args)
 
     server = _ApiServer((host, port), Handler)
+    server.api = api  # whoever stops the server closes api.wrapper after it
     server.slo = serve_slo  # tests/bench read summaries off the live server
     server.usage = meter  # per-tenant usage meter (None when top_k=0)
     server._usage_collector = ((registry if registry is not None

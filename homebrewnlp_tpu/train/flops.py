@@ -24,26 +24,21 @@ from __future__ import annotations
 import dataclasses
 import typing
 
-#: Peak dense bf16 FLOP/s per chip, by device_kind substring (public specs).
-#: Order matters: more specific substrings first ("v5 lite" before "v5").
-PEAK_BF16: typing.Tuple[typing.Tuple[str, float], ...] = (
-    ("v6", 918e12), ("trillium", 918e12),
-    ("v5p", 459e12),
-    ("v5 lite", 197e12), ("v5e", 197e12), ("v5litepod", 197e12),
-    ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+#: Peak dense bf16 FLOP/s per chip (public specs), keyed by the canonical
+#: kinds of ``homebrewnlp_tpu/devices.py``.
+PEAK_BF16: typing.Dict[str, float] = {
+    "v6e": 918e12, "v5p": 459e12, "v5e": 197e12,
+    "v4": 275e12, "v3": 123e12, "v2": 45e12,
+}
 
 
 def peak_flops(device_kind: str) -> typing.Optional[float]:
-    """Per-chip peak bf16 FLOP/s, or None for CPU/unknown (no MFU claim)."""
-    kind = device_kind.lower()
-    for sub, peak in PEAK_BF16:
-        if sub in kind:
-            return peak
-    return None
+    """Per-chip peak bf16 FLOP/s for a runtime ``device_kind`` or a
+    canonical kind, by exact match (``devices.py::canonical_kind``): None
+    on ``"cpu"`` (no MFU claim), an error for any unknown kind."""
+    from ..devices import canonical_kind
+    kind = canonical_kind(device_kind)
+    return PEAK_BF16[kind] if kind else None
 
 
 def eqn_dot_flops(eqn) -> float:

@@ -105,12 +105,15 @@ class MetricWriter:
         return self._productive_s / wall if wall > 0 else 0.0
 
     def write_run_start(self, resume_step: int, cfg_hash: str,
-                        identity: typing.Optional[dict] = None) -> None:
+                        identity: typing.Optional[dict] = None,
+                        **run_facts) -> None:
         """Run boundary marker: ``metrics.jsonl`` appends across restarts, so
         every run begins with ``{"run_start": true, resume_step,
         config_hash, wall_time}`` plus the fleet identity (rank /
         world_size / coordinator / generation — obs/fleet.py) so the file
-        itself says which host of which fleet generation wrote it.
+        itself says which host of which fleet generation wrote it, plus
+        the caller's ``run_facts`` (main.py: ``data_source``, ``mesh``,
+        ``n_devices``).
         ``identity``: the caller's cfg-resolved identity (main.py passes
         ``Obs.identity``) so config-driven multi-host runs — env vars
         unset, dist_* knobs set — record the same rank /healthz reports;
@@ -118,7 +121,8 @@ class MetricWriter:
         read metric rows must skip records without a ``"loss"``/``"step"``
         key (bench.py's guard and the test helpers do)."""
         doc = {"run_start": True, "resume_step": int(resume_step),
-               "config_hash": cfg_hash, "wall_time": time.time()}
+               "config_hash": cfg_hash, "wall_time": time.time(),
+               **run_facts}
         ident = identity if identity is not None else fleet.identity()
         doc["rank"] = ident["rank"]
         doc["world_size"] = ident["world_size"]
@@ -248,9 +252,10 @@ class AsyncMetricWriter:
             "wall seconds blocked in the device->host metric pull per step")
 
     def write_run_start(self, resume_step: int, cfg_hash: str,
-                        identity: typing.Optional[dict] = None) -> None:
+                        identity: typing.Optional[dict] = None,
+                        **run_facts) -> None:
         self.writer.write_run_start(resume_step, cfg_hash,
-                                    identity=identity)
+                                    identity=identity, **run_facts)
 
     def set_utilization(self, util,
                         run_start: typing.Optional[float] = None) -> None:

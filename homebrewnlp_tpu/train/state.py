@@ -294,13 +294,10 @@ class Trainer:
         args = (state, batch, rng) + self.step_extra_args(grad_scale)
         if self._compiled is not None:
             # AOT executable from step_cost_analysis (jit's dispatch cache is
-            # separate, so calling the jit fn would compile a second time)
-            try:
-                return self._compiled(*args)
-            except (TypeError, ValueError):
-                # shapes/dtypes/shardings changed since the AOT compile —
-                # the exact exception type varies by jax version
-                self._compiled = None
+            # separate, so calling the jit fn would compile a second time).
+            # Arguments that no longer match it raise: falling back to jit
+            # here would hide a second compile inside a timed window
+            return self._compiled(*args)
         with self.mesh:
             return self._step_fn(*args)
 
@@ -317,10 +314,7 @@ class Trainer:
             self._compiled = self._step_fn.lower(
                 state, batch, jax.random.key(0),
                 *self.step_extra_args()).compile()
-        cost = self._compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns per-device list
-            cost = cost[0] if cost else {}
-        return dict(cost or {})
+        return dict(self._compiled.cost_analysis() or {})
 
     # -- reporting -----------------------------------------------------------
     def param_census(self, params: typing.Dict[str, jnp.ndarray]

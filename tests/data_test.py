@@ -594,8 +594,8 @@ def test_metric_writer_scalars_and_histograms(tmp_path):
 
 
 def test_bench_guard_threshold_logic():
-    """bench.evaluate_guard: 10k-acceptance-record thresholds at full
-    length (docs/perf/32ctx_10k_run.md: 7.71 -> 3.45@100 -> 2.76@300),
+    """bench.evaluate_guard: the 10k-step acceptance run's thresholds at
+    full length (7.71 -> 3.45@100 -> 2.76@300, bench.py docstring),
     reach-what-you-ran semantics for short development runs."""
     import os
     import sys
@@ -614,8 +614,8 @@ def test_bench_guard_threshold_logic():
     assert not evaluate_guard(rows([(1, 7.77), (50, 7.9)]), 50)["pass"]
     # bad init (loaded checkpoint instead of fresh) -> fail
     assert not evaluate_guard(rows([(1, 3.0), (300, 2.5)]), 300)["pass"]
-    # the LR-0.01 instability signature (docs/perf/32ctx_real_run.md:
-    # regression toward 5-8 after warmup) -> fail at full length
+    # the LR-0.01 instability signature (regression toward 5-8 after
+    # warmup, bench.py docstring) -> fail at full length
     stalled = rows([(1, 7.77), (120, 5.7), (300, 5.7)])
     assert not evaluate_guard(stalled, 300)["pass"]
     # stalls above the 300-step bar -> fail

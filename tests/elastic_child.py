@@ -37,14 +37,9 @@ def main() -> None:
     jax.config.update("jax_platforms", "cpu")
     from tests.backend import tiny_config
     from homebrewnlp_tpu import main as cli
-    # compilation_cache_dir="": fresh-process checkpoint resume can
-    # segfault on some jax builds when deserializing a persistently-cached
-    # executable (docs/reliability.md "Troubleshooting") — the drill tests
-    # the fleet protocol, not the XLA cache
     cfg = tiny_config(model_path=args.model_path, use_checkpointing=True,
                       steps_per_checkpoint=2, fault_plan=args.fault_plan,
-                      grace_deadline_s=60.0, compilation_cache_dir="",
-                      obs_spans=args.obs_spans)
+                      grace_deadline_s=60.0, obs_spans=args.obs_spans)
     cli.train(cfg, argparse.Namespace(steps=args.steps, profile="",
                                       workers=None))
 

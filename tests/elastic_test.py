@@ -787,10 +787,11 @@ def test_cli_inits_distributed_and_drills_dist_init_fault(tmp_path):
                                         "feed_forward-in:relu"]}],
                model_path=str(tmp_path / "run"),
                dist_coordinator=f"127.0.0.1:{port}", dist_num_processes=1,
-               fault_plan="dist_init:fail@1", compilation_cache_dir="")
+               fault_plan="dist_init:fail@1")
     cfg_path = tmp_path / "cfg.json"
     cfg_path.write_text(json.dumps(cfg))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="false")
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "main.py"), "--model",
          str(cfg_path), "--run_mode", "train", "--steps", "2"],

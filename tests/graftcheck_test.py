@@ -764,31 +764,7 @@ def test_ast_bare_io_repo_is_clean():
     assert json.load(open(ast_rules.bare_io_golden_path())) == {}
 
 
-# -- trace_compat shims (ISSUE 7 satellite) ----------------------------------
-
-def test_trace_compat_uninstalls_after_midcontext_raise():
-    """The trace-only jax API shims must be gone after an exception inside
-    the context — a half-traced config must never leave patched jax state
-    behind for the rest of the process."""
-    before = {name: (hasattr(obj, name), getattr(obj, name, None))
-              for obj, name in ((jax, "shard_map"), (jax.lax, "pcast"),
-                                (jax, "typeof"),
-                                (jax.sharding, "get_abstract_mesh"))}
-    with pytest.raises(RuntimeError, match="boom"):
-        with atrace.trace_compat():
-            # inside the context every shimmed surface exists
-            assert hasattr(jax, "shard_map")
-            assert hasattr(jax.lax, "pcast")
-            assert hasattr(jax, "typeof")
-            assert hasattr(jax.sharding, "get_abstract_mesh")
-            raise RuntimeError("boom")
-    for (obj, name), (had, val) in zip(
-            ((jax, "shard_map"), (jax.lax, "pcast"), (jax, "typeof"),
-             (jax.sharding, "get_abstract_mesh")), before.values()):
-        assert hasattr(obj, name) == had, name
-        if had:
-            assert getattr(obj, name) is val, name
-
+# -- census normalization -----------------------------------------------------
 
 def test_collective_prims_cover_both_toolchain_spellings():
     """Census normalization: the typed-shard_map toolchain spellings and the

@@ -773,7 +773,6 @@ def _drill_cfg(tmp_path) -> str:
         serve_max_batch=3, use_checkpointing=False,
         watchdog_factor=3.0, serve_watchdog_min_stall_s=1.0,
         model_path=str(tmp_path / "model"),
-        compilation_cache_dir=str(tmp_path / "jitcache"),
     )
     path = tmp_path / "cfg.json"
     path.write_text(json.dumps(raw))
@@ -806,7 +805,8 @@ def test_chaos_drill_replica_die_behind_router(tmp_path):
     cfg_path = _drill_cfg(tmp_path)
     base_port, obs_port = _free_port(), _free_port()
     router_port = _free_port()
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jitcache"))
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "tools", "graftserve.py"),
          "--model", cfg_path, "--replicas", "2",

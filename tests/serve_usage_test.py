@@ -658,13 +658,13 @@ def test_usage_drill_replica_die_exact_reconciliation(tmp_path):
         serve_max_batch=3, use_checkpointing=False,
         watchdog_factor=3.0, serve_watchdog_min_stall_s=1.0,
         model_path=str(tmp_path / "model"),
-        compilation_cache_dir=str(tmp_path / "jitcache"),
     )
     cfg_path = tmp_path / "cfg.json"
     cfg_path.write_text(json.dumps(raw))
     base_port, obs_port, router_port = (_free_port(), _free_port(),
                                         _free_port())
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jitcache"))
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "tools", "graftserve.py"),
          "--model", str(cfg_path), "--replicas", "2",

@@ -236,9 +236,9 @@ def test_flops_reconcile_with_bench_cost_analysis(eight_devices):
 
 def test_peak_flops_table():
     from homebrewnlp_tpu.train.flops import peak_flops
-    assert peak_flops("TPU v5e") == 197e12
-    assert peak_flops("TPU v5p") == 459e12
-    assert peak_flops("TPU v5 lite") == 197e12  # specific beats generic
+    assert peak_flops("TPU v5 lite") == 197e12  # the runtime's own string
+    assert peak_flops("v5e") == 197e12  # canonical target_device names
+    assert peak_flops("v5p") == 459e12
     assert peak_flops("cpu") is None
 
 

@@ -36,12 +36,9 @@ def _parse_variant(spec: str) -> dict:
 
 def run(config: str, batch: int, knobs: dict) -> dict:
     from homebrewnlp_tpu.train import Trainer
-    from homebrewnlp_tpu.utils import load_config, random_text_batch
+    from homebrewnlp_tpu.utils import one_chip_config, random_text_batch
 
-    cfg = load_config(f"configs/{config}.json", use_checkpointing=False,
-                      calc_accuracy=False, tpu_size=1,
-                      slice_dtype="bfloat16", train_batch_size=batch,
-                      **knobs)
+    cfg = one_chip_config(config, train_batch_size=batch, **knobs)
     trainer = Trainer(cfg)
     batch_d = random_text_batch(cfg)
     state = trainer.init(batch_d)
@@ -83,7 +80,7 @@ def main() -> None:
     ap.add_argument("--variant", action="append", required=True,
                     help="comma-separated knob=value list; one run each")
     args = ap.parse_args()
-    enable_compilation_cache(None)
+    enable_compilation_cache()
     for spec in args.variant:
         print(json.dumps(run(args.config, args.batch, _parse_variant(spec))),
               flush=True)

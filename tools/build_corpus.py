@@ -1,5 +1,5 @@
 """Reproduce the in-image real-text corpus used by the 32ctx acceptance run
-(docs/perf/32ctx_real_run.md): walks deterministic source/doc roots inside
+(configs/32ctx_accept_10k.json): walks deterministic source/doc roots inside
 the image (natural-language-rich .py/.rst/.md/.txt), concatenates up to a
 byte budget, splits into N parts, and shards them with text2tfrecord.
 

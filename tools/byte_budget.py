@@ -107,7 +107,8 @@ def layer_rows(cfg, shape, cfg_fused=None) -> dict:
 
 
 def main() -> None:
-    from homebrewnlp_tpu.utils import (enable_compilation_cache, load_config,
+    from homebrewnlp_tpu.utils import (ONE_CHIP_COMMON,
+                                       enable_compilation_cache, load_config,
                                        random_text_batch)
     from homebrewnlp_tpu.train import Trainer
 
@@ -118,10 +119,9 @@ def main() -> None:
                     help="layer rows only (no full-step compiles)")
     args = ap.parse_args()
 
-    common = dict(train_batch_size=args.batch, use_checkpointing=False,
-                  calc_accuracy=False, tpu_size=1, slice_dtype="bfloat16")
+    common = dict(ONE_CHIP_COMMON, train_batch_size=args.batch)
     cfg = load_config(args.config, **common)
-    enable_compilation_cache(cfg.compilation_cache_dir)
+    enable_compilation_cache()
 
     out = {"config": args.config, "batch": args.batch,
            "device": jax.devices()[0].device_kind}

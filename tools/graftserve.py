@@ -8,7 +8,9 @@ them, and keep the set alive:
 
 - **spawn** — replica i serves on ``--base-port + i`` with its /healthz
   exporter on ``--base-obs-port + i``; the router health-gates on the
-  latter.  ``--fault-plan i:PLAN`` injects a chaos plan
+  latter.  On a TPU host every replica is pinned to its own chip
+  (``replica_chip_envs``), and more replicas than chips is refused at
+  start.  ``--fault-plan i:PLAN`` injects a chaos plan
   (``HBNLP_FAULT_PLAN``, reliability/faults.py) into exactly one replica —
   how the chaos-serve drill kills replica 0 mid-run.
 - **health-watch + relaunch** — a dead replica (child exit) relaunches
@@ -200,6 +202,44 @@ class ReplicaSupervisor:
                 return
 
 
+def host_tpu_chips() -> int:
+    """TPU chips on this host, counted WITHOUT jax — the supervisor must
+    never hold a chip, and a process that touched JAX does.  The installed
+    runtime exposes one ``/dev/vfio/<n>`` device per chip (one entry on the
+    one-chip v5e machine, ``0``..``3`` on the 2x2 host, where two children
+    pinned this way each opened their own chip at once: chip runs of
+    PR 21)."""
+    try:
+        return sum(1 for name in os.listdir("/dev/vfio") if name.isdigit())
+    except OSError:
+        return 0
+
+
+def replica_chip_envs(n_replicas: int, environ: typing.Mapping[str, str],
+                      n_chips: typing.Optional[int] = None
+                      ) -> typing.List[typing.Dict[str, str]]:
+    """Per-replica environment additions that give every replica its OWN
+    chip.  A chip belongs to one process at a time, so N children started
+    with the parent's environment all reach for the same device: the first
+    wins and the relaunch loop retries the rest for ever.  Each replica is
+    pinned to one chip through libtpu's own variables; when the host has
+    fewer chips than replicas the fleet refuses to start.  On the CPU
+    platform (``JAX_PLATFORMS=cpu``, or no chip on the host) replicas share
+    the host and nothing is pinned."""
+    platforms = environ.get("JAX_PLATFORMS", "")
+    chips = host_tpu_chips() if n_chips is None else n_chips
+    if platforms.split(",")[0] == "cpu" or chips == 0:
+        return [{} for _ in range(n_replicas)]
+    if n_replicas > chips:
+        raise SystemExit(
+            f"graftserve: {n_replicas} replicas need {n_replicas} chips, "
+            f"this host has {chips} — one replica per chip (a second "
+            f"process cannot open a chip the first one holds)")
+    return [{"TPU_VISIBLE_CHIPS": str(i),
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_BOUNDS": "1,1,1"} for i in range(n_replicas)]
+
+
 def build_replica_cmd(cfg_path: str, port: int, obs_port: int
                       ) -> typing.List[str]:
     return [sys.executable, os.path.join(REPO, "main.py"),
@@ -255,6 +295,7 @@ def main(argv=None) -> int:
                          max_delay_s=args.backoff_max)
     replicas = []
     sups: typing.List[ReplicaSupervisor] = []
+    chip_envs = replica_chip_envs(args.replicas, os.environ)
     for i in range(args.replicas):
         port = args.base_port + i
         obs_port = args.base_obs_port + i
@@ -262,7 +303,7 @@ def main(argv=None) -> int:
         obs_url = f"http://127.0.0.1:{obs_port}"
         replicas.append(router_mod.Replica(url, obs_url,
                                            name=f"replica{i}"))
-        env = dict(os.environ)
+        env = {**os.environ, **chip_envs[i]}
         if i in plans:
             env["HBNLP_FAULT_PLAN"] = plans[i]
         fleet = (FleetCoordinator(args.fleet_dir, rank=i,

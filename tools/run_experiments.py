@@ -5,8 +5,9 @@ config entries (:62-75), one JSON config + run name per grid point (:78-93),
 then launch each run (:99-125).  The reference hardcodes preemptible-TPU
 creation through ``gcloud compute tpus create`` inside ``screen``; here the
 launch command is a template (``--launch-cmd``) so the same sweep runs
-locally, under tmux, or against any cloud CLI — the gcloud/screen recipe is
-the documented default template.
+locally, under tmux, or against any cloud CLI.  The local default runs the
+grid points one after another (one process per chip); a cloud template that
+creates a machine per run starts them all at once.
 
 Usage:
   python tools/run_experiments.py --base configs/32ctx_mixer.json \
@@ -22,6 +23,7 @@ import json
 import os
 import subprocess
 
+LOCAL_TEMPLATE = "python3 main.py --model {config} --run_mode train"
 GCLOUD_TEMPLATE = (
     "gcloud compute tpus create {name} --zone europe-west4-a --range {cidr} "
     "--accelerator-type v3-8 --version tpu-vm-tf-2.x --preemptible && "
@@ -43,9 +45,11 @@ def main() -> None:
     ap.add_argument("--grid", action="append", default=[],
                     help="key=v1,v2,... (repeatable); meshgrid over all")
     ap.add_argument("--out-dir", required=True)
-    ap.add_argument("--launch-cmd",
-                    default="python3 main.py --model {config} --run_mode train",
+    ap.add_argument("--launch-cmd", default=LOCAL_TEMPLATE,
                     help="command per run; {config}/{name}/{cidr} substituted."
+                         " The local default runs the grid points one after"
+                         " another (they share this host's chips); any other"
+                         " command is started for all points at once."
                          f" gcloud recipe: {GCLOUD_TEMPLATE!r}")
     ap.add_argument("--cidr-base", default="10.48", help="first two CIDR "
                     "octets for TPU ranges (reference :78-93)")
@@ -78,7 +82,13 @@ def main() -> None:
         cmd = args.launch_cmd.format(config=cfg_path, name=f"sweep-{run_idx}",
                                      cidr=cidr)
         print(("LAUNCH " if args.execute else "would launch ") + cmd)
-        if args.execute:
+        if not args.execute:
+            continue
+        if args.launch_cmd == LOCAL_TEMPLATE:
+            # a chip belongs to one process at a time: a second local run
+            # started beside the first fails or hangs at device init
+            subprocess.run(cmd, shell=True)
+        else:
             procs.append(subprocess.Popen(cmd, shell=True))
     for p in procs:
         p.wait()
