@@ -1,0 +1,10 @@
+"""`group_linear_ms`: device time of `bottleneck_group_linear`'s instructions,
+every pass, per update (`scope_time.py`; the scopes are in the data file
+beside this one)."""
+import scope_time
+
+UNIT = "ms"
+
+
+def read(run: dict):
+    return scope_time.ms_per_update(run, __file__)

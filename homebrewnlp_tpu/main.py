@@ -181,6 +181,9 @@ def _finalize_profile(cfg, args, trainer, obs) -> None:
             f"mxu {d.get('mxu', 0.0):.3f} + hbm {d.get('hbm', 0.0):.3f} + "
             f"comm {d.get('comm', 0.0):.3f} + idle {d.get('idle', 0.0):.3f} "
             f"(scope coverage {summary.attributed_scope_frac:.0%}) -> {path}")
+        for line in profile_mod.layer_pass_table(summary.layer_pass_s,
+                                                 cfg.profile_steps):
+            color_print(line)
     except Exception as e:  # noqa: BLE001
         color_print(f"graftprof summary write failed: {e}")
         return

@@ -18,6 +18,14 @@ module turns the capture into machine-checkable numbers:
   (:data:`OP_MAP_FILENAME`) next to the trace at ``stop_trace`` time, and
   the parser joins trace events against it — per-layer device time
   without a TPU-side dependency.
+- **the train step's scope grammar**: :func:`step_scope` turns one
+  ``op_name`` into ``(pass, block, layer)`` — which layer of which block,
+  and whether the instruction runs in the chain's forward, the reversible
+  backward's replay, the remat recompute or the backward proper.  On a TPU
+  the profiler's own ``.xplane.pb`` carries each instruction's ``op_name``
+  (:func:`xplane_op_names`), so the layer x pass table needs no sidecar
+  there; the benchmark's per-scope metrics (``benchmark/scope_time.py``)
+  read the same two functions.
 - **an ms_per_step decomposition** into ``mxu + hbm + comm + idle`` that
   sums to the device wall window, reconciled against graftcost's static
   alpha-beta / roofline estimates (``analysis/cost_model.py``) as
@@ -40,6 +48,7 @@ self-time.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import glob
 import gzip
 import json
@@ -159,14 +168,24 @@ _WRAP_RE = re.compile(r"^(%s)\((.*)\)$" % "|".join(_WRAPPERS))
 _JIT_HEAD_RE = re.compile(r"^(jit|pjit)\(.*\)$")
 
 
+#: path components that jax.checkpoint itself writes into ``op_name`` (the
+#: lowering's ``checkpoint`` and the transpose's ``rematted_computation``):
+#: machinery, never a model scope.  :func:`step_scope` reads the pass from
+#: them in the raw string; the scope path drops them
+_REMAT_COMPONENTS = ("checkpoint", "rematted_computation")
+
+
 def _collapse_repeat(parts: typing.Tuple[str, ...]
                      ) -> typing.Tuple[str, ...]:
-    """Collapse a doubled leading run: ``gpt/body/gpt/body/d0_0`` ->
+    """Collapse a repeated leading run: ``gpt/body/gpt/body/d0_0`` ->
     ``gpt/body/d0_0``.  Per-block sub-builds re-enter their full preset
     scope path (models/ctx.py::_PresetScope) while the outer build's
     jax name-stack entries are still open, so compiled metadata carries
-    the prefix twice; the parameter path is the single-run form."""
-    parts = tuple(parts)
+    the prefix twice; the parameter path is the single-run form.  Under
+    ``jax.checkpoint`` the prefix comes once more behind the lowering's own
+    component (``gpt/body/checkpoint/gpt/body/d0_0``): those components
+    go first, so that every pass of one block resolves to one path."""
+    parts = tuple(p for p in parts if p not in _REMAT_COMPONENTS)
     changed = True
     while changed and parts:
         changed = False
@@ -175,6 +194,16 @@ def _collapse_repeat(parts: typing.Tuple[str, ...]
                 parts = parts[i:]
                 changed = True
                 break
+    return parts
+
+
+def _op_name_parts(op_name: str) -> typing.List[str]:
+    """Components of one ``op_name`` behind the leading ``jit(...)``
+    machinery.  XLA joins the names of merged instructions with ``;``: the
+    first one stands for the instruction."""
+    parts = [p for p in op_name.split(";")[0].split("/") if p]
+    while parts and _JIT_HEAD_RE.match(parts[0]):
+        parts.pop(0)
     return parts
 
 
@@ -188,11 +217,8 @@ def scope_of_op_name(op_name: str) -> typing.Tuple[str, ...]:
         jit(step)/jit(main)/transpose(jvp(body))/layer0/ffn/dot_general
         -> ("body", "layer0", "ffn")
     """
-    parts = [p for p in op_name.split("/") if p]
-    while parts and _JIT_HEAD_RE.match(parts[0]):
-        parts.pop(0)
     out: typing.List[str] = []
-    for p in parts:
+    for p in _op_name_parts(op_name):
         m = _WRAP_RE.match(p)
         while m:
             p = m.group(2)
@@ -200,6 +226,149 @@ def scope_of_op_name(op_name: str) -> typing.Tuple[str, ...]:
         if p:
             out.append(p)
     return _collapse_repeat(tuple(out[:-1]))  # last component = primitive
+
+
+# -- the train step's scope grammar: op_name -> (pass, block, layer) ----------
+
+#: which run of the model an instruction belongs to (order = table order)
+PASSES = ("forward", "replay", "remat", "backward", "optimizer", "other")
+#: layers that the benchmark's metrics and the operator's table name; an nd
+#: layer scope outside :data:`_LAYER_OF_TOKEN` reports under its own name
+LAYERS = ("norm", "group_linear", "map", "body", "input", "output", "loss",
+          "optimizer", "other")
+OTHER = "other"
+
+_BLOCK_RE = re.compile(r"^d\d+_\d+$")
+_BLOCK_SCOPE_RE = re.compile(r"^block_\d*$")
+#: nd gives every layer scope a use counter: ``norm_``, ``norm_1``
+_COUNTER_RE = re.compile(r"_\d*$")
+_LAYER_OF_TOKEN = {"norm": "norm",
+                   "bottleneck_group_linear": "group_linear",
+                   "attention": "map", "activation": "map"}
+_TRANSFORM_RE = re.compile(r"^(jvp|transpose)\(")
+
+
+def _pass_of_parts(parts: typing.Sequence[str]) -> str:
+    if not parts:
+        return OTHER
+    if parts[0] == "optimizer":
+        return "optimizer"
+    outer = _TRANSFORM_RE.match(parts[0])
+    if outer is None:
+        return OTHER
+    if outer.group(1) == "jvp":
+        return "forward"
+    if "rematted_computation" in parts:
+        return "remat"
+    for p in parts[1:]:
+        inner = _TRANSFORM_RE.match(p)
+        if inner is not None:
+            return "replay" if inner.group(1) == "jvp" else "backward"
+    return "backward"
+
+
+def _layer_of_scope(scope: typing.Sequence[str]
+                    ) -> typing.Tuple[typing.Optional[str], str]:
+    if not scope:
+        return None, OTHER
+    if scope[0] == "optimizer":
+        return None, "optimizer"
+    at = next((i for i, p in enumerate(scope) if _BLOCK_RE.match(p)), None)
+    if at is None:
+        top = scope[1] if len(scope) > 1 else ""
+        return None, top if top in ("input", "output", "loss", "body") \
+            else OTHER
+    inside = [p for p in scope[at + 1:] if not _BLOCK_SCOPE_RE.match(p)]
+    if not inside or not _COUNTER_RE.search(inside[0]):
+        # no nd layer scope: the block's own instructions
+        return scope[at], "map"
+    base = _COUNTER_RE.sub("", inside[0])
+    return scope[at], _LAYER_OF_TOKEN.get(base, base)
+
+
+@functools.lru_cache(maxsize=1 << 16)  # a step has a few thousand names
+def step_scope(op_name: str
+               ) -> typing.Tuple[str, typing.Optional[str], str]:
+    """``(pass_, block, layer)`` of one instruction of the compiled train
+    step, from its ``metadata.op_name`` alone.
+
+    ``layer``: the nd layer scope under ``d<i>_<j>/block_`` with its use
+    counter dropped and folded by :data:`_LAYER_OF_TOKEN` (``norm_1`` ->
+    ``norm``, ``bottleneck_group_linear_`` -> ``group_linear``,
+    ``attention_`` / ``activation_`` -> ``map``; any other layer scope keeps
+    its own name); ``optimizer``; ``input`` / ``output`` / ``loss``;
+    ``body`` for the reversible chain's own glue between blocks (residual
+    sums, the cotangent squash); ``other`` for step-level glue with no
+    model scope (gradient norm, clipping, argument copies).  Whatever sits
+    directly under ``block_`` with no layer scope of its own is ``map``:
+    only a fused block (``fused_mixer_block``) emits instructions there,
+    the kernel (``jit(_fwd_pallas)`` / ``jit(_bwd_pallas)``) and the layout
+    glue around it.  The kernel holds that block's norms too; they run
+    inside one custom call and cannot be split out, so a fused block's
+    ``map`` is the whole block.
+
+    ``block``: ``d<i>_<j>`` or None.
+
+    ``pass_`` comes from the transform wrappers of the raw string, which
+    :func:`scope_of_op_name` strips.  The step is ``jax.grad`` of ``gpt``, so
+    the first component is ``jvp(gpt)`` (the chain's forward: ``forward``),
+    ``transpose(jvp(gpt))`` (everything the backward runs) or
+    ``optimizer``.  Inside the backward, ``ops/reversible.py::chain_bwd``
+    calls ``jax.vjp`` on each block again, and the next wrapped component
+    tells its two halves apart: ``jvp(...)`` is that vjp's primal, the
+    inverse's forward (``replay``); ``transpose(...)`` its transposed
+    instructions (``backward``), which is also where a ``custom_vjp``'s
+    backward rule lands (``transpose(transpose(jvp(gpt)))/.../
+    jit(_bwd_pallas)``).  Under ``jax.checkpoint`` the transposed half
+    holds both the recompute, which JAX marks ``rematted_computation``
+    (``remat``), and the transposed instructions (``backward``).  Counted
+    on the optimized HLO of both block layouts (tests/graftprof_test.py):
+    XLA drops the first block's replay under remat (nothing reads the
+    reconstructed input) and merges the last block's with the forward it
+    repeats, so a chain of n blocks shows n - 2 or n - 1 replays."""
+    if "(" not in op_name:
+        # argument labels ("state.params['gpt/...']") are no scope path
+        return OTHER, None, OTHER
+    block, layer = _layer_of_scope(scope_of_op_name(op_name))
+    return _pass_of_parts(_op_name_parts(op_name)), block, layer
+
+
+def layer_pass_seconds(rows: typing.Iterable[
+        typing.Tuple[typing.Optional[str], float]]) -> typing.Dict[str, float]:
+    """``{"<layer>/<pass>": seconds}`` over ``(op_name or None, seconds)``
+    rows, by :func:`step_scope`; a row without a name is ``other/other``.
+    Empty when no row resolved to a scope."""
+    total: typing.Dict[str, float] = {}
+    for op_name, seconds in rows:
+        pass_, _, layer = step_scope(op_name or "")
+        key = f"{layer}/{pass_}"
+        total[key] = total.get(key, 0.0) + seconds
+    return total if set(total) - {f"{OTHER}/{OTHER}"} else {}
+
+
+def layer_pass_table(table: typing.Dict[str, float], n_steps: int = 1
+                     ) -> typing.List[str]:
+    """Text lines of a :func:`layer_pass_seconds` table in ms a step:
+    one row a layer (largest first), one column a pass, sums on both.
+    No table, no lines."""
+    if not table:
+        return []
+    steps = max(1, n_steps)
+    cells = {tuple(k.rsplit("/", 1)): v * 1e3 / steps
+             for k, v in table.items()}
+    by_layer: typing.Dict[str, float] = {}
+    for (layer, _), v in cells.items():
+        by_layer[layer] = by_layer.get(layer, 0.0) + v
+    lines = [f"{'layer (ms/step)':<16}"
+             + "".join(f"{p:>10}" for p in PASSES) + f"{'sum':>10}"]
+    for layer in sorted(by_layer, key=lambda k: -by_layer[k]):
+        lines.append(f"{layer[:16]:<16}" + "".join(
+            f"{cells.get((layer, p), 0.0):>10.3f}" for p in PASSES)
+            + f"{by_layer[layer]:>10.3f}")
+    lines.append(f"{'sum':<16}" + "".join(
+        f"{sum(v for (_, q), v in cells.items() if q == p):>10.3f}"
+        for p in PASSES) + f"{sum(by_layer.values()):>10.3f}")
+    return lines
 
 
 # -- HLO op map (instruction -> metadata op_name) -----------------------------
@@ -287,6 +456,126 @@ def write_op_map_for(trainer, profile_dir: str) -> typing.Optional[str]:
     if compiled is None:
         return None
     return write_op_map(compiled, profile_dir)
+
+
+# -- op_name from the profiler's own file -------------------------------------
+#
+# On the TPU the profiler writes ``<session>/<host>.xplane.pb``.  Every
+# device event points at an event-metadata entry whose name is the whole HLO
+# instruction and whose ``tf_op`` stat is that instruction's
+# ``metadata.op_name`` followed by ``:`` (looked at by hand, PR 25, in
+# traces of executables compiled in the run and loaded from the persistent
+# cache alike).  ``jax.profiler.ProfileData`` shows an event's own stats
+# only, so the few message fields needed are read off the protobuf wire
+# format here: XSpace.planes = 1; XPlane.name = 2, .event_metadata = 4 and
+# .stat_metadata = 5 (map entries: value = 2); XEventMetadata.name = 2,
+# .stats = 5; XStatMetadata.id = 1, .name = 2; XStat.metadata_id = 1,
+# .str_value = 5, .ref_value = 7 (tsl/profiler/protobuf/xplane.proto).
+
+OP_NAME_STAT = "tf_op"
+DEVICE_PLANE_PREFIX = "/device:TPU:"
+
+
+def _wire_fields(buf) -> typing.Iterator[typing.Tuple[int, typing.Any]]:
+    """(field number, value) of one serialized message: an int for a
+    varint, the bytes for a length-delimited or fixed-width field."""
+    i, n = 0, len(buf)
+
+    def varint():
+        nonlocal i
+        value = shift = 0
+        while True:
+            byte = buf[i]
+            i += 1
+            value |= (byte & 0x7F) << shift
+            if not byte & 0x80:
+                return value
+            shift += 7
+
+    while i < n:
+        key = varint()
+        kind = key & 7
+        if kind == 0:
+            yield key >> 3, varint()
+            continue
+        if kind == 2:
+            size = varint()
+        elif kind in (1, 5):
+            size = 8 if kind == 1 else 4
+        else:
+            raise ValueError(f"protobuf wire type {kind} in an xplane")
+        yield key >> 3, buf[i:i + size]
+        i += size
+
+
+def _map_values(entries) -> typing.Iterator[typing.Dict[int, list]]:
+    """The value message of every map entry, as {field: [values]}."""
+    for entry in entries:
+        for field, value in _wire_fields(entry):
+            if field == 2:
+                message: typing.Dict[int, list] = {}
+                for f, v in _wire_fields(value):
+                    message.setdefault(f, []).append(v)
+                yield message
+
+
+def xplane_op_names(path: str) -> typing.Dict[str, str]:
+    """``{event name: op_name}`` over the device planes of one
+    ``.xplane.pb``: the name is the event's whole HLO instruction, as
+    ``ProfileData`` gives it, the value its ``metadata.op_name``.
+    Instructions without one (parameters, async copies that XLA itself
+    put in) are left out."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out: typing.Dict[str, str] = {}
+    for field, plane in _wire_fields(space):
+        if field != 1:
+            continue
+        parts: typing.Dict[int, list] = {}
+        for f, v in _wire_fields(plane):
+            if f in (2, 4, 5):
+                parts.setdefault(f, []).append(v)
+        name = bytes(parts.get(2, [b""])[0]).decode()
+        if not name.startswith(DEVICE_PLANE_PREFIX):
+            continue
+        stat_names = {m[1][0]: bytes(m.get(2, [b""])[0]).decode()
+                      for m in _map_values(parts.get(5, [])) if 1 in m}
+        for meta in _map_values(parts.get(4, [])):
+            for stat in meta.get(5, []):
+                got = dict(_wire_fields(stat))
+                if stat_names.get(got.get(1)) != OP_NAME_STAT:
+                    continue
+                value = (bytes(got[5]).decode() if 5 in got
+                         else stat_names.get(got.get(7), ""))
+                op_name = value.rsplit(":", 1)[0]
+                if op_name:
+                    out.setdefault(bytes(meta[2][0]).decode(), op_name)
+    return out
+
+
+def find_xplane_file(profile_dir: str) -> typing.Optional[str]:
+    """The newest ``.xplane.pb`` under a profiler output directory."""
+    session = _newest_session_dir(profile_dir)
+    hits = sorted(glob.glob(os.path.join(session, "*.xplane.pb"))) \
+        if session else []
+    return hits[-1] if hits else None
+
+
+def xplane_layer_pass_seconds(path: str) -> typing.Dict[str, float]:
+    """The layer x pass table of every ``XLA Ops`` event of one
+    ``.xplane.pb`` (a TPU capture; a CPU one names no instruction and
+    gives ``{}``): durations summed flat, a fusion under its own
+    instruction's scope."""
+    op_names = xplane_op_names(path)
+    if not op_names:
+        return {}
+    from jax.profiler import ProfileData
+    return layer_pass_seconds(
+        (op_names.get(event.name), event.duration_ns / 1e9)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith(DEVICE_PLANE_PREFIX)
+        for line in plane.lines if line.name == "XLA Ops"
+        for event in line.events)
 
 
 # -- trace loading ------------------------------------------------------------
@@ -467,6 +756,10 @@ class ProfileSummary:
     #: full per-(scope, op) self seconds — flamegraph source; trimmed to
     #: top_ops in the JSON form
     op_rows: typing.List[dict] = dataclasses.field(default_factory=list)
+    #: ``{"<layer>/<pass>": seconds}`` by :func:`step_scope`: from the
+    #: ``.xplane.pb`` on a TPU, from the op-map sidecar elsewhere
+    layer_pass_s: typing.Dict[str, float] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def comm_fraction(self) -> float:
@@ -515,6 +808,7 @@ def summarize_events(raw_events: typing.List[dict],
                          float] = {}
     scope_us: typing.Dict[typing.Tuple[str, ...], float] = {}
     total_self = 0.0
+    named_rows: typing.List[typing.Tuple[typing.Optional[str], float]] = []
     for e in events:
         cat = categorize(e.op)
         cats[cat] += e.self_us
@@ -526,6 +820,7 @@ def summarize_events(raw_events: typing.List[dict],
         op_name = None
         if op_map is not None:
             op_name = op_map.lookup(e.module, e.op)
+        named_rows.append((op_name, e.self_us * 1e-6))
         if op_name:
             # argument-label metadata ("state.params['gpt/...']",
             # "batch['token_x']") is not a scope path: step-level glue
@@ -576,7 +871,9 @@ def summarize_events(raw_events: typing.List[dict],
         decomposition_ms_per_step={k: round(v, 6)
                                    for k, v in decomp_ms.items()},
         fractions={k: round(v, 6) for k, v in fractions.items()},
-        op_rows=op_rows)
+        op_rows=op_rows,
+        layer_pass_s={k: round(v, 9) for k, v in
+                      layer_pass_seconds(named_rows).items()})
 
 
 def summarize_trace(path: str, op_map: typing.Optional[OpMap] = None,
@@ -589,14 +886,22 @@ def summarize_trace(path: str, op_map: typing.Optional[OpMap] = None,
 def capture_summary(profile_dir: str, n_steps: typing.Optional[int] = None,
                     top_k: int = 20) -> typing.Optional[ProfileSummary]:
     """Summarize the newest capture under a profiler output dir, joining
-    the op-map sidecar when one sits next to the trace.  None when no
+    the op-map sidecar when one sits next to the trace.  Where the
+    capture's ``.xplane.pb`` names its instructions itself (a TPU), the
+    layer x pass table is read from it.  None when no
     trace was written (profiler plugin directory absent — the caller
     skips cleanly rather than failing the run)."""
     trace = find_trace_file(profile_dir)
     if trace is None:
         return None
-    return summarize_trace(trace, op_map=sidecar_op_map(trace),
-                           n_steps=n_steps, top_k=top_k)
+    summary = summarize_trace(trace, op_map=sidecar_op_map(trace),
+                              n_steps=n_steps, top_k=top_k)
+    xplane = find_xplane_file(profile_dir)
+    from_device = xplane_layer_pass_seconds(xplane) if xplane else {}
+    if from_device:
+        summary.layer_pass_s = {k: round(v, 9)
+                                for k, v in from_device.items()}
+    return summary
 
 
 # -- flamegraph + diff + reconcile --------------------------------------------

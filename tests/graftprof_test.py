@@ -17,14 +17,17 @@ Three layers of coverage:
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
+import re
 
 import pytest
 
 from homebrewnlp_tpu.obs import profile as P
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURE = os.path.join(DATA, "mini_trace.json")
 
 
@@ -102,6 +105,151 @@ def test_collapse_repeat_pure():
     assert P._collapse_repeat(("a", "a")) == ("a",)
     assert P._collapse_repeat(("a", "b", "c")) == ("a", "b", "c")
     assert P._collapse_repeat(()) == ()
+
+
+def test_collapse_repeat_drops_checkpoint_components():
+    # jax.checkpoint's own components sit between the repeats
+    assert P._collapse_repeat(
+        ("gpt", "body", "checkpoint", "gpt", "body", "d0_0", "block_")
+    ) == ("gpt", "body", "d0_0", "block_")
+    assert P.scope_of_op_name(
+        "jit(step_fn)/transpose(jvp(gpt))/body/"
+        "transpose(jvp(transpose(jvp(gpt))))/body/jvp()/checkpoint/"
+        "rematted_computation/gpt/body/d0_0/block_/norm_/rsqrt"
+    ) == ("gpt", "body", "d0_0", "block_", "norm_")
+
+
+# -- the train step's scope grammar -------------------------------------------
+
+_BWD = "jit(step_fn)/transpose(jvp(gpt))/body/"
+_CKPT = _BWD + "transpose(jvp(transpose(jvp(gpt))))/body/jvp()/checkpoint/"
+
+
+@pytest.mark.parametrize("op_name,want", [
+    # the three runs of one norm under revnet + remat, and its backward
+    ("jit(step_fn)/jvp(gpt)/body/gpt/body/d0_0/block_/norm_/rsqrt",
+     ("forward", "d0_0", "norm")),
+    (_BWD + "jvp(gpt)/body/d0_1/block_/norm_1/rsqrt",
+     ("replay", "d0_1", "norm")),
+    (_CKPT + "rematted_computation/gpt/body/d0_0/block_/norm_/rsqrt",
+     ("remat", "d0_0", "norm")),
+    (_CKPT + "gpt/body/d0_0/block_/norm_/mul",
+     ("backward", "d0_0", "norm")),
+    # einsums keep their layer through the spec component
+    ("jit(step_fn)/jvp(gpt)/body/gpt/body/d3_0/block_/"
+     "bottleneck_group_linear_/abcd,cde->abce/dot_general",
+     ("forward", "d3_0", "group_linear")),
+    (_CKPT + "gpt/body/d31_1/block_/attention_1/abc,dcae->dbae/dot_general",
+     ("backward", "d31_1", "map")),
+    (_BWD + "jvp(gpt)/body/d1_1/block_/activation_/tanh",
+     ("replay", "d1_1", "map")),
+    # no remat: the replay's vjp is transposed in place
+    (_BWD + "transpose(jvp(gpt))/body/d0_0/block_/bottleneck_group_linear_/"
+     "abcd,cde->abe/dot_general", ("backward", "d0_0", "group_linear")),
+    # a fused block: the kernel and the glue directly under block_
+    ("jit(step_fn)/jvp(gpt)/body/gpt/body/d0_1/block_/jit(_fwd_pallas)/"
+     "while/body/dot_general", ("forward", "d0_1", "map")),
+    (_BWD + "jvp(gpt)/body/d0_1/block_/jit(_fwd_pallas)/while/body/add",
+     ("replay", "d0_1", "map")),
+    (_BWD + "transpose(transpose(jvp(gpt)))/body/jvp(gpt)/body/d0_1/block_/"
+     "jit(_bwd_pallas)/while/body/add", ("backward", "d0_1", "map")),
+    (_BWD + "transpose(transpose(jvp(gpt)))/body/jvp(gpt)/body/d0_1/block_/"
+     "transpose", ("backward", "d0_1", "map")),
+    # outside the blocks
+    ("jit(step_fn)/optimizer/mul", ("optimizer", None, "optimizer")),
+    ("jit(step_fn)/jvp(gpt)/loss/jit(take_along_axis)/gather",
+     ("forward", None, "loss")),
+    ("jit(step_fn)/transpose(jvp(gpt))/output/abcd,cdef->abef/dot_general",
+     ("backward", None, "output")),
+    ("jit(step_fn)/jvp(gpt)/input/gather/embed/gather",
+     ("forward", None, "input")),
+    (_BWD + "add_any", ("backward", None, "body")),
+    ("jit(step_fn)/add", ("other", None, "other")),
+    ("state.params['gpt/body/d0_0/block_/norm_/scale']",
+     ("other", None, "other")),
+    # XLA joins the names of merged instructions: the first one counts
+    ("jit(step_fn)/transpose(jvp(gpt))/loss/mul;"
+     "jit(step_fn)/jvp(gpt)/loss/sub", ("backward", None, "loss")),
+    ("", ("other", None, "other")),
+])
+def test_step_scope(op_name, want):
+    assert P.step_scope(op_name) == want
+    assert want[0] in P.PASSES
+
+
+_TOY_CONFIGS = os.path.join(REPO, "benchmark", "tests", "toy", "configs")
+_EINSUM = re.compile(r"/\w+(,\w+)+->\w+/")
+
+
+@functools.lru_cache(maxsize=None)
+def _toy_step_op_names(layout):
+    """``op_name`` of every instruction of the optimized train step of one
+    of the benchmark's toy configurations (depth 2, two blocks a depth), on
+    one device as the cells run it: a partitioned step renames its dots."""
+    import jax
+    from homebrewnlp_tpu.config import Config
+    from homebrewnlp_tpu.parallel import make_mesh
+    from homebrewnlp_tpu.train import Trainer
+    from homebrewnlp_tpu.utils import random_text_batch
+    with open(os.path.join(_TOY_CONFIGS, layout + ".json")) as f:
+        raw = json.load(f)
+    raw.pop("benchmark")
+    cfg = Config(raw)
+    tr = Trainer(cfg, make_mesh(cfg, jax.devices()[:1]))
+    batch = random_text_batch(cfg)
+    tr.step_cost_analysis(tr.init(batch), batch)
+    return tuple(P.op_map_from_hlo_text(tr._compiled.as_text()).values())
+
+
+def _einsum_passes_by_block(op_names):
+    found = {}
+    for name in op_names:
+        pass_, block, _ = P.step_scope(name)
+        if block is not None and _EINSUM.search(name):
+            found.setdefault(block, set()).add(pass_)
+    return found
+
+
+@pytest.mark.parametrize("layout", ["32big_mixer", "32mixer_group"])
+def test_toy_step_blocks_never_resolve_to_other(layout):
+    in_block = [n for n in _toy_step_op_names(layout)
+                if "(" in n and re.search(r"/d\d+_\d+/", n)]
+    assert len(in_block) > 1000
+    for name in in_block:
+        pass_, block, layer = P.step_scope(name)
+        assert block and pass_ != "other" and layer in (
+            "norm", "group_linear", "map"), name
+    layers = {P.step_scope(n)[2] for n in _toy_step_op_names(layout)}
+    assert layers == set(P.LAYERS)
+
+
+def test_remat_layout_runs_every_block_in_every_pass():
+    names = _toy_step_op_names("32big_mixer")
+    passes = _einsum_passes_by_block(names)
+    assert sorted(passes) == ["d0_0", "d0_1", "d1_0", "d1_1"]
+    for block, seen in passes.items():
+        assert {"forward", "remat", "backward"} <= seen, (block, seen)
+    # XLA drops the first block's replay (nothing reads the reconstructed
+    # input) and may merge the last block's with the forward it repeats
+    for block in ("d0_1", "d1_0"):
+        assert "replay" in passes[block], passes
+    # only a fused block emits instructions directly under block_
+    assert all(re.search(r"/(attention|activation)_\d*/", n) for n in names
+               if P.step_scope(n)[2] == "map")
+
+
+def test_fused_layout_has_no_remat_and_the_kernel_in_its_passes():
+    names = _toy_step_op_names("32mixer_group")
+    assert "remat" not in {P.step_scope(n)[0] for n in names}
+    passes = _einsum_passes_by_block(names)
+    assert sorted(passes) == ["d0_0", "d1_0"]  # d*_1 are fused: no einsum
+    for block, seen in passes.items():
+        assert seen == {"forward", "replay", "backward"}, (block, seen)
+    fwd = {P.step_scope(n) for n in names if "jit(_fwd_pallas)" in n}
+    assert {s[0] for s in fwd} == {"forward", "replay"}
+    bwd = {P.step_scope(n) for n in names if "jit(_bwd_pallas)" in n}
+    assert {s[0] for s in bwd} == {"backward"}
+    assert {s[1:] for s in fwd | bwd} == {("d0_1", "map"), ("d1_1", "map")}
 
 
 # -- HLO op map ---------------------------------------------------------------
@@ -222,6 +370,96 @@ def test_empty_trace_summary():
     s = P.summarize_events([])
     assert s.n_events == 0 and s.wall_s == 0.0
     assert s.decomposition_ms_per_step["total"] == 0.0
+
+
+# -- the profiler's own file: op_name from the .xplane.pb ---------------------
+
+_XSPACE = """
+planes {
+  id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Ops"
+    events { metadata_id: 7 offset_ps: 1000 duration_ps: 5000000 }
+    events { metadata_id: 8 offset_ps: 6000000 duration_ps: 2000000 }
+    events { metadata_id: 9 offset_ps: 9000000 duration_ps: 1000000 }
+    events { metadata_id: 7 offset_ps: 11000000 duration_ps: 5000000 } }
+  lines { id: 2 name: "XLA Modules"
+    events { metadata_id: 9 offset_ps: 0 duration_ps: 90000000 } }
+  event_metadata { key: 7 value { id: 7
+    name: "%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kLoop"
+    stats { metadata_id: 3 uint64_value: 12 }
+    stats { metadata_id: 2 str_value:
+      "jit(step_fn)/transpose(jvp(gpt))/body/jvp(gpt)/body/d0_0/block_/norm_/rsqrt:" } } }
+  event_metadata { key: 8 value { id: 8 name: "%copy.2 = bf16[8]{0} copy(%q)"
+    stats { metadata_id: 2 ref_value: 4 } } }
+  event_metadata { key: 9 value { id: 9 name: "%copy-start = bf16[8]{0} copy-start(%q)" } }
+  stat_metadata { key: 2 value { id: 2 name: "tf_op" } }
+  stat_metadata { key: 3 value { id: 3 name: "flops" } }
+  stat_metadata { key: 4 value { id: 4 name: "jit(step_fn)/optimizer/mul:" } }
+}
+planes { id: 2 name: "/host:CPU"
+  event_metadata { key: 1 value { id: 1 name: "x"
+    stats { metadata_id: 1 str_value: "jit(f)/y:" } } }
+  stat_metadata { key: 1 value { id: 1 name: "tf_op" } } }
+"""
+
+
+def _write_xspace(directory, text=_XSPACE):
+    from jax.profiler import ProfileData
+    os.makedirs(directory, exist_ok=True)
+    path = os.path.join(str(directory), "host.xplane.pb")
+    with open(path, "wb") as f:
+        f.write(ProfileData.text_proto_to_serialized_xspace(text))
+    return path
+
+
+def test_xplane_op_names_reads_the_event_metadata(tmp_path):
+    names = P.xplane_op_names(_write_xspace(tmp_path))
+    # a string stat, a stat by reference; no name, no entry; devices only
+    assert names == {
+        "%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kLoop":
+            "jit(step_fn)/transpose(jvp(gpt))/body/jvp(gpt)/body/d0_0/"
+            "block_/norm_/rsqrt",
+        "%copy.2 = bf16[8]{0} copy(%q)": "jit(step_fn)/optimizer/mul"}
+    with pytest.raises(ValueError):
+        list(P._wire_fields(bytes([0x0B])))  # a group: not in an xplane
+
+
+def test_xplane_layer_pass_seconds_sums_the_ops_line_flat(tmp_path):
+    table = P.xplane_layer_pass_seconds(_write_xspace(tmp_path))
+    assert table == pytest.approx({"norm/replay": 10e-6,
+                                   "optimizer/optimizer": 2e-6,
+                                   "other/other": 1e-6})
+    lines = P.layer_pass_table(table, n_steps=2)
+    assert lines[0].split() == ["layer", "(ms/step)", *P.PASSES, "sum"]
+    assert [line.split()[0] for line in lines[1:]] == [
+        "norm", "optimizer", "other", "sum"]
+    assert float(lines[1].split()[2]) == pytest.approx(0.005)
+    assert float(lines[-1].split()[-1]) == pytest.approx(0.0065, abs=6e-4)
+    # a capture that names no instruction (the CPU's) gives no table
+    unnamed = _XSPACE.replace('name: "tf_op"', 'name: "hlo_category"')
+    assert P.xplane_layer_pass_seconds(
+        _write_xspace(tmp_path / "cpu", unnamed)) == {}
+    assert P.layer_pass_seconds([(None, 1.0), ("jit(f)/add", 2.0)]) == {}
+
+
+def test_capture_summary_takes_the_table_from_the_xplane(tmp_path, capsys):
+    session = tmp_path / "plugins" / "profile" / "2026_01_01"
+    _write_xspace(session)
+    with open(FIXTURE) as f, open(session / "host.trace.json", "w") as out:
+        out.write(f.read())
+    assert P.find_xplane_file(str(tmp_path)).endswith("host.xplane.pb")
+    summary = P.capture_summary(str(tmp_path), n_steps=2)
+    assert summary.layer_pass_s == pytest.approx(
+        {"norm/replay": 10e-6, "optimizer/optimizer": 2e-6,
+         "other/other": 1e-6})
+    assert P.ProfileSummary.from_json(
+        json.loads(json.dumps(summary.to_json()))).layer_pass_s \
+        == summary.layer_pass_s
+    # not the sidecar's reading of the Chrome trace beside it
+    assert "norm/replay" not in fixture_summary().layer_pass_s
+    assert _run_cli(str(tmp_path), "--steps", "2") == 0
+    assert "layer (ms/step)" in capsys.readouterr().out
+    assert P.find_xplane_file(str(tmp_path / "missing")) is None
 
 
 # -- flamegraph + compare + CLI -----------------------------------------------
@@ -472,6 +710,10 @@ def test_train_profile_capture_end_to_end(tmp_path):
     assert doc["attributed_scope_frac"] >= 0.9
     assert any(k.startswith("gpt/") for k in doc["scopes_s"])
     assert "optimizer" in doc["scopes_s"]
+    # the layer x pass table (step_scope), here through the sidecar
+    table = doc["layer_pass_s"]
+    assert table["optimizer/optimizer"] > 0
+    assert {k.split("/")[1] for k in table} >= {"forward", "backward"}
     d = doc["decomposition_ms_per_step"]
     assert (d["mxu"] + d["hbm"] + d["comm"] + d["idle"]
             == pytest.approx(d["total"], rel=1e-3))

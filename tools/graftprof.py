@@ -10,7 +10,9 @@ Sources (positional argument, auto-detected):
 
 - a profiler output directory (``--profile`` dir / bench tempdir) — the
   newest ``plugins/profile/<session>/*.trace.json.gz`` is parsed, joined
-  with the ``graftprof_op_map.json`` sidecar when present;
+  with the ``graftprof_op_map.json`` sidecar when present; the layer x
+  pass table (``obs.profile.step_scope``) comes from the session's
+  ``.xplane.pb`` where that names its instructions itself (a TPU);
 - a ``*.trace.json[.gz]`` file directly;
 - a saved ``profile_summary.json`` (main.py writes one per ``--profile``
   run);
@@ -144,6 +146,9 @@ def render_summary(s: P.ProfileSummary, top: int, depth: int) -> str:
         for k, v in sorted(scopes.items(), key=lambda kv: -kv[1])[:top]:
             lines.append(f"{k[:56]:<56} {v * 1e3 / steps:>12.3f} "
                          f"{v / total:>7.1%}")
+    table = P.layer_pass_table(s.layer_pass_s, steps)
+    if table:
+        lines += ["", *table]
     if s.top_ops:
         lines.append("")
         lines.append(f"{'op':<28} {'category':<11} "
