@@ -12,28 +12,52 @@ is the 5-layer chain
     out = (bias2 . causal) @ g
 
 on a ``[B, S, H, K]`` activation.  Under XLA every arrow above is a
-separate HLO with a full ``[B,S,H,K]`` HBM round-trip (measured: the
-32mixer_group step is bandwidth-bound at 266.7 GB with the MXU 4x idle —
-docs/perf/README.md roofline), and the backward doubles it with recompute
-reads plus f32 grad temporaries.  Per (batch, head) slice, however, the
-whole chain is a pair of tiny ``[S,S] @ [S,K]`` matmuls with elementwise
-glue — it fits VMEM whole.  This kernel runs the chain (forward) and its
-entire vjp (backward) per ``(head, batch-block)`` grid cell — each cell
-covers ``_block_rows`` batch rows (python-unrolled), amortizing the
-per-cell bias load, causal-mask build and DMA latency: the forward reads
-x and writes out ONCE; the backward reads x and d(out) once, writes dx
-once, recomputes the internals in VMEM (remat-in-kernel — the same FLOPs
-XLA's remat executes, for a fraction of the bytes), and accumulates the
-parameter gradients (dbias1, dbias2, dscale/dshift) in f32 across the
-batch grid axis.
+separate HLO with a full ``[B,S,H,K]`` HBM round-trip, and the backward
+doubles it with recompute reads plus f32 grad temporaries.  Per (batch,
+head) slice, however, the whole chain is a pair of tiny ``[S,S] @ [S,K]``
+matmuls with elementwise glue — it fits VMEM whole.  This kernel runs the
+chain (forward) and its entire vjp (backward) per ``(head, batch-block)``
+grid cell — each cell covers ``_block_rows`` batch rows (python-unrolled),
+amortizing the per-cell bias load, causal-mask build and DMA latency: the
+forward reads x and writes out ONCE; the backward reads x and d(out) once,
+writes dx once, recomputes the internals in VMEM (remat-in-kernel — the
+same FLOPs XLA's remat executes, for a fraction of the bytes), and
+accumulates the parameter gradients (dbias1, dbias2, dscale/dshift) in f32
+across the batch grid axis.
 
-Layout notes (pallas TPU tiling): activations are viewed as
-``[B, S, H*K]`` so the per-head block is a stack of ``[S, K]``
-lane-aligned column slices (the same trick ops/pallas_attn.py uses); the
-tiny ``[H, K]`` scale/shift vectors ride as ``[H, 1, K]`` with a
-``(1, 1, K)`` per-head block — mosaic rejects dynamic sublane offsets
-into a whole-``[H, K]`` tile, and a head-blocked window needs no
-in-kernel dynamic indexing at all.
+Layout notes.  XLA stores the residual stream of the mixer models as
+``[B,S,H,K]{1,3,2,0}``: physically ``[B,H,K,S]``, the sequence in the lanes
+(read off the v5e compiler's HLO, PERF.md §7).  The kernels therefore take
+and return activations as ``[B,H,K,S]``: ``x.transpose(0, 2, 3, 1)`` is a
+bitcast of what XLA already holds, the custom call takes its neighbour's
+fusion directly, and a grid cell works on transposed ``[K, S]`` tiles
+(block ``(n_bt, 1, K, S)``).  Inside, the chain is the same chain written
+for that tile: norm moments reduce over axis 0, ``a1T = n1T @ b1m^T``,
+``outT = gT @ b2m^T``; backward ``dgT = doutT @ b2m``, ``db2 = doutT^T @
+gT`` (likewise ``dn1T``, ``db1``).  The tiny ``[H, K]`` scale / shift
+vectors ride as ``[H, K, 1]`` columns with a ``(1, K, 1)`` per-head block:
+they broadcast along a tile's lanes as they are, and a head-blocked window
+needs no in-kernel dynamic indexing.
+
+Two boundaries that cost more (v5e compiles of the 32mixer_group model,
+never timed on their own; the first was the code until PR 26):
+
+* a flat ``[B, S, H*K]`` view with ``[S, K]`` column slices a head asks XLA
+  for row-major ``[B,S,H*K]``, so every activation crossing a call was
+  transposed through HBM, in and out: two 268 MB copies a call, 192 an
+  update, 15% of that cell's step (ledger, PR 25);
+* rank 4 as the program names it, ``[B,S,H,K]`` row-major, leaves no copy at
+  the call but makes XLA re-lay the neighbours (norm, the stream's adds):
+  more copies in the module than the flat view had.
+
+The ``optimization_barrier`` in ``_to_kernel`` pins the transpose to the
+call.  Without it XLA sinks the transpose through the residual add that
+produced x, which splits that add in two (one in kernel order for the call,
+one in stream order for everybody else); the stream's own add then has no
+consumer that needs it in memory, XLA re-derives every stream value from
+all earlier blocks' outputs, and the step's temporaries grow with depth
+(11.3 GB against 3.4 GB at depth 8; depth 32 does not fit a v5e).
+tests/tpu_compile_test.py guards both the bitcasts and this.
 
 Numerics match the unfused chain's dtype walk: norms compute in f32 from
 the stored dtype (models/layers.py::norm), map matmuls take
@@ -55,31 +79,35 @@ import jax
 import jax.numpy as jnp
 
 
-def _norm_fwd(x32: jnp.ndarray, scale: jnp.ndarray, shift: jnp.ndarray
-              ) -> jnp.ndarray:
-    """models/layers.py::norm on one [S, K] slice, f32 in/out: one-pass
-    moments, clamped var, affine fold."""
-    m1 = jnp.mean(x32, axis=1, keepdims=True)
-    m2 = jnp.mean(x32 * x32, axis=1, keepdims=True)
+def _norm_fwd(x32: jnp.ndarray, scale: jnp.ndarray, shift: jnp.ndarray,
+              axis: int = 1) -> jnp.ndarray:
+    """models/layers.py::norm on one 2-D f32 slice whose features run along
+    ``axis`` (``[S, K]`` rows with ``[K]`` scale / shift by default, ``[K, S]``
+    columns with ``[K, 1]`` for ``axis=0``): one-pass moments, clamped var,
+    affine fold."""
+    vec = (1, -1) if axis == 1 else (-1, 1)
+    m1 = jnp.mean(x32, axis=axis, keepdims=True)
+    m2 = jnp.mean(x32 * x32, axis=axis, keepdims=True)
     var = jnp.maximum(m2 - m1 * m1, 0.0)
-    mul = jax.lax.rsqrt(var + 1e-5) * scale[None, :]
-    return x32 * mul + (shift[None, :] - m1 * mul)
+    mul = jax.lax.rsqrt(var + 1e-5) * scale.reshape(vec)
+    return x32 * mul + (shift.reshape(vec) - m1 * mul)
 
 
-def _norm_bwd(x32: jnp.ndarray, scale: jnp.ndarray,
-              dy: jnp.ndarray) -> typing.Tuple[jnp.ndarray, jnp.ndarray,
-                                               jnp.ndarray]:
-    """vjp of _norm_fwd wrt (x, scale, shift); all f32 [S, K] / [K]."""
-    m1 = jnp.mean(x32, axis=1, keepdims=True)
-    m2 = jnp.mean(x32 * x32, axis=1, keepdims=True)
+def _norm_bwd(x32: jnp.ndarray, scale: jnp.ndarray, dy: jnp.ndarray,
+              axis: int = 1) -> typing.Tuple[jnp.ndarray, jnp.ndarray,
+                                             jnp.ndarray]:
+    """vjp of _norm_fwd wrt (x, scale, shift), all f32; dscale / dshift come
+    back in scale's own shape (``[K]`` for ``axis=1``, ``[K, 1]`` for 0)."""
+    m1 = jnp.mean(x32, axis=axis, keepdims=True)
+    m2 = jnp.mean(x32 * x32, axis=axis, keepdims=True)
     var = jnp.maximum(m2 - m1 * m1, 0.0)
     r = jax.lax.rsqrt(var + 1e-5)
     xhat = (x32 - m1) * r
-    u = dy * scale[None, :]
-    dx = r * (u - jnp.mean(u, axis=1, keepdims=True)
-              - xhat * jnp.mean(u * xhat, axis=1, keepdims=True))
-    dscale = jnp.sum(dy * xhat, axis=0)
-    dshift = jnp.sum(dy, axis=0)
+    u = dy * scale.reshape((1, -1) if axis == 1 else (-1, 1))
+    dx = r * (u - jnp.mean(u, axis=axis, keepdims=True)
+              - xhat * jnp.mean(u * xhat, axis=axis, keepdims=True))
+    dscale = jnp.sum(dy * xhat, axis=1 - axis, keepdims=axis == 0)
+    dshift = jnp.sum(dy, axis=1 - axis, keepdims=axis == 0)
     return dx, dscale, dshift
 
 
@@ -89,32 +117,43 @@ def _causal(seq: int, dtype) -> jnp.ndarray:
     return (row >= col).astype(dtype)
 
 
-def _chain_fwd_tiles(x, b1m, b2m, s1, sh1, s2, sh2, cdtype):
-    """Forward chain on one [S, K] slice; returns (out, intermediates).
-    Dtype walk mirrors the unfused layers: f32 norms, cdtype matmul
-    operands with f32 accumulation, cdtype gelu."""
-    n1 = _norm_fwd(x.astype(jnp.float32), s1, sh1).astype(cdtype)
-    a1 = jnp.dot(b1m, n1, preferred_element_type=jnp.float32).astype(cdtype)
-    n2 = _norm_fwd(a1.astype(jnp.float32), s2, sh2).astype(cdtype)
+def _dot(a, b, contract_a: int, contract_b: int):
+    """a . b over the given axes on the MXU, f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((contract_a,), (contract_b,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _chain_fwd_tiles(xt, b1m, b2m, s1, sh1, s2, sh2, cdtype):
+    """Forward chain on one transposed [K, S] slice; returns (outT,
+    intermediates), every tile [K, S].  ``a1 = b1m @ n1`` reads
+    ``a1T = n1T @ b1m^T`` here.  Dtype walk mirrors the unfused layers: f32
+    norms, cdtype matmul operands with f32 accumulation, cdtype gelu."""
+    n1 = _norm_fwd(xt.astype(jnp.float32), s1, sh1, axis=0).astype(cdtype)
+    a1 = _dot(n1, b1m, 1, 1).astype(cdtype)
+    n2 = _norm_fwd(a1.astype(jnp.float32), s2, sh2, axis=0).astype(cdtype)
     g = jax.nn.gelu(n2)
-    out = jnp.dot(b2m, g, preferred_element_type=jnp.float32).astype(cdtype)
+    out = _dot(g, b2m, 1, 1).astype(cdtype)
     return out, (n1, a1, n2, g)
+
+
+def _load_params(b1_ref, b2_ref, s1_ref, sh1_ref, s2_ref, sh2_ref, seq: int,
+                 cdtype):
+    """The cell's head: masked [S, S] maps and f32 [K, 1] norm columns."""
+    mask = _causal(seq, cdtype)
+    vecs = (r[0].astype(jnp.float32)
+            for r in (s1_ref, sh1_ref, s2_ref, sh2_ref))
+    return (mask, b1_ref[0] * mask, b2_ref[0] * mask, *vecs)
 
 
 def _fwd_kernel(x_ref, b1_ref, b2_ref, s1_ref, sh1_ref, s2_ref, sh2_ref,
                 out_ref, *, seq: int, n_bt: int):
     cdtype = x_ref.dtype
-    mask = _causal(seq, cdtype)
-    b1m = b1_ref[0] * mask
-    b2m = b2_ref[0] * mask
-    s1 = s1_ref[0, 0].astype(jnp.float32)
-    sh1 = sh1_ref[0, 0].astype(jnp.float32)
-    s2 = s2_ref[0, 0].astype(jnp.float32)
-    sh2 = sh2_ref[0, 0].astype(jnp.float32)
+    _, b1m, b2m, s1, sh1, s2, sh2 = _load_params(
+        b1_ref, b2_ref, s1_ref, sh1_ref, s2_ref, sh2_ref, seq, cdtype)
     for i in range(n_bt):  # unrolled: amortizes mask/bias setup + grid DMA
-        out, _ = _chain_fwd_tiles(x_ref[i], b1m, b2m, s1, sh1, s2, sh2,
+        out, _ = _chain_fwd_tiles(x_ref[i, 0], b1m, b2m, s1, sh1, s2, sh2,
                                   cdtype)
-        out_ref[i] = out
+        out_ref[i, 0] = out
 
 
 def _bwd_kernel(x_ref, b1_ref, b2_ref, s1_ref, sh1_ref, s2_ref, sh2_ref,
@@ -126,26 +165,22 @@ def _bwd_kernel(x_ref, b1_ref, b2_ref, s1_ref, sh1_ref, s2_ref, sh2_ref,
     f32 = jnp.float32
     b = pl.program_id(1)  # batch is the fastest grid axis: accumulate here
 
-    mask = _causal(seq, cdtype)
-    b1m = b1_ref[0] * mask
-    b2m = b2_ref[0] * mask
-    s1 = s1_ref[0, 0].astype(f32)
-    sh1 = sh1_ref[0, 0].astype(f32)
-    s2 = s2_ref[0, 0].astype(f32)
-    sh2 = sh2_ref[0, 0].astype(f32)
+    mask, b1m, b2m, s1, sh1, s2, sh2 = _load_params(
+        b1_ref, b2_ref, s1_ref, sh1_ref, s2_ref, sh2_ref, seq, cdtype)
     maskf = mask.astype(f32)
 
     db1 = db2 = ds1 = dsh1 = ds2 = dsh2 = None
     acc = lambda t, u: u if t is None else t + u
     for i in range(n_bt):  # unrolled over the cell's batch rows
-        x = x_ref[i]
+        x = x_ref[i, 0]
         # recompute the forward internals in VMEM (remat-in-kernel)
         _, (n1, a1, n2, g) = _chain_fwd_tiles(x, b1m, b2m, s1, sh1, s2,
                                               sh2, cdtype)
-        dout = dout_ref[i]
-        # out = b2m @ g
-        dg = jnp.dot(b2m.T, dout, preferred_element_type=f32)
-        db2 = acc(db2, jnp.dot(dout, g.T, preferred_element_type=f32))
+        dout = dout_ref[i, 0]
+        # out = b2m @ g, all tiles transposed: dgT = doutT @ b2m,
+        # db2 = dout @ g^T = doutT^T @ gT
+        dg = _dot(dout, b2m, 1, 0)
+        db2 = acc(db2, _dot(dout, g, 0, 0))
         # g = gelu(n2) in cdtype (vjp evaluated in f32 of the cdtype-rounded
         # n2, matching the unfused chain's value to rounding); the vjp
         # cotangent comes back in n2's dtype — grads accumulate in f32
@@ -153,14 +188,14 @@ def _bwd_kernel(x_ref, b1_ref, b2_ref, s1_ref, sh1_ref, s2_ref, sh2_ref,
         (dn2,) = gelu_vjp(dg)
         dn2 = dn2.astype(f32)
         # n2 = norm(a1)
-        da1, ds2_i, dsh2_i = _norm_bwd(a1.astype(f32), s2, dn2)
+        da1, ds2_i, dsh2_i = _norm_bwd(a1.astype(f32), s2, dn2, axis=0)
         da1c = da1.astype(cdtype)
         # a1 = b1m @ n1
-        dn1 = jnp.dot(b1m.T, da1c, preferred_element_type=f32)
-        db1 = acc(db1, jnp.dot(da1c, n1.T, preferred_element_type=f32))
+        dn1 = _dot(da1c, b1m, 1, 0)
+        db1 = acc(db1, _dot(da1c, n1, 0, 0))
         # n1 = norm(x)
-        dx, ds1_i, dsh1_i = _norm_bwd(x.astype(f32), s1, dn1)
-        dx_ref[i] = dx.astype(dx_ref.dtype)
+        dx, ds1_i, dsh1_i = _norm_bwd(x.astype(f32), s1, dn1, axis=0)
+        dx_ref[i, 0] = dx.astype(dx_ref.dtype)
         ds1 = acc(ds1, ds1_i)
         dsh1 = acc(dsh1, dsh1_i)
         ds2 = acc(ds2, ds2_i)
@@ -176,19 +211,19 @@ def _bwd_kernel(x_ref, b1_ref, b2_ref, s1_ref, sh1_ref, s2_ref, sh2_ref,
     def _init():
         db1_ref[0] = db1
         db2_ref[0] = db2
-        ds1_ref[0, 0] = ds1
-        dsh1_ref[0, 0] = dsh1
-        ds2_ref[0, 0] = ds2
-        dsh2_ref[0, 0] = dsh2
+        ds1_ref[0] = ds1
+        dsh1_ref[0] = dsh1
+        ds2_ref[0] = ds2
+        dsh2_ref[0] = dsh2
 
     @pl.when(b != 0)
     def _acc():
         db1_ref[0] += db1
         db2_ref[0] += db2
-        ds1_ref[0, 0] += ds1
-        dsh1_ref[0, 0] += dsh1
-        ds2_ref[0, 0] += ds2
-        dsh2_ref[0, 0] += dsh2
+        ds1_ref[0] += ds1
+        dsh1_ref[0] += dsh1
+        ds2_ref[0] += ds2
+        dsh2_ref[0] += dsh2
 
 
 def _block_rows(n_b: int, seq: int, key: int) -> int:
@@ -204,20 +239,26 @@ def _block_rows(n_b: int, seq: int, key: int) -> int:
 
 def _specs(seq: int, key: int, n_bt: int):
     from jax.experimental import pallas as pl
-    # activations viewed as [B, S, H*K]: per-head block = [n_bt, S, K]
-    # lane-aligned column slices; maps blocked per head
-    x_spec = pl.BlockSpec((n_bt, seq, key), lambda h, b: (b, 0, h))
+    # activations cross as [B, H, K, S] (see "Layout notes"): per-head block
+    # = n_bt [K, S] tiles; maps blocked per head
+    x_spec = pl.BlockSpec((n_bt, 1, key, seq), lambda h, b: (b, h, 0, 0))
     map_spec = pl.BlockSpec((1, seq, seq), lambda h, b: (h, 0, 0))
-    # [H,K] vectors ride as [H,1,K] with a (1,1,K) per-head block: mosaic
-    # rejects dynamic sublane offsets into a whole-[H,K] tile, but a
+    # [H,K] vectors ride as [H,K,1] columns with a (1,K,1) per-head block:
+    # they broadcast along the lanes of a [K,S] tile as they are, and a
     # head-blocked window needs no in-kernel dynamic indexing at all
-    vec_spec = pl.BlockSpec((1, 1, key), lambda h, b: (h, 0, 0))
+    vec_spec = pl.BlockSpec((1, key, 1), lambda h, b: (h, 0, 0))
     return x_spec, map_spec, vec_spec
 
 
-def _flat(x):
-    n_b, seq, n_h, key = x.shape
-    return x.reshape(n_b, seq, n_h * key)
+def _to_kernel(x):
+    """[B,S,H,K] as the program names it -> [B,H,K,S], the order XLA stores
+    the stream in (a bitcast of its ``{1,3,2,0}`` layout).  The barrier
+    keeps the transpose at the call: "Layout notes" above."""
+    return jax.lax.optimization_barrier(x).transpose(0, 2, 3, 1)
+
+
+def _from_kernel(xt):
+    return xt.transpose(0, 3, 1, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -235,13 +276,13 @@ def _fwd_pallas(x, bias1, bias2, scale1, shift1, scale2, shift2,
         in_specs=[x_spec, map_spec, map_spec, vec_spec, vec_spec, vec_spec,
                   vec_spec],
         out_specs=x_spec,
-        out_shape=jax.ShapeDtypeStruct((n_b, seq, n_h * key), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_b, n_h, key, seq), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(_flat(x), bias1, bias2,
-      scale1[:, None], shift1[:, None], scale2[:, None], shift2[:, None])
-    return out.reshape(x.shape)
+    )(_to_kernel(x), bias1, bias2, scale1[..., None], shift1[..., None],
+      scale2[..., None], shift2[..., None])
+    return _from_kernel(out)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -254,8 +295,8 @@ def _bwd_pallas(x, bias1, bias2, scale1, shift1, scale2, shift2, dout,
     n_bt = _block_rows(n_b, seq, key)
     x_spec, map_spec, vec_spec = _specs(seq, key, n_bt)
     f32 = jnp.float32
-    vec3 = (n_h, 1, key)
-    outs = (jax.ShapeDtypeStruct((n_b, seq, n_h * key), x.dtype),  # dx
+    vec3 = (n_h, key, 1)
+    outs = (jax.ShapeDtypeStruct((n_b, n_h, key, seq), x.dtype),   # dx
             jax.ShapeDtypeStruct(bias1.shape, f32),                # dbias1
             jax.ShapeDtypeStruct(bias2.shape, f32),                # dbias2
             jax.ShapeDtypeStruct(vec3, f32),                       # dscale1
@@ -273,12 +314,11 @@ def _bwd_pallas(x, bias1, bias2, scale1, shift1, scale2, shift2, dout,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(_flat(x), bias1, bias2,
-      scale1[:, None], shift1[:, None], scale2[:, None], shift2[:, None],
-      _flat(dout))
+    )(_to_kernel(x), bias1, bias2, scale1[..., None], shift1[..., None],
+      scale2[..., None], shift2[..., None], _to_kernel(dout))
     dx, db1, db2, ds1, dsh1, ds2, dsh2 = res
-    return (dx.reshape(x.shape), db1, db2, ds1[:, 0], dsh1[:, 0],
-            ds2[:, 0], dsh2[:, 0])
+    return (_from_kernel(dx), db1, db2, ds1[..., 0], dsh1[..., 0],
+            ds2[..., 0], dsh2[..., 0])
 
 
 def mixer_chain_reference(x, bias1, bias2, scale1, shift1, scale2, shift2):

@@ -1,0 +1,149 @@
+"""Compiles for a v5e that is described, not attached (libtpu compiles with no
+chip: PERF.md §7, "the off-chip compile").  They size HLO and ask Mosaic to
+accept a kernel; they never give a time.  The topology is described inside a
+fixture, so every xdist worker collects the same tests and only the worker
+that runs this file loads libtpu; keep such tests in this one file."""
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from homebrewnlp_tpu.config import Config
+from homebrewnlp_tpu.models import build
+from homebrewnlp_tpu.models.ctx import Ctx
+from homebrewnlp_tpu.nd import NT
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+GROUP_CELL_CONFIG = os.path.join(REPO, "benchmark", "configs",
+                                 "32mixer_group.json")
+TOKEN_NAMES = ("batch", "sequence", "language_token_patch")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache; keep it out so the next run does not warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def compiled_gradient_hlo(cfg: Config, sharding) -> str:
+    """Optimized HLO of `jax.grad` of the model's loss, parameters and
+    tokens given as shapes on the described chip."""
+    shape = (cfg.train_batch_size, cfg.sequence_length, cfg.token_patch_size)
+    tok = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    def batch(tx, ty):
+        return {"token_x": NT(tx, TOKEN_NAMES), "token_y": NT(ty, TOKEN_NAMES)}
+
+    def collect(tx, ty):
+        ctx = Ctx(cfg, params=None, seed=0, train=False)
+        build(ctx, batch(tx, ty))
+        return ctx.collected
+
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding)
+              for k, v in jax.eval_shape(collect, tok, tok).items()}
+
+    def loss(p, tx, ty):
+        ctx = Ctx(cfg, params=p, train=True, rng=jax.random.key(0))
+        return build(ctx, batch(tx, ty)).loss
+
+    return jax.jit(jax.grad(loss)).lower(params, tok, tok).compile().as_text()
+
+
+def entry_instructions(hlo: str) -> dict:
+    """name -> (opcode, operand names, the instruction's text) of the entry
+    computation."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo, re.S | re.M).group(1)
+    out = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)", line)
+        if not m:
+            continue
+        head = m.group(2).split(", metadata=")[0]
+        call = re.search(r"[\]})] ([\w\-]+)\((.*)", head)
+        out[m.group(1)] = (call.group(1), re.findall(r"%([\w.\-]+)",
+                                                     call.group(2)), line)
+    return out
+
+
+def test_fused_mixer_block_crosses_its_kernels_without_a_copy(
+        one_chip, no_compile_cache, monkeypatch):
+    """The only test that sees a layout.  XLA stores the group model's
+    stream as [B,S,H,K]{1,3,2,0}, physically [B,H,K,S]; ops/pallas_mixer
+    hands its kernels that order so both transposes at the call boundary are
+    bitcasts.  The day a change elsewhere makes XLA hold the stream another
+    way, activation-sized copies come back under the fused block (192 an
+    update and 15% of the 32mixer_group.train step before PR 26) and this
+    fails; so does a rename of the two calls, which
+    benchmark/layer_metrics/mixer_block_roofline.json finds by name."""
+    import homebrewnlp_tpu.ops as ops
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    with open(GROUP_CELL_CONFIG) as f:
+        raw = {k: v for k, v in json.load(f).items() if k != "benchmark"}
+    depth = 2
+    cfg = Config({**raw, "depth": depth})
+    hlo = compiled_gradient_hlo(cfg, one_chip)
+    insts = entry_instructions(hlo)
+
+    n_b, seq = cfg.train_batch_size, cfg.sequence_length
+    n_h, key = cfg.heads, cfg.features_per_head
+    activation = re.compile(r"bf16\[(%s)\]" % "|".join(
+        ",".join(map(str, dims)) for dims in (
+            (n_b, seq, n_h, key), (n_b, n_h, key, seq), (n_h, n_b, seq, key),
+            (n_b, seq, n_h * key))))
+    fused_block = re.compile(r'op_name="[^"]*/d\d+_1/block_/')
+
+    moved = [line.strip()[:160] for opcode, _, line in insts.values()
+             if opcode in ("copy", "transpose", "copy-start")
+             and activation.match(line.split(" = ", 1)[1])
+             and fused_block.search(line)]
+    assert not moved, moved
+
+    def producer(name):
+        while insts[name][0] in ("bitcast", "get-tuple-element"):
+            name = insts[name][1][0]
+        return insts[name][0]
+
+    kernels = {name: operands for name, (opcode, operands, line)
+               in insts.items()
+               if opcode == "custom-call" and "tpu_custom_call" in line
+               and fused_block.search(line)}
+    # forward + replay + backward a fused block; XLA merges the last
+    # block's replay with the forward it repeats when nothing lies between
+    assert 3 * depth - 1 <= len(kernels) <= 3 * depth, sorted(kernels)
+    for name, operands in kernels.items():
+        assert re.match(r"_(fwd|bwd)_pallas(\.\d+)?$", name), name
+        fed_by = {producer(o) for o in operands
+                  if activation.match(insts[o][2].split(" = ", 1)[1])}
+        assert fed_by and fed_by <= {"fusion", "custom-call"}, (name, fed_by)
+
+    # ops/pallas_mixer's optimization_barrier at work: each reversible block's
+    # input is rebuilt by one subtract (XLA drops the first block's).  Without
+    # the barrier XLA sinks the kernel's transpose through the stream's
+    # residual add, nothing needs the stream itself in memory any more, and
+    # every consumer re-derives it from all earlier blocks' outputs: the
+    # subtracts multiply (18 for 7 at depth 4) and depth 32 needs 29 GB.
+    rebuilt = len(re.findall(r'op_name="[^"]*/body/sub"', hlo))
+    assert rebuilt <= 2 * depth, rebuilt
