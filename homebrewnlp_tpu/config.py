@@ -31,6 +31,15 @@ EXPERTS = "experts"
 ROUTED_EXPERTS = "routed_experts"
 PKM_AXES = "pkm_axes"
 PKM_VALUES = "product_key_value_dim"
+# inner geometry of the kda / mla mixers (their heads and head widths differ
+# from the stream's), the latent K/V, the low-rank gate pairs, the taps of a
+# short convolution and the width of a routed or shared expert
+MIXER_HEADS = "mixer_heads"
+MIXER_KEY = "mixer_key"
+LATENT = "latent"
+LOW_RANK = "low_rank"
+CONV_TAP = "conv_tap"
+EXPERT_INTERMEDIATE = "expert_intermediate"
 # leading axis of stage-stacked pipeline-parallel body parameters; maps to
 # the pipeline mesh axis so each device holds only its stage's weights
 PIPE_STAGE = "pipe_stage"
@@ -44,7 +53,9 @@ from . import nd as _nd  # noqa: E402  (registry import, no cycle: nd is leaf)
 
 _nd.register_axis(BATCH, SEQUENCE, HEADS, KEY, INTERMEDIATE, VOCAB,
                   TOKEN_PATCH, HEIGHT, WIDTH, COLOR_CHANNELS, EXPERTS,
-                  ROUTED_EXPERTS, PKM_AXES, PKM_VALUES, PIPE_STAGE)
+                  ROUTED_EXPERTS, PKM_AXES, PKM_VALUES, PIPE_STAGE,
+                  MIXER_HEADS, MIXER_KEY, LATENT, LOW_RANK, CONV_TAP,
+                  EXPERT_INTERMEDIATE)
 
 
 def anonymize_name(name: str) -> str:
@@ -60,6 +71,20 @@ DTYPES = {
     "float16": jnp.float16,
     "float64": jnp.float64,
 }
+
+
+# keys of an upstream model's own `config.json` that a configuration file
+# carries beside this repo's keys, for the record of what was published:
+# nothing reads them (the block DSL says the same), so they are no typo
+UPSTREAM_KEYS = frozenset((
+    "first_k_dense_replace", "head_dim", "hidden_act", "hidden_size",
+    "intermediate_size", "mla_use_nope", "model_max_length", "model_type",
+    "moe_layer_freq", "moe_renormalize", "moe_router_activation_func",
+    "num_attention_heads", "num_expert_group", "num_experts",
+    "num_experts_per_token", "num_hidden_layers", "num_key_value_heads",
+    "num_nextn_predict_layers", "num_shared_experts", "q_lora_rank",
+    "rope_scaling", "rope_theta", "tie_word_embeddings", "topk_group",
+    "use_grouped_topk"))
 
 
 @dataclasses.dataclass
@@ -315,6 +340,27 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     embedding_stddev=0.04,
     experts=64,
     moe_balance_weight=0.01,  # routed_moe load-balance aux loss (extension)
+    # routed_moe under expert parallelism: `experts` is how many the router
+    # scores; this process holds `experts_held` of them (None = all),
+    # starting at `expert_offset`, and computes their part of the result
+    experts_held=None,
+    expert_offset=0,
+    moe_intermediate_size=None,  # width of one expert (None = intermediate)
+    routed_scaling_factor=1.0,
+    rms_norm_eps=1e-5,
+    # kda: {"num_heads", "head_dim", "short_conv_kernel_size"} as upstream
+    # names them (the two low-rank gate pairs take head_dim as their rank)
+    linear_attn_config=None,
+    # mla (latent K/V attention, no positions)
+    kv_lora_rank=None,
+    qk_nope_head_dim=None,
+    qk_rope_head_dim=None,
+    v_head_dim=None,
+    # false: the table holds one stream-wide row a token, no factorisation
+    factorized_embedding=True,
+    # which block_config entries run at which depth: one list of indices a
+    # depth (None = every entry, in order, at every depth)
+    block_schedule=None,
     pkm_axes=2,
     convolution_size=16,
     scale_by_depth=True,
@@ -488,7 +534,8 @@ class Config:
         self.__dict__.update(_DEFAULTS)
         config = dict(config or {})
         for k, v in config.items():
-            if k not in _DEFAULTS and k not in ("mesh_shape", "layout"):
+            if (k not in _DEFAULTS and k not in ("mesh_shape", "layout")
+                    and k not in UPSTREAM_KEYS):
                 print(f"WARNING: Unknown Config parameter {k}={v!r}")
             setattr(self, k, v)
         self._validate_and_derive()
@@ -892,6 +939,31 @@ class Config:
                                    for c in self.input_block_config]
         self.output_block_config = [BlockConfig.make(c, "checkpoint")
                                     for c in self.output_block_config]
+        every = list(range(len(self.block_config)))
+        if self.block_schedule is None:
+            self.block_schedule = [every] * self.depth
+        self.block_schedule = [[int(c) for c in row]
+                               for row in self.block_schedule]
+        if len(self.block_schedule) != self.depth or any(
+                c not in every for row in self.block_schedule for c in row):
+            raise ValueError(
+                f"block_schedule needs one list of block_config indices "
+                f"(0..{len(every) - 1}) for each of the {self.depth} depths")
+        if self.block_schedule != [every] * self.depth and (
+                self.pipeline_parallel > 1):
+            raise ValueError("pipeline_parallel stacks equal stages: it "
+                             "takes no block_schedule")
+        if not self.factorized_embedding and self.token_patch_size != 1:
+            raise ValueError("factorized_embedding=false holds one stream-"
+                             "wide row a token: token_patch_size must be 1")
+        if self.experts_held is None:
+            self.experts_held = self.experts
+        if not (0 < self.experts_held
+                and 0 <= self.expert_offset
+                and self.expert_offset + self.experts_held <= self.experts):
+            raise ValueError(
+                f"experts_held={self.experts_held} from expert_offset="
+                f"{self.expert_offset} is no share of experts={self.experts}")
 
         # video patch arithmetic (reference dataclass.py:262-271)
         self.time_patch_size = self.sequence_length // self.time_patch
@@ -908,6 +980,8 @@ class Config:
         self.intermediate_size = int(
             self.heads * self.features_per_head * self.intermediate_feed_forward_multiplier)
         self.product_key_value_vectors = self.features_per_head ** 2
+        if self.moe_intermediate_size is None:
+            self.moe_intermediate_size = self.intermediate_size
 
         # dimension registry
         self.dims: typing.Dict[str, int] = {
@@ -916,6 +990,7 @@ class Config:
             HEADS: self.heads,
             KEY: self.features_per_head,
             INTERMEDIATE: self.intermediate_size,
+            EXPERT_INTERMEDIATE: self.moe_intermediate_size,
             VOCAB: self.vocab_size,
             TOKEN_PATCH: self.token_patch_size,
             EXPERTS: self.experts,

@@ -26,6 +26,7 @@ differently-shaped Gumbel noise stream.
 """
 from __future__ import annotations
 
+import logging
 import typing
 
 import jax
@@ -41,6 +42,17 @@ from ..sync import make_lock
 _SEQUENCE_MIXERS = ("cumsum", "cummean", "convolution",
                     "transpose_sequence_features")
 _MAP_FLAGS = ("biased_softmax", "biased_attention_map", "scale_attention_map")
+# mixers that train here and are not served yet (ROADMAP R4, R5): what a
+# cache for each would have to hold
+_NO_CACHE_YET = {
+    "kda": "decoding needs a state cache (one [d_k, d_v] state a head and "
+           "the last taps of q, k and v of each short convolution); without "
+           "it every token rebuilds the whole sequence",
+    "mla": "decoding needs a latent cache (the normed latent and the shared "
+           "key part of every position, with the up-projection absorbed "
+           "into q and the output); without it every token rebuilds the "
+           "whole sequence",
+}
 
 
 def cache_eligible(cfg: Config) -> bool:
@@ -56,6 +68,10 @@ def cache_eligible(cfg: Config) -> bool:
             parts = spec.replace(":", "-").split("-")
             name = parts[0]
             if name in _SEQUENCE_MIXERS:
+                return False
+            if name in _NO_CACHE_YET:
+                logging.getLogger(__name__).info(
+                    "layer %s: %s", name, _NO_CACHE_YET[name])
                 return False
             if name == "attention":
                 # dot_product caches K/V; the learned-map family caches V and

@@ -35,6 +35,8 @@ class ModelOutput(typing.NamedTuple):
     token_loss: typing.Optional[jnp.ndarray]
     frame_out: typing.Optional[NT]
     token_out: typing.Optional[NT]
+    # [routed layers, experts held]: selected pairs that fell on each
+    expert_load: typing.Optional[jnp.ndarray] = None
 
 
 # -- input ------------------------------------------------------------------
@@ -84,13 +86,25 @@ def _input(ctx: Ctx, batch: typing.Dict[str, NT], spatial_ctx: str
     if cfg.use_language:
         txt_src = batch["token_x"]
         base_args = Args(ctx, txt_src, [""])
-        small = int(cfg.intermediate_size * cfg.vocab_weight_factorization)
-        txt, table = gather_embed(base_args(list(cfg.token_embedding)),
-                                  [(VOCAB, cfg.vocab_size), (INTERMEDIATE, small)])
-        ctx.text_input_embedding = table
-        txt = ctx.dropout(txt, cfg.input_dropout)
-        txt = linear_to_features(
-            base_args(txt), [(TOKEN_PATCH, cfg.token_patch_size), (INTERMEDIATE, small)])
+        if cfg.factorized_embedding:
+            small = int(cfg.intermediate_size * cfg.vocab_weight_factorization)
+            txt, table = gather_embed(
+                base_args(list(cfg.token_embedding)),
+                [(VOCAB, cfg.vocab_size), (INTERMEDIATE, small)])
+            ctx.text_input_embedding = table
+            txt = ctx.dropout(txt, cfg.input_dropout)
+            txt = linear_to_features(
+                base_args(txt),
+                [(TOKEN_PATCH, cfg.token_patch_size), (INTERMEDIATE, small)])
+        else:
+            # one stream-wide row a token (the patch holds one token)
+            txt, table = gather_embed(
+                base_args(list(cfg.token_embedding)),
+                [(VOCAB, cfg.vocab_size)]
+                + [(n, cfg.dims[n]) for n in cfg.feature_dims])
+            ctx.text_input_embedding = table
+            txt = ctx.dropout(nd.reduce_sum(txt, reduced=[TOKEN_PATCH]),
+                              cfg.input_dropout)
         for config_idx, config in enumerate(cfg.input_block_config):
             txt = block_part_fn(ctx, config, txt, f"lang_inp{config_idx}")
         if not cfg.use_video:
@@ -134,7 +148,7 @@ def _body(ctx: Ctx, src: NT) -> NT:
                     src.dim_size(dim), fdims)
 
         strategy = cfg.memory_reduction_strategy
-        seq = [(i, c) for i in range(cfg.depth) for c in range(len(cfg.block_config))]
+        seq = [(i, c) for i in range(cfg.depth) for c in cfg.block_schedule[i]]
         attn_starts = []
         acc = ctx.attention_idx
         for i, c in seq:
@@ -196,11 +210,13 @@ def _body(ctx: Ctx, src: NT) -> NT:
                         bctx.scope(_block_scope(i, c)):
                     out = block_part_fn(bctx, conf, x)
                 if with_aux:
-                    # aux losses (routed-MoE balance term) returned as real
-                    # outputs so they cross jax.checkpoint with gradients
-                    # intact; the per-block count is static (set by the
-                    # block's layer specs), so the pytree structure is stable
-                    return out, tuple(bctx.aux_losses)
+                    # aux losses (routed-MoE balance term) and the experts'
+                    # load returned as real outputs so they cross
+                    # jax.checkpoint (the losses with gradients intact); the
+                    # per-block count is static (set by the block's layer
+                    # specs), so the pytree structure is stable
+                    return out, (tuple(bctx.aux_losses),
+                                 tuple(bctx.expert_load))
                 return out
 
             return f
@@ -239,7 +255,8 @@ def _body(ctx: Ctx, src: NT) -> NT:
                 out, aux = jax.checkpoint(f)(p, out)
             else:
                 out, aux = f(p, out)
-            ctx.aux_losses.extend(aux)
+            ctx.aux_losses.extend(aux[0])
+            ctx.expert_load.extend(aux[1])
         return out
 
 
@@ -604,8 +621,9 @@ def build(ctx: Ctx, batch: typing.Dict[str, NT]) -> ModelOutput:
     total = loss_list[0]
     for l in loss_list[1:]:
         total = total + l
+    load = jnp.stack(ctx.expert_load) if ctx.expert_load else None
     return ModelOutput(total, tuple(loss_list), video_loss, acc, token_loss,
-                       frame_out, token_out)
+                       frame_out, token_out, load)
 
 
 def _pipeline_seq(cfg: Config):

@@ -80,6 +80,9 @@ class Ctx:
         # layer-collected auxiliary loss terms (routed-MoE load balance);
         # only propagated out of non-reversible bodies — see _body
         self.aux_losses: typing.List[jnp.ndarray] = []
+        # pairs that fell on each held expert, one vector a routed layer;
+        # leaves the body as aux_losses does, for the step's counters
+        self.expert_load: typing.List[jnp.ndarray] = []
         self.param_count = 0
 
     @property

@@ -1,0 +1,306 @@
+"""Block parts of sparse-expert hybrids of linear and latent attention.
+
+Five layers of the block DSL, each declared once (ROADMAP D8, R2, R3, R5):
+
+- ``rms_norm[-scale]``: a norm without centering, over the stream's features;
+- ``gated_feed_forward[-in:<act>]``: ``(act(x W_gate) * (x W_up)) W_down``;
+- ``kda``: the gated delta-rule mixer with a decay for every channel, short
+  causal convolutions on q, k and v, and a gated norm on its output
+  (``ops/delta_rule.py``);
+- ``mla``: causal softmax attention whose keys and values are expanded from
+  one low-rank latent a token, with no positions (``ops/block_attention.py``);
+- ``routed_moe[-topk<k>][-sigmoid][-bias][-gated][-shared<n>][-in:<act>]``:
+  the one routed expert layer, with nothing dropped (``ops/grouped_ffn.py``).
+
+The mixers' heads and head widths are their own (``linear_attn_config``,
+``qk_nope_head_dim`` ...): the stream's ``(heads, features_per_head)`` pair
+says how wide the residual is, not how wide a layer is inside.  Serving these
+layers is out of scope here: ``infer/kv_cache.py::cache_eligible`` says what
+is missing.
+"""
+from __future__ import annotations
+
+import typing
+
+import jax
+import jax.numpy as jnp
+
+from .. import nd
+from ..config import (CONV_TAP, EXPERT_INTERMEDIATE, INTERMEDIATE, LATENT,
+                      LOW_RANK, MIXER_HEADS, MIXER_KEY, ROUTED_EXPERTS,
+                      SEQUENCE)
+from ..nd import NT
+from ..ops import grouped_ffn as gf
+from ..ops.activations import PLAIN, activate
+from ..ops.block_attention import causal_attention
+from ..ops.delta_rule import chunked_kda
+from .ctx import Args
+from .linear import Dim, linear, normal_var, orthogonal_var
+
+
+#: selected pairs a chunk of the grouped product, as a multiple of the tokens:
+#: a share of 1/32 of the experts under top-8 gets a quarter of this
+EXPERT_CHUNK_TOKENS = 1
+
+
+def _fdims(args: Args) -> typing.List[Dim]:
+    return [(n, args.cfg.dims[n]) for n in args.cfg.feature_dims]
+
+
+def _rms(x, weight, eps: float):
+    """``x * rsqrt(mean(x^2) + eps) * weight`` over the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps
+                             ) * weight.astype(jnp.float32)
+
+
+def _activation(args: Args, default: str) -> str:
+    named = [a[len("in:"):] for a in args if a.startswith("in:")]
+    return named[0] if named else default
+
+
+def _matrix(args: Args, name: str, old: typing.Sequence[Dim],
+            new: typing.Sequence[Dim]) -> NT:
+    return orthogonal_var(args, list(old) + list(new), old, name=name)
+
+
+def _project(args: Args, name: str, x: NT, old: typing.Sequence[Dim],
+             new: typing.Sequence[Dim]) -> NT:
+    """``x`` times a named matrix, contracting ``old``."""
+    out = [n for n in x.names if n not in {o for o, _ in old}] + [
+        n for n, _ in new]
+    return nd.einsum([x, _matrix(args, name, old, new)], out)
+
+
+# -- norm and feed-forward ----------------------------------------------------
+
+def rms_norm(args: Args) -> NT:
+    """``x * rsqrt(mean(x^2) + eps)`` over the stream's features: no
+    centering, no shift; ``scale`` multiplies by a learned weight."""
+    t = args.tensor
+    fdims = _fdims(args)
+    xf = NT(t.x.astype(jnp.float32), t.names)
+    mean_sq = nd.reduce_mean(xf * xf, reduced=[n for n, _ in fdims])
+    out = xf * NT(jax.lax.rsqrt(mean_sq.x + args.cfg.rms_norm_eps),
+                  mean_sq.names)
+    if "scale" in args:
+        p = normal_var(args, fdims, mean=1.0, name="scale")
+        out = out * NT(p.x.astype(jnp.float32), p.names)
+    return NT(out.x.astype(t.x.dtype), out.names).transpose_to(t.names)
+
+
+def _feed_forward(args: Args, width: Dim, act: str, gated: bool = True
+                  ) -> NT:
+    """``(act(x W_gate) * (x W_up)) W_down``, or ``act(x W_in) W_out``."""
+    fdims = _fdims(args)
+    hidden = activate(args([act])(linear(args, fdims, [width])))
+    if gated:
+        hidden = hidden * linear(args, fdims, [width])
+    return linear(args(hidden), [width], fdims)
+
+
+def gated_feed_forward(args: Args) -> NT:
+    return _feed_forward(args, (INTERMEDIATE, args.cfg.intermediate_size),
+                         _activation(args, "silu"))
+
+
+# -- kda ----------------------------------------------------------------------
+
+def _short_conv(x: NT, taps: NT) -> NT:
+    """Causal depthwise convolution over the sequence, one filter a channel:
+    ``y_t = sum_j w_j x_{t - (taps - 1) + j}``."""
+    axis = x.names.index(SEQUENCE)
+    n = taps.dim_size(CONV_TAP)
+    length = x.x.shape[axis]
+    padded = jnp.pad(x.x, [(n - 1, 0) if a == axis else (0, 0)
+                           for a in range(x.x.ndim)])
+    w = taps.x.astype(x.x.dtype)
+    out = sum(jax.lax.slice_in_dim(padded, j, j + length, axis=axis) * w[j]
+              for j in range(n))
+    return NT(out, x.names)
+
+
+def kda(args: Args) -> NT:
+    """Kimi delta attention: see ``ops/delta_rule.py`` for the recurrence.
+
+        q, k, v = silu(conv(u W_q)), silu(conv(u W_k)), silu(conv(u W_v))
+        q = q / |q| * d^-1/2,  k = k / |k|                   (per head)
+        g = -exp(a_log) * softplus((u W_fa) W_fb + dt_bias)  (per channel)
+        beta = sigmoid(u W_beta)                             (per head)
+        y = (rms_head(o) * sigmoid((u W_ga) W_gb)) W_o
+    """
+    cfg, ctx, u = args.cfg, args.ctx, args.tensor
+    conf = cfg.linear_attn_config
+    heads, width = (MIXER_HEADS, conf["num_heads"]), (MIXER_KEY,
+                                                      conf["head_dim"])
+    taps = (CONV_TAP, conf["short_conv_kernel_size"])
+    rank = (LOW_RANK, conf["head_dim"])
+    fdims = _fdims(args)
+    f32 = jnp.float32
+
+    with ctx.scope("conv"):
+        qkv = []
+        for n in "qkv":
+            mixed = _short_conv(
+                _project(args, f"{n}_proj", u, fdims, [heads, width]),
+                normal_var(args, [taps, heads, width], taps[1] ** -0.5,
+                           name=f"{n}_conv"))
+            qkv.append(NT(jax.nn.silu(mixed.x), mixed.names))
+        q, k, v = qkv
+    with ctx.scope("gates"):
+        low = _project(args, "decay_down", u, fdims, [rank])
+        decay = _project(args, "decay_up", low, [rank], [heads, width])
+        dt_bias = normal_var(args, [heads, width], 1.0, -2.0, name="dt_bias")
+        a_log = normal_var(args, [heads], 0.5, name="a_log")
+        g = -jnp.exp(a_log.x.astype(f32))[:, None] * jax.nn.softplus(
+            decay.x.astype(f32) + dt_bias.x.astype(f32))
+        beta = jax.nn.sigmoid(_project(args, "beta", u, fdims, [heads]
+                                       ).x.astype(f32))
+        low = _project(args, "out_down", u, fdims, [rank])
+        gate = jax.nn.sigmoid(_project(args, "out_up", low, [rank],
+                                       [heads, width]).x.astype(f32))
+    with ctx.scope("chunk_scan"):
+        def unit(x):
+            x = x.astype(f32)
+            return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1,
+                                             keepdims=True) + 1e-6)
+        kind = u.x.dtype
+        o = chunked_kda((unit(q.x) * width[1] ** -0.5).astype(kind),
+                        unit(k.x).astype(kind), v.x, g, beta)
+    with ctx.scope("out"):
+        scale = normal_var(args, [width], mean=1.0, name="norm_scale")
+        o = NT((_rms(o, scale.x, cfg.rms_norm_eps) * gate).astype(kind),
+               q.names)
+        return _project(args, "proj", o, [heads, width], fdims
+                        ).transpose_to(u.names)
+
+
+# -- mla ----------------------------------------------------------------------
+
+def mla(args: Args) -> NT:
+    """Latent K/V attention without positions, for training (K and V are
+    expanded from the latent; the absorbed form is a serving matter):
+
+        q = u W_q -> [heads, nope + rope]
+        c = u W_kva;  c_kv = rms(c[:rank]),  k_pe = c[rank:]   (no rotation)
+        [k_nope, v] = c_kv W_kvb;  k_h = [k_nope_h, k_pe]
+        y = concat_h softmax(q_h k_h^T / sqrt(nope + rope) + causal) v_h W_o
+    """
+    cfg, ctx, u = args.cfg, args.ctx, args.tensor
+    nope, rope, v_dim, rank = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                               cfg.v_head_dim, cfg.kv_lora_rank)
+    heads = (MIXER_HEADS, cfg.heads)
+    fdims = _fdims(args)
+    kind = u.x.dtype
+    q = _project(args, "q_proj", u, fdims, [heads, (MIXER_KEY, nope + rope)])
+    c = _project(args, "kv_down", u, fdims, [(LATENT, rank + rope)])
+    scale = normal_var(args, [(LATENT, rank)], mean=1.0, name="latent_norm")
+    c_kv = NT(_rms(c.x[..., :rank], scale.x, cfg.rms_norm_eps).astype(kind),
+              c.names)
+    kv = _project(args, "kv_up", c_kv, [(LATENT, rank)],
+                  [heads, (MIXER_KEY, nope + v_dim)])
+    k_pe = jnp.broadcast_to(c.x[..., None, rank:],
+                            kv.x.shape[:-1] + (rope,))
+    k = jnp.concatenate([kv.x[..., :nope], k_pe], -1)
+    o = causal_attention(q.x * (nope + rope) ** -0.5, k, kv.x[..., nope:])
+    return _project(args, "out_proj", NT(o, kv.names), [heads,
+                                                        (MIXER_KEY, v_dim)],
+                    fdims).transpose_to(u.names)
+
+
+# -- routed experts -----------------------------------------------------------
+
+def routed_mixture_of_experts(args: Args) -> NT:
+    """Top-k routed experts under expert parallelism.
+
+    The router scores all ``cfg.experts`` (``sigmoid``, else softmax, in
+    float32), picks the top k of score plus a selection ``bias`` that takes
+    no gradient, and weighs each pick by its score over the picks' sum times
+    ``routed_scaling_factor``.  This process holds experts ``expert_offset ..
+    expert_offset + experts_held`` and computes their part of the result for
+    every (token, expert) pair that fell on them, whatever the imbalance:
+    nothing is dropped and no capacity exists.  What the other experts would
+    have added is left out: the sum over all shares is the whole layer, and
+    on one chip the layer runs without its exchange.  ``shared<n>`` adds n
+    experts every token takes; ``gated`` makes every expert a gated
+    feed-forward (three matrices), else ``in:<act>`` (relu) between two.
+
+    With ``moe_balance_weight > 0`` a Switch-style balance term (1.0 at a
+    uniform load) joins ``ctx.aux_losses``; the load of each held expert
+    joins ``ctx.expert_load`` for the step's counters.
+    """
+    cfg, ctx, t = args.cfg, args.ctx, args.tensor
+    topk, shared = 1, 0
+    for extra in args.name_extras:
+        if extra.startswith("topk"):
+            topk = int(extra[len("topk"):])
+        elif extra.startswith("shared"):
+            shared = int(extra[len("shared"):])
+    topk = min(topk, cfg.experts)
+    gated = "gated" in args
+    act = _activation(args, "silu" if gated else "relu")
+    fdims = _fdims(args)
+    fnames = [n for n, _ in fdims]
+    held = (ROUTED_EXPERTS, cfg.experts_held)
+    inter = (EXPERT_INTERMEDIATE, cfg.moe_intermediate_size)
+    f32 = jnp.float32
+
+    token_axes = [n for n in t.names if n not in fnames]
+    xt = t.transpose_to(token_axes + fnames)
+    width = cfg.heads * cfg.features_per_head
+    x = xt.x.reshape(-1, width)                                # [N, D]
+    tokens = x.shape[0]
+
+    gate_w = normal_var(args, fdims + [(ROUTED_EXPERTS, cfg.experts)],
+                        cfg.embedding_stddev, name="router")
+    bias = (normal_var(args, [(ROUTED_EXPERTS, cfg.experts)], 0.0,
+                       name="router_bias") if "bias" in args else None)
+    # one stack a matrix of the experts held: in (gate), [up], out (down)
+    stacks = [_stack(args, [held] + fdims + [inter], fdims).x.reshape(
+        held[1], width, -1) for _ in range(2 if gated else 1)]
+    stacks.append(_stack(args, [held, inter] + fdims, [inter]).x.reshape(
+        held[1], -1, width))
+
+    with ctx.scope("router"):
+        logits = nd.einsum_f32("nd,de->ne", x, gate_w.x.reshape(width, -1))
+        scores = (jax.nn.sigmoid(logits) if "sigmoid" in args
+                  else jax.nn.softmax(logits, -1))
+        ranked = scores if bias is None else scores + jax.lax.stop_gradient(
+            bias.x.astype(f32))
+        _, picked = jax.lax.top_k(ranked, topk)                 # [N, k]
+        weight = jnp.take_along_axis(scores, picked, -1)
+        weight = weight / jnp.maximum(jnp.sum(weight, -1, keepdims=True),
+                                      1e-9) * cfg.routed_scaling_factor
+        if cfg.moe_balance_weight > 0:
+            load = jnp.zeros((cfg.experts,), f32).at[picked.reshape(-1)].add(
+                1.0) / tokens
+            share = scores / jnp.maximum(jnp.sum(scores, -1, keepdims=True),
+                                         1e-9)
+            ctx.aux_losses.append(f32(cfg.moe_balance_weight) * cfg.experts
+                                  * jnp.sum(load * jnp.mean(share, 0)) / topk)
+    with ctx.scope("dispatch"):
+        chunk = min(int(EXPERT_CHUNK_TOKENS * tokens), tokens * topk)
+        routing = gf.route(picked, cfg.expert_offset, held[1], chunk)
+        ctx.expert_load.append(routing.counts)
+    with ctx.scope("experts"):
+        def expert(rows, sizes, *mats):
+            hidden = PLAIN[act](jax.lax.ragged_dot(rows, mats[0], sizes))
+            if gated:
+                hidden = hidden * jax.lax.ragged_dot(rows, mats[1], sizes)
+            return jax.lax.ragged_dot(hidden, mats[-1], sizes)
+
+        y = gf.grouped_ffn(expert, chunk, x, tuple(stacks), weight, routing)
+    out = NT(y.reshape(xt.x.shape), xt.names)
+    if shared:
+        with ctx.scope("shared"):
+            every = _feed_forward(
+                args(xt), (EXPERT_INTERMEDIATE, shared * inter[1]), act, gated)
+        with ctx.scope("combine"):
+            out = out + every.transpose_to(out.names)
+    return out.transpose_to(t.names)
+
+
+def _stack(args: Args, dims: typing.Sequence[Dim],
+           fan_in: typing.Sequence[Dim]) -> NT:
+    return args.ctx.scoped("orthogonal_var", orthogonal_var, args, dims,
+                           fan_in)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from .. import nd
 from ..config import (HEADS, INTERMEDIATE, KEY, PKM_AXES, PKM_VALUES,
-                      ROUTED_EXPERTS, SEQUENCE, anonymize_name)
+                      SEQUENCE, anonymize_name)
 from ..nd import NT
 from ..ops.activations import ACTIVATIONS, activate
 from .ctx import Args
@@ -25,11 +25,6 @@ from .linear import (Dim, get_intermediate, linear, linear_shapes, normal_var,
                      orthogonal_var, scalar_var, wrapped_linear)
 
 ATTENTION_DIM = typing.NamedTuple("AttentionDim", (("index", int), ("dim", str)))
-
-# layer-local scratch axis: the routed-MoE dispatch flattens all non-group
-# token axes into one row axis ("_rows", anonymized: never sharded)
-nd.register_axis("rows")
-
 
 # -- shape helpers ----------------------------------------------------------
 
@@ -149,139 +144,6 @@ def group_linear(args: Args) -> NT:
     anon = [(HEADS, cfg.heads), (anonymize_name(KEY), cfg.features_per_head)]
     out = linear(args("group"), fdims, anon)
     return out.rename(anonymize_name(KEY), KEY).transpose_to(args.tensor.names)
-
-
-def routed_mixture_of_experts(args: Args) -> NT:
-    """Top-k routed MoE with expert parallelism — the all-to-all dispatch
-    extension SURVEY.md §2.12 names (the reference only has the dense soft
-    MoE, basic.py:37-44).
-
-    GShard/Switch-style dense dispatch with BATCH as the routing group axis
-    (GShard's [G, S, E, C] layout): per batch row, gate -> top-k expert
-    choices -> capacity-bounded one-hot dispatch/combine tensors -> per-
-    expert FFN.  Capacity is per (group, expert), so dispatch memory is
-    linear in tokens, and the group axis stays data-sharded.  Experts shard
-    over the DATA mesh axis (parallel/sharding.py ROUTED_EXPERTS rule) while
-    features stay head-sharded on the model axis; the dispatch/combine
-    einsums between token-sharded and expert-sharded layouts make GSPMD emit
-    the token<->expert all-to-all over ICI — no hand-written collectives.
-
-    DSL: ``routed_moe[-topk<k>][-capacity<f>][-in:<act>]``, e.g.
-    ``routed_moe-topk2-capacity1.5-in:relu`` (activation defaults to relu).
-    Dropped tokens (expert over capacity) pass through with a zero expert
-    contribution (their residual path is the block skip).  Combine weights
-    are normalized over the selected k, so with identical experts the layer
-    reduces exactly to one FFN — the property the parity test checks.
-
-    A Switch-style load-balance auxiliary loss (E * sum_e f_e*P_e per group,
-    scaled by ``cfg.moe_balance_weight``) is collected via ``ctx.aux_losses``
-    and added to the first loss term.  Under ``memory_reduction_strategy``
-    "none" it is collected directly; under "checkpoint" it is threaded
-    through ``jax.checkpoint`` as a real block output.  The reversible
-    strategies (revnet/momentum) cannot carry it across their custom_vjp
-    boundary, so config validation rejects that combination whenever
-    ``moe_balance_weight > 0`` (config.py)."""
-    from ..parallel.sharding import constraint
-    cfg = args.cfg
-    ctx = args.ctx
-    t = args.tensor
-    topk = 1
-    cap_factor = 1.25
-    for extra in args.name_extras:
-        if extra.startswith("topk"):
-            topk = int(extra[len("topk"):])
-        elif extra.startswith("capacity"):
-            cap_factor = float(extra[len("capacity"):])
-    n_exp = cfg.experts
-    topk = min(topk, n_exp)
-
-    fdims = [(n, cfg.dims[n]) for n in cfg.feature_dims]
-    fnames = [n for n, _ in fdims]
-    inter = (INTERMEDIATE, cfg.intermediate_size)
-    re_dim = (ROUTED_EXPERTS, n_exp)
-    group_axis = t.names[0]  # batch: the GShard routing group
-
-    # flatten the remaining non-feature axes into one row axis per group
-    token_axes = [n for n in t.names if n not in fnames]
-    assert token_axes[0] == group_axis
-    xt = t.transpose_to(token_axes + fnames)
-    lead = xt.x.shape[:len(token_axes)]
-    n_groups = lead[0]
-    rows = 1
-    for s in lead[1:]:
-        rows *= s
-    feat_shape = xt.x.shape[len(token_axes):]
-    x = NT(xt.x.reshape((n_groups, rows) + feat_shape),
-           (group_axis, "_rows") + tuple(fnames))
-
-    # gate (f32 for a stable softmax over experts)
-    gate_w = normal_var(args, fdims + [re_dim], cfg.embedding_stddev,
-                        name="router")
-    logits = nd.einsum([x, gate_w], (group_axis, "_rows", ROUTED_EXPERTS)
-                       ).astype(jnp.float32)
-    probs = jax.nn.softmax(logits.x, axis=-1)  # [G, S, E]
-    top_p, top_idx = jax.lax.top_k(probs, topk)  # [G, S, k]
-    top_p = top_p / jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9)
-
-    capacity = max(1, int(rows * topk * cap_factor / n_exp))
-    dispatch = jnp.zeros((n_groups, rows, n_exp, capacity), jnp.float32)
-    combine = jnp.zeros((n_groups, rows, n_exp, capacity), jnp.float32)
-    counts = jnp.zeros((n_groups, n_exp), jnp.int32)
-    for j in range(topk):  # static unroll over the k slots
-        onehot = jax.nn.one_hot(top_idx[..., j], n_exp, dtype=jnp.float32)
-        pos = jnp.cumsum(onehot, axis=1) - onehot + counts[:, None, :]
-        pos_tok = jnp.sum(pos * onehot, axis=-1).astype(jnp.int32)  # [G, S]
-        keep = (pos_tok < capacity).astype(jnp.float32)
-        slot = jax.nn.one_hot(jnp.minimum(pos_tok, capacity - 1), capacity,
-                              dtype=jnp.float32)
-        d = onehot[..., None] * slot[..., None, :] * keep[..., None, None]
-        dispatch = dispatch + d
-        combine = combine + d * top_p[..., j, None, None]
-        counts = counts + jnp.sum(onehot, axis=1).astype(jnp.int32)
-
-    if cfg.moe_balance_weight > 0:
-        # Switch-style balance: E * sum_e (fraction dispatched to e) *
-        # (mean router prob of e), averaged over groups; 1.0 at uniform
-        frac = jnp.mean(dispatch.sum(-1), axis=1)        # [G, E]
-        mean_p = jnp.mean(probs, axis=1)                 # [G, E]
-        balance = n_exp * jnp.mean(jnp.sum(frac * mean_p, -1)) / topk
-        ctx.aux_losses.append(
-            jnp.float32(cfg.moe_balance_weight) * balance)
-
-    cdtype = cfg.calculation_dtype
-    disp_names = (group_axis, "_rows", ROUTED_EXPERTS, "_expert_capacity")
-    disp = NT(dispatch.astype(cdtype), disp_names)
-    comb = NT(combine.astype(cdtype), disp_names)
-
-    # dispatch tokens to expert shards: the group axis becomes anonymous on
-    # the expert side (each expert shard holds tokens from every group), so
-    # GSPMD emits the all-to-all over the data axis
-    e_names = (ROUTED_EXPERTS, anonymize_name(group_axis), "_expert_capacity")
-    expert_in = nd.einsum([disp.rename(group_axis, anonymize_name(group_axis)),
-                           x.rename(group_axis, anonymize_name(group_axis))],
-                          e_names + tuple(fnames))
-    if ctx.mesh is not None:
-        expert_in = constraint(expert_in, ctx.mesh)
-
-    w_in = args.ctx.scoped(
-        "orthogonal_var", orthogonal_var, args,
-        [re_dim] + fdims + [inter], fdims)
-    w_out = args.ctx.scoped(
-        "orthogonal_var", orthogonal_var, args,
-        [re_dim, inter] + fdims, [inter])
-    h = nd.einsum([expert_in, w_in], e_names + (INTERMEDIATE,))
-    acts = [a[len("in:"):] for a in args.name_extras if a.startswith("in:")]
-    h = activate(args(acts or ["relu"])(h))
-    expert_out = nd.einsum([h, w_out], e_names + tuple(fnames))
-    if ctx.mesh is not None:
-        expert_out = constraint(expert_out, ctx.mesh)
-
-    # combine back to token-sharded layout (second all-to-all)
-    y = nd.einsum(
-        [comb.rename(group_axis, anonymize_name(group_axis)), expert_out],
-        (anonymize_name(group_axis), "_rows") + tuple(fnames))
-    out = NT(y.x.reshape(lead + feat_shape), tuple(token_axes + fnames))
-    return out.transpose_to(t.names)
 
 
 def sum_heads(args: Args) -> NT:

@@ -12,7 +12,7 @@ from ..config import BlockConfig
 from ..nd import NT
 from ..ops.activations import activate
 from .ctx import Args, Ctx
-from . import layers
+from . import hybrid, layers
 
 
 def _get_block_part(block_part_config: BlockConfig, ctx: Ctx, block_input: NT) -> NT:
@@ -39,7 +39,9 @@ def _get_block_part(block_part_config: BlockConfig, ctx: Ctx, block_input: NT) -
             args = Args(ctx, out, extras, idx == len(block_part_config.layer))
             out = ctx.scoped(name + "_", LAYER_FUNCTIONS[name], args)
     if block_part_config.skip and block_part_config.memory_reduction_strategy in ("none", "checkpoint"):
-        out = out + block_input
+        # a scope of its own: what sits bare under `block_` reads as a fused
+        # block's kernel (obs/profile.py::step_scope)
+        out = ctx.scoped("skip_", lambda: out + block_input)
     return out
 
 
@@ -87,7 +89,10 @@ LAYER_FUNCTIONS: typing.Dict[str, typing.Callable[[Args], NT]] = {
     "transpose_sequence_features": layers.transpose_sequence_features,
     "bottleneck_group_linear": layers.bottleneck_group_linear,
     "sum_heads": layers.sum_heads,
-    # extension: top-k routed MoE with expert-parallel all-to-all dispatch
-    # (SURVEY.md §2.12 row EP; the reference only has the dense soft MoE)
-    "routed_moe": layers.routed_mixture_of_experts,
+    # extensions (models/hybrid.py; the reference has none of them)
+    "rms_norm": hybrid.rms_norm,
+    "gated_feed_forward": hybrid.gated_feed_forward,
+    "kda": hybrid.kda,
+    "mla": hybrid.mla,
+    "routed_moe": hybrid.routed_mixture_of_experts,
 }

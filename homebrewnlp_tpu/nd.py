@@ -264,6 +264,16 @@ def einsum(inputs: typing.Sequence[NT], out_names: typing.Sequence[str],
     return NT(x, out_names)
 
 
+def einsum_f32(spec: str, *arrays, precision=None):
+    """``jnp.einsum`` over plain arrays that adds up in float32 and returns
+    float32, under :func:`einsum`'s rule for half-precision operands."""
+    if (arrays[0].dtype in (jnp.bfloat16, jnp.float16)
+            and jax.default_backend() not in ("tpu", "gpu")):
+        arrays = [a.astype(jnp.float32) for a in arrays]
+    return jnp.einsum(spec, *arrays, precision=precision,
+                      preferred_element_type=jnp.float32)
+
+
 def _reduce(t: NT, fn, reduced: typing.Optional[typing.Sequence[str]] = None,
             out_names: typing.Optional[typing.Sequence[str]] = None) -> NT:
     if reduced is None:

@@ -242,7 +242,7 @@ _BLOCK_RE = re.compile(r"^d\d+_\d+$")
 _BLOCK_SCOPE_RE = re.compile(r"^block_\d*$")
 #: nd gives every layer scope a use counter: ``norm_``, ``norm_1``
 _COUNTER_RE = re.compile(r"_\d*$")
-_LAYER_OF_TOKEN = {"norm": "norm",
+_LAYER_OF_TOKEN = {"norm": "norm", "rms_norm": "norm",
                    "bottleneck_group_linear": "group_linear",
                    "attention": "map", "activation": "map"}
 _TRANSFORM_RE = re.compile(r"^(jvp|transpose)\(")
@@ -260,10 +260,16 @@ def _pass_of_parts(parts: typing.Sequence[str]) -> str:
         return "forward"
     if "rematted_computation" in parts:
         return "remat"
-    for p in parts[1:]:
+    for at, p in enumerate(parts[1:], 2):
         inner = _TRANSFORM_RE.match(p)
         if inner is not None:
-            return "replay" if inner.group(1) == "jvp" else "backward"
+            # under the plain `checkpoint` strategy the body is one jvp of
+            # its own, and jax.checkpoint names its transposed half
+            # `checkpoint/...`: no forward runs there but the recompute
+            # (`rematted_computation`, above)
+            replay = (inner.group(1) == "jvp"
+                      and "checkpoint" not in parts[at:])
+            return "replay" if replay else "backward"
     return "backward"
 
 
@@ -295,8 +301,9 @@ def step_scope(op_name: str
     ``layer``: the nd layer scope under ``d<i>_<j>/block_`` with its use
     counter dropped and folded by :data:`_LAYER_OF_TOKEN` (``norm_1`` ->
     ``norm``, ``bottleneck_group_linear_`` -> ``group_linear``,
-    ``attention_`` / ``activation_`` -> ``map``; any other layer scope keeps
-    its own name); ``optimizer``; ``input`` / ``output`` / ``loss``;
+    ``attention_`` / ``activation_`` -> ``map``, ``rms_norm_`` -> ``norm``;
+    any other layer scope keeps its own name: ``kda``, ``mla``,
+    ``routed_moe``, ``gated_feed_forward``); ``optimizer``; ``input`` / ``output`` / ``loss``;
     ``body`` for the reversible chain's own glue between blocks (residual
     sums, the cotangent squash); ``other`` for step-level glue with no
     model scope (gradient norm, clipping, argument copies).  Whatever sits
@@ -321,7 +328,10 @@ def step_scope(op_name: str
     backward rule lands (``transpose(transpose(jvp(gpt)))/.../
     jit(_bwd_pallas)``).  Under ``jax.checkpoint`` the transposed half
     holds both the recompute, which JAX marks ``rematted_computation``
-    (``remat``), and the transposed instructions (``backward``).  Counted
+    (``remat``), and the transposed instructions (``backward``); with the
+    body under the plain ``checkpoint`` strategy those read
+    ``transpose(jvp(gpt))/body/jvp(gpt)/body/checkpoint/gpt/...``, an inner
+    ``jvp`` that replays nothing.  Counted
     on the optimized HLO of both block layouts (tests/graftprof_test.py):
     XLA drops the first block's replay under remat (nothing reads the
     reconstructed input) and merges the last block's with the forward it

@@ -35,18 +35,20 @@ def softsign(x):
     return x / (1 + jnp.abs(x))
 
 
-ACTIVATIONS = {
-    "relu": _wrap(jax.nn.relu),
-    "sigmoid": _wrap(jax.nn.sigmoid),
-    "tanh": _wrap(jnp.tanh),
-    "gelu": _wrap(jax.nn.gelu),
-    "lecun_tanh": _wrap(lecun_tanh),
-    "silu": _wrap(jax.nn.silu),
-    "mish": _wrap(mish),
-    "mtf_mish": _wrap(mish),
-    "softsign": _wrap(softsign),
-    "exp": _wrap(jnp.exp),
+#: name -> function over plain arrays
+PLAIN = {
+    "relu": jax.nn.relu,
+    "sigmoid": jax.nn.sigmoid,
+    "tanh": jnp.tanh,
+    "gelu": jax.nn.gelu,
+    "lecun_tanh": lecun_tanh,
+    "silu": jax.nn.silu,
+    "mish": mish,
+    "mtf_mish": mish,
+    "softsign": softsign,
+    "exp": jnp.exp,
 }
+ACTIVATIONS = {name: _wrap(fn) for name, fn in PLAIN.items()}
 
 
 def activate(args) -> NT:

@@ -153,6 +153,13 @@ class Trainer:
                 m["video_loss"] = o.video_loss
             if o.accuracy is not None:
                 m["accuracy"] = o.accuracy
+            if o.expert_load is not None:
+                # routed experts held here: the selected pairs that fell on
+                # them, and the largest and mean load of one, this update
+                m["expert_pairs_held"] = jnp.sum(o.expert_load)
+                m["expert_load_max"] = jnp.max(o.expert_load)
+                m["expert_load_mean"] = jnp.mean(
+                    o.expert_load.astype(jnp.float32))
             return m
 
         # device telemetry (obs/device_telemetry.py): in-graph numerics and

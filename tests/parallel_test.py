@@ -224,22 +224,30 @@ def _routed_cfg(**over):
                 optimizer="adam-learning_rate", learning_rate=1e-2,
                 intermediate_feed_forward_multiplier_multiplier=0.5,
                 block_config=[{"layer": ["norm-shift-scale",
-                                         "routed_moe-topk2-capacity8"]}])
+                                         "routed_moe-topk2"]}])
     base.update(over)
     from homebrewnlp_tpu.config import Config
     return Config(base)
 
 
-def test_routed_moe_identical_experts_reduce_to_ffn(eight_devices):
-    """With every expert holding the same weights and ample capacity, the
-    routed layer must equal a single FFN exactly (combine weights are
-    normalized over the selected k)."""
+@pytest.mark.parametrize("routing", ["as_initialised", "all_to_two"])
+def test_routed_moe_identical_experts_reduce_to_ffn(eight_devices, routing):
+    """With every expert holding the same weights, the routed layer must
+    equal a single FFN exactly (combine weights are normalized over the
+    selected k), whatever the load: under `all_to_two` the router sends
+    every token to experts 0 and 1, which a capacity would have dropped
+    (128 tokens each where the mean load is 64); nothing is."""
     import jax.numpy as jnp
     from homebrewnlp_tpu.models import build, init_params
     from homebrewnlp_tpu.models.ctx import Ctx
     cfg = _routed_cfg()
     batch = text_batch(cfg)
     params, axes = init_params(cfg, batch)
+    if routing == "all_to_two":
+        (router,) = [k for k in params if k.endswith("routed_moe_/router")]
+        # a zero router scores every expert alike, and top-k breaks the
+        # tie by index
+        params[router] = jnp.zeros_like(params[router])
     w_in = [k for k in params if "routed_moe" in k and "orthogonal_var/" in k]
     w_out = [k for k in params if "routed_moe" in k and "orthogonal_var1/" in k]
     assert w_in and w_out, sorted(k for k in params if "routed" in k)
@@ -262,6 +270,10 @@ def test_routed_moe_identical_experts_reduce_to_ffn(eight_devices):
         build(ctx, batch)
     finally:
         registry.LAYER_FUNCTIONS["routed_moe"] = orig
+    load = np.asarray(ctx.expert_load[0])
+    assert load.sum() == 2 * 8 * 16
+    if routing == "all_to_two":
+        assert load.tolist() == [128, 128, 0, 0]
 
     x = np.asarray(rec["in"].x, np.float32)          # [b, s, h, k]
     wi = np.asarray(params[w_in[0]], np.float32)     # [E, h, k, m]
@@ -957,7 +969,7 @@ def test_pipeline_1f1b_routed_moe(eight_devices):
         depth=2, train_batch_size=16, heads=2, experts=4,
         block_config=[{"layer": ["norm-shift-scale", "feed_forward-in:relu"]},
                       {"layer": ["norm-shift-scale",
-                                 "routed_moe-topk2-capacity2"]}])
+                                 "routed_moe-topk2"]}])
     with pytest.raises(ValueError, match="gpipe"):
         Config(dict(base, pipeline_parallel=2, pipeline_schedule="gpipe"))
     cfg_f = Config(dict(base, pipeline_parallel=2, pipeline_schedule="1f1b"))
@@ -1028,7 +1040,7 @@ def test_cli_train_1f1b_checkpoint_resume(eight_devices, tmp_path):
         model_path=str(tmp_path / "run"),
         block_config=[
             {"layer": ["norm-shift-scale", "feed_forward-in:relu"]},
-            {"layer": ["norm-shift-scale", "routed_moe-topk2-capacity2"]}])
+            {"layer": ["norm-shift-scale", "routed_moe-topk2"]}])
     cfg_path = tmp_path / "cfg.json"
     cfg_path.write_text(json.dumps(cfg))
 
