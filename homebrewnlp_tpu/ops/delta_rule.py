@@ -28,10 +28,13 @@ tokens and splits every other pair's decay at a point between ``i`` and
 The decay, ``A``, ``P`` and the inverse are float32; the products against the
 state take their operands in the type of ``q`` and add up in float32.
 
-Two scans: the chunks' own work (``A``, ``P``, the inverse) runs ``group``
+Two stages: the chunks' own work (``A``, ``P``, the inverse) runs ``group``
 chunks at a time, each group recomputed in the backward so that the
 ``[C, C, d_k]`` products never outlive their step; the state then walks the
-chunks one by one, keeping only itself a chunk for the backward.
+chunks one by one, keeping only itself a chunk for the backward.  Where the
+head widths are whole lane tiles (multiples of 128) the first stage is the
+pair of Mosaic kernels in ``ops/pallas_kda.py``; every other shape takes the
+scan here, which is also the kernels' oracle.
 """
 from __future__ import annotations
 
@@ -143,12 +146,27 @@ def chunked_kda(q, k, v, g, beta, chunk: int = 32, sub: int = 8,
     result has the type of ``v``."""
     if chunk % sub:
         raise ValueError(f"a chunk of {chunk} is no multiple of {sub}")
-    kind = q.dtype
     b, t, h, d_v = v.shape
     chunk = min(chunk, -(-t // sub) * sub)
     n = -(-t // chunk)
     group = min(group or max(1, 256 // chunk), n)   # 256 tokens a step
     n_pad = -(-n // group) * group
+
+    if not (q.shape[-1] % 128 or d_v % 128 or sub % 8):
+        parts = _kernel_parts(q, k, v, g, beta, chunk, sub, group, n_pad)
+    else:
+        parts = _scan_parts(q, k, v, g, beta, chunk, sub, group, n_pad)
+    out = _walk_state(*parts)
+    out = jnp.moveaxis(out, 0, 1)                                 # [B,N,H,C,d]
+    out = jnp.moveaxis(out, 2, 3).reshape(b, n_pad * chunk, h, d_v)
+    return out[:, :t].astype(v.dtype)
+
+
+def _scan_parts(q, k, v, g, beta, chunk, sub, group, n_pad):
+    """``_walk_state``'s arguments by a scan over groups of chunks: every
+    shape's path, and the kernel's oracle."""
+    kind = q.dtype
+    b, t = q.shape[:2]
     pad = n_pad * chunk - t
 
     def chunks(x):
@@ -168,7 +186,24 @@ def chunked_kda(q, k, v, g, beta, chunk: int = 32, sub: int = 8,
 
     _, parts = jax.lax.scan(within, None, tuple(
         chunks(x) for x in (q, k, v, g, beta)))
-    out = _walk_state(*(x.reshape((n_pad,) + x.shape[2:]) for x in parts))
-    out = jnp.moveaxis(out, 0, 1)                                 # [B,N,H,C,d]
-    out = jnp.moveaxis(out, 2, 3).reshape(b, n_pad * chunk, h, d_v)
-    return out[:, :t].astype(v.dtype)
+    return tuple(x.reshape((n_pad,) + x.shape[2:]) for x in parts)
+
+
+def _kernel_parts(q, k, v, g, beta, chunk, sub, group, n_pad):
+    """The same by ``ops/pallas_kda.py``'s kernels, for head widths that are
+    whole lane tiles: operands heads-major in float32, as ``_scan_parts``
+    casts them, results chunk axis first as the kernel writes them."""
+    from . import pallas_interpret
+    from .pallas_kda import kda_chunks
+    t = beta.shape[1]
+    pad = n_pad * chunk - t
+
+    def heads_major(x):
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x, 2, 1)
+
+    f32 = lambda x: heads_major(x.astype(jnp.float32))
+    *parts, last = kda_chunks(
+        f32(q), f32(k), f32(v), f32(g), f32(beta), jnp.dtype(q.dtype), chunk,
+        sub, group, pallas_interpret())
+    return (*parts, last[..., 0, :])

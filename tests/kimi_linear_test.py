@@ -186,6 +186,46 @@ def test_chunked_delta_rule_matches_the_recurrence(t, decay, chunk, sub):
         np.testing.assert_allclose(mine, theirs, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("t,decay", [(64, 0.1), (128, 5.0), (70, 5.0),
+                                     (20, 1.0)],
+                         ids=["mild", "decay5_whole_span", "ragged",
+                              "shorter_than_chunk"])
+def test_delta_rule_kernels_match_the_scan_and_the_recurrence(t, decay,
+                                                              monkeypatch):
+    """ops/pallas_kda.py's kernels (interpreted here) at a head width of one
+    lane tile, against the scan they replace there and the token-by-token
+    recurrence: outputs and all five gradients.  128 tokens fill the matrix
+    unit's span of four chunks; 64 and 70 walk chunk by chunk."""
+    from homebrewnlp_tpu.ops import delta_rule
+    args = _kda_inputs(t, decay, b=1, n=2, d=128)
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    got = delta_rule.chunked_kda(*args)
+    mine = jax.grad(loss(delta_rule.chunked_kda), range(5))(*args)
+    assert np.all(np.isfinite(got))
+    recurrence = lambda *a: ref._delta_rule(*a, inner=1)
+    np.testing.assert_allclose(got, recurrence(*args), **LAYER)
+    for a, b in zip(mine, jax.grad(loss(recurrence), range(5))(*args)):
+        assert np.all(np.isfinite(a))
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    monkeypatch.setattr(delta_rule, "_kernel_parts", delta_rule._scan_parts)
+    np.testing.assert_allclose(got, delta_rule.chunked_kda(*args), rtol=1e-6,
+                               atol=1e-6)
+    for a, b in zip(mine, jax.grad(loss(delta_rule.chunked_kda), range(5))(
+            *args)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_the_head_width_alone_chooses_the_kernels():
+    """Whole lane tiles take the kernels, forward and backward; every other
+    width keeps the scan."""
+    from homebrewnlp_tpu.ops.delta_rule import chunked_kda
+    for d, kernels in ((8, 0), (128, 2)):
+        args = _kda_inputs(64, 1.0, b=1, n=2, d=d)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(chunked_kda(*a)), range(5)))(*args))
+        assert text.count("pallas_call") == kernels, (d, kernels)
+
+
 def test_kda_layer_matches_the_reference():
     cfg = Config(toy())
     sz = ref.Sizes.from_config(toy())
@@ -336,6 +376,19 @@ def test_step_scope_gives_every_new_layer_its_own_layer_and_pass():
     inner = {p for n in names for p in n.split("/")}
     assert {"conv", "gates", "chunk_scan", "out", "router", "dispatch",
             "experts", "shared", "combine"} <= inner
+    # ops/pallas_kda.py's kernels (the toy width keeps the scan), named as
+    # the v5e compile of the cell's gradient names them
+    step, under = "jit(step_fn)/", "/block_/kda_/chunk_scan/jit(_kda_chunks_"
+    back = step + "transpose(jvp(gpt))/body/jvp(gpt)/body/checkpoint/"
+    for name, pass_ in (
+            (step + "jvp(gpt)/body/gpt/body/d0_0" + under
+             + "fwd)/pallas_call", "forward"),
+            (back + "rematted_computation/gpt/body/d4_0" + under
+             + "fwd)/pallas_call", "remat"),
+            (back + "gpt/body/d4_0" + under + "bwd)/pallas_call",
+             "backward")):
+        assert P.step_scope(name) == (pass_, name.split("/body/")[-1][:4],
+                                      "kda"), name
 
 
 # -- (f) the two configuration files ------------------------------------------

@@ -147,3 +147,75 @@ def test_fused_mixer_block_crosses_its_kernels_without_a_copy(
     # subtracts multiply (18 for 7 at depth 4) and depth 32 needs 29 GB.
     rebuilt = len(re.findall(r'op_name="[^"]*/body/sub"', hlo))
     assert rebuilt <= 2 * depth, rebuilt
+
+
+def test_delta_rule_gradient_runs_two_kernels_and_no_chunk_scan(
+        one_chip, no_compile_cache, monkeypatch):
+    """`jax.grad` of `chunked_kda` at the shape of kimi_linear_48b.train:
+    Mosaic accepts ops/pallas_kda.py's forward and backward, the scan over
+    groups of chunks (its `while` carried bf16[32,8,2,32,32,128]) is gone and
+    only the state's walk loops, and nothing the size of an operand is copied
+    on its own next to a kernel: the heads-major transposes fuse into what
+    makes the operands, the results enter the walk as they are written."""
+    import homebrewnlp_tpu.ops as ops
+    from homebrewnlp_tpu.ops.delta_rule import chunked_kda
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    n_b, seq, n_h, key = 2, 8192, 32, 128
+
+    def shape(kind, *dims):
+        return jax.ShapeDtypeStruct(dims, kind, sharding=one_chip)
+
+    stream = shape(jnp.bfloat16, n_b, seq, n_h, key)
+
+    def loss(q, k, v, g, beta):
+        # operands made by a fusion, as the layer's projections make them
+        out = chunked_kda(q * 2, k * 2, v * 2, g * 2, beta * 2)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    hlo = jax.jit(jax.grad(loss, range(5))).lower(
+        stream, stream, stream, shape(jnp.float32, n_b, seq, n_h, key),
+        shape(jnp.float32, n_b, seq, n_h)).compile().as_text()
+    insts = entry_instructions(hlo)
+    kernels = {name: operands for name, (opcode, operands, line)
+               in insts.items()
+               if opcode == "custom-call" and "tpu_custom_call" in line}
+    assert sorted(re.search(r"jit\((_kda_chunks_\w+)\)", insts[name][2]).group(1)
+                  for name in kernels) == ["_kda_chunks_bwd",
+                                           "_kda_chunks_fwd"], sorted(kernels)
+    carried = [line for opcode, _, line in insts.values()
+               if opcode == "while"]
+    assert carried and not any("bf16[32,8,2,32,32,128]" in line
+                               for line in carried), carried
+
+    def resolve(name):
+        while insts[name][0] in ("bitcast", "get-tuple-element"):
+            name = insts[name][1][0]
+        return name
+
+    def big(name):
+        dims = re.match(r"\(?\w+\[([\d,]*)\]", insts[name][2].split(
+            " = ", 1)[1]).group(1)
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        return size >= n_b * seq * n_h * key
+
+    users = {}
+    for name, (_, operands, _) in insts.items():
+        for o in operands:
+            if o in insts:      # not a called computation
+                users.setdefault(o, []).append(name)
+    for name, operands in kernels.items():
+        around = [resolve(o) for o in operands if o in insts]
+        pending = list(users.get(name, []))
+        while pending:
+            user = pending.pop()
+            if insts[user][0] in ("bitcast", "get-tuple-element"):
+                pending += users.get(user, [])
+            else:
+                around.append(user)
+        moved = [insts[n][2].strip()[:120] for n in around
+                 if (insts[n][0] in ("copy", "transpose", "copy-start")
+                     or n.startswith("copy_"))   # a fusion that only copies
+                 and big(n)]
+        assert not moved, (name, moved)
