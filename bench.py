@@ -260,7 +260,7 @@ def bench_workload(name: str, probe_loss: bool = False) -> dict:
     # counted; pallas kernels are opaque to cost analysis, so the unfused
     # chain is the only honest flop count)
     flops_algo = flops_exec
-    kernel_opaque = bool(cfg.fused_mixer_block or cfg.fused_group_linear)
+    kernel_opaque = bool(cfg.fused_mixer_block)
     if cfg.reversible_remat_blocks or kernel_opaque or cfg.blocked_causal_map:
         from homebrewnlp_tpu.optim import Optimizer
         # blocked_causal_map also resets to 0: the algorithmic count is the
@@ -271,7 +271,6 @@ def bench_workload(name: str, probe_loss: bool = False) -> dict:
                                **WORKLOADS[name],
                                reversible_remat_blocks=False,
                                fused_mixer_block=False,
-                               fused_group_linear=False,
                                blocked_causal_map=0)
         # params/opt-state/axes are identical either way: adopt them from
         # the measured trainer instead of re-initializing on device

@@ -176,13 +176,6 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     multi_loss_strategy="linear",
     memory_reduction_strategy="revnet",
     momentumnet_alpha=0.99,
-    # precision squash (round through the given dtype) on the cotangent
-    # streams BETWEEN reversible blocks during backward ("" = exact).
-    # Measured round 4: under bf16 calculation_dtype the streams are
-    # ALREADY bf16 (bit-identical loss, byte-identical step with
-    # "bfloat16" set — docs/perf/README.md), so this only affects
-    # f32-calculation configs.
-    reversible_cotangent_dtype="",
     # jax.checkpoint each reversible block's backward replay: recompute
     # block internals instead of storing residuals — FLOPs for HBM bytes,
     # a win on bandwidth-bound workloads (docs/perf/README.md round 4)
@@ -192,11 +185,6 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     # lever for the bandwidth-bound mixer workloads, ops/pallas_mixer.py).
     # Single-device only: the GSPMD/sharded paths keep the unfused chain.
     fused_mixer_block=False,
-    # fuse the [norm, bottleneck_group_linear] block into two pallas
-    # fwd+bwd kernel pairs split at the bottleneck activation (the second
-    # bytes lever for the group workload, ops/pallas_group.py).  Same
-    # single-device guard as fused_mixer_block.
-    fused_group_linear=False,
     # quantized-compute scope (ops/quant.py, docs/performance.md
     # "Low-precision compute"): layer-scope substrings whose DSL linears run
     # the W8A8 quantized forward (dynamic in-graph scales, f32-accumulated

@@ -23,6 +23,7 @@ from ..ops.losses import softmax_cross_entropy_with_logits, video_l1_loss
 from ..ops.reversible import make_reversible_chain
 from .ctx import Args, Ctx, DEPTH_TOKEN
 from .embedding import embed, gather, gather_embed, positional_embed
+from .layers import fused_mixer_eligible
 from .linear import linear, linear_from_features, linear_to_features
 from .registry import block_part_fn
 
@@ -229,19 +230,14 @@ def _body(ctx: Ctx, src: NT) -> NT:
             # config validation rejects routed_moe here when
             # moe_balance_weight > 0 (config.py)
             fs = [make_f(k, i, c) for k, (i, c) in enumerate(seq)]
-            cot = (jnp.dtype(cfg.reversible_cotangent_dtype)
-                   if cfg.reversible_cotangent_dtype else None)
             # remat skips fused-kernel blocks: their custom_vjp already
             # stores only inputs, so jax.checkpoint there would re-run the
             # forward kernel for nothing (measured +30 ms on 32mixer_group)
-            from .layers import fused_group_eligible, fused_mixer_eligible
             rb = [cfg.reversible_remat_blocks
                   and not fused_mixer_eligible(ctx, cfg.block_config[c], src)
-                  and not fused_group_eligible(ctx, cfg.block_config[c], src)
                   for _, c in seq]
             chain = make_reversible_chain(fs, mode=strategy,
                                           alpha=cfg.momentumnet_alpha,
-                                          cotangent_dtype=cot,
                                           remat_blocks=rb)
             if strategy == "revnet":
                 y1, y2 = chain(subparams, src, src)

@@ -445,9 +445,9 @@ def _blocked_map_rows(bias_x, val_x, depth: int):
     inherits the same saving in both backward contractions.  Measured
     on-chip at the 32ctx shape: ~25% faster per fwd+bwd call than the
     masked einsum (docs/perf/README.md round 5c); two hand-written pallas
-    variants of the same skip LOSE to XLA here (ops/pallas_attn.py round
-    2, ops/pallas_tri_attn.py round 5) — the win needs XLA's own schedule,
-    just with the rectangle carved smaller.
+    variants of the same skip LOSE to XLA here (docs/perf/README.md rounds
+    2 and 5) — the win needs XLA's own schedule, just with the rectangle
+    carved smaller.
 
     Partial sums accumulate in f32 (one cast at the top, strictly tighter
     than the single-einsum baseline's policy); plain jnp slicing/concat,
@@ -604,15 +604,6 @@ def convolution(args: Args) -> NT:
 
 # -- fused mixer block (pallas bytes lever) ---------------------------------
 
-def _fused_norm_params(args: Args) -> typing.Tuple[NT, NT]:
-    """The norm layer's scale/shift constructor pair, shared by both fused
-    block replays so the two paths cannot diverge from the unfused norm()."""
-    fs = linear_shapes(args)[0]
-    scale = normal_var(args, fs, mean=1.0, name="scale")
-    shift = normal_var(args, fs, mean=0.0, name="shift")
-    return scale, shift
-
-
 MIXER_FUSED_PATTERN = (
     "norm-shift-scale-features-group",
     "attention-biased_attention_map-absolute-input_as_value-shared",
@@ -656,6 +647,13 @@ def fused_mixer_block_part(conf, ctx, x: NT) -> NT:
     cfg = ctx.cfg
     collected: typing.List[NT] = []
 
+    def norm_params(args: Args) -> typing.Tuple[NT, NT]:
+        # the scale/shift pair, built as the unfused norm() builds it
+        fs = linear_shapes(args)[0]
+        scale = normal_var(args, fs, mean=1.0, name="scale")
+        shift = normal_var(args, fs, mean=0.0, name="shift")
+        return scale, shift
+
     def attn_params(args: Args) -> NT:
         ctx.attention_idx += 1
         dim = get_attention_dim(args).dim
@@ -668,7 +666,7 @@ def fused_mixer_block_part(conf, ctx, x: NT) -> NT:
         name, *extras = layer_spec.split("-")
         args = Args(ctx, x, extras, idx == len(specs))
         if name == "norm":
-            collected.append(ctx.scoped("norm_", _fused_norm_params, args))
+            collected.append(ctx.scoped("norm_", norm_params, args))
         elif name == "attention":
             collected.append(ctx.scoped("attention_", attn_params, args))
         else:  # activation: consumes its scope slot, holds no parameters
@@ -686,101 +684,6 @@ def fused_mixer_block_part(conf, ctx, x: NT) -> NT:
         shift1.transpose_to((HEADS, KEY)).x,
         scale2.transpose_to((HEADS, KEY)).x,
         shift2.transpose_to((HEADS, KEY)).x,
-        pallas_interpret(),
-    )
-    return NT(out_x, order).transpose_to(x.names)
-
-
-# -- fused bottleneck-group-linear block (pallas bytes lever #2) ------------
-
-GROUP_FUSED_PATTERN = (
-    "norm-shift-scale-features-group",
-    "bottleneck_group_linear-in:relu-mid:relu-mid:norm-mid:shift-mid:scale"
-    "-mid:features",
-)
-
-
-def fused_group_eligible(ctx, conf, x: NT) -> bool:
-    """The two-kernel pair (ops/pallas_group.py) replaces exactly the group
-    configs' block-1 chain [group norm, bottleneck_group_linear] on an
-    unsharded device, in apply mode, on the plain rank-4 text layout with
-    lane-aligned widths (the block is per-position, so no mask/seq
-    constraint applies — only tiling)."""
-    cfg = ctx.cfg
-    layer = conf.layer if isinstance(conf.layer, (list, tuple)) else None
-    mid = cfg.features_per_head * cfg.group_linear_factor
-    n_rows = (x.dim_size(x.names[0]) * x.dim_size(SEQUENCE)
-              if SEQUENCE in x.names else 0)
-    mesh = ctx.effective_mesh
-    from ..ops import quant
-    return (cfg.fused_group_linear
-            # quantization wins over fusion: the pallas kernels run their
-            # own unquantized matmuls, so a quant-declared block must take
-            # the unfused chain where linear() applies the quantized path
-            # (the graftcheck quant-dtype rule would flag the fallback)
-            and not quant.pattern_quantized(cfg, GROUP_FUSED_PATTERN)
-            and layer is not None and tuple(layer) == GROUP_FUSED_PATTERN
-            and ctx.params is not None and ctx.decode is None
-            and (mesh is None or mesh.size == 1)
-            and x.names[1:] == (SEQUENCE, HEADS, KEY)
-            and x.dim_size(KEY) % 128 == 0
-            and mid % 128 == 0
-            and cfg.intermediate_size % 128 == 0
-            and n_rows % 128 == 0
-            and jax.default_backend() in ("tpu", "cpu"))
-
-
-def fused_group_block_part(conf, ctx, x: NT) -> NT:
-    """Apply the [group norm, bottleneck_group_linear] block through the
-    fused pallas kernel pair.
-
-    The scope walk REPLAYS ``registry._get_block_part`` exactly — the same
-    ``ctx.scoped`` calls in the same order with the same parameter
-    constructors the unfused layers invoke (norm's normal_var pair, then
-    inside the bottleneck scope: linear's scoped orthogonal_var for W1/W2,
-    the mid-norm's normal_var pair, orthogonal_var for W3) — so parameter
-    names, shapes and init are bit-identical to the unfused chain and
-    checkpoints interchange freely between the two paths."""
-    from ..ops import pallas_interpret
-    from ..ops.pallas_group import fused_group_linear_block
-
-    cfg = ctx.cfg
-    anon_key = anonymize_name(KEY)
-    inter = cfg.intermediate_size
-    mid = cfg.features_per_head * cfg.group_linear_factor
-    in_dims = [(HEADS, cfg.heads), (KEY, cfg.features_per_head)]
-    mid_dims = [(HEADS, cfg.heads), (anon_key, mid)]
-
-    def bgl_params(args: Args):
-        w1 = ctx.scoped("orthogonal_var", orthogonal_var, args,
-                        in_dims + [(INTERMEDIATE, inter)], in_dims)
-        old1 = [(INTERMEDIATE, inter)]
-        w2 = ctx.scoped("orthogonal_var", orthogonal_var, args,
-                        old1 + mid_dims, old1)
-        s1 = normal_var(args, mid_dims, mean=1.0, name="scale")
-        h1 = normal_var(args, mid_dims, mean=0.0, name="shift")
-        w3 = ctx.scoped("orthogonal_var", orthogonal_var, args,
-                        mid_dims + in_dims, mid_dims)
-        return w1, w2, s1, h1, w3
-
-    specs = list(conf.layer)
-    norm_spec, bgl_spec = specs
-    norm_args = Args(ctx, x, norm_spec.split("-")[1:], False)
-    scale0, shift0 = ctx.scoped("norm_", _fused_norm_params, norm_args)
-    bgl_args = Args(ctx, x, bgl_spec.split("-")[1:], True)
-    w1, w2, s1, h1, w3 = ctx.scoped("bottleneck_group_linear_", bgl_params,
-                                    bgl_args)
-
-    order = (x.names[0], SEQUENCE, HEADS, KEY)
-    out_x = fused_group_linear_block(
-        x.transpose_to(order).x,
-        w1.transpose_to((HEADS, KEY, INTERMEDIATE)).x,
-        w2.transpose_to((INTERMEDIATE, HEADS, anon_key)).x,
-        w3.transpose_to((HEADS, anon_key, KEY)).x,
-        scale0.transpose_to((HEADS, KEY)).x,
-        shift0.transpose_to((HEADS, KEY)).x,
-        s1.transpose_to((HEADS, anon_key)).x,
-        h1.transpose_to((HEADS, anon_key)).x,
         pallas_interpret(),
     )
     return NT(out_x, order).transpose_to(x.names)

@@ -22,13 +22,6 @@ def _get_block_part(block_part_config: BlockConfig, ctx: Ctx, block_input: NT) -
         # walk, a fraction of the HBM traffic
         out = layers.fused_mixer_block_part(block_part_config, ctx,
                                             block_input)
-    elif layers.fused_group_eligible(ctx, block_part_config, block_input):
-        # the [group norm, bottleneck_group_linear] chain as two pallas
-        # fwd+bwd kernel pairs split at the bottleneck activation
-        # (ops/pallas_group.py) — same parameters, same scope walk, a
-        # fraction of the HBM traffic
-        out = layers.fused_group_block_part(block_part_config, ctx,
-                                            block_input)
     else:
         out = block_input
         for idx, layer in enumerate(block_part_config.layer, 1):

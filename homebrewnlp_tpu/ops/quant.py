@@ -1,9 +1,9 @@
 """Quantized-compute layer: int8/fp8 matmuls for the DSL linear family.
 
 The grouped-mixer workload sits at 0.31 algorithmic MFU and is ABOVE its
-bandwidth bound after the round-5 fusion experiments (ops/pallas_group.py
-header: moving fewer bytes was measured REJECT), so the remaining lever is
-making the MXU math itself cheaper.  TPU MXUs run int8 matmuls at 2-4x the
+bandwidth bound after the round-5 fusion experiments (docs/perf/README.md
+round 5b: moving fewer bytes was measured REJECT), so the remaining lever
+is making the MXU math itself cheaper.  TPU MXUs run int8 matmuls at 2-4x the
 bf16 rate (and fp8 at 2x on v5p+); this module provides the quantized
 forward path behind the ``quant_blocks`` / ``quant_dtype`` config knobs
 (docs/performance.md "Low-precision compute"):
@@ -29,7 +29,7 @@ forward path behind the ``quant_blocks`` / ``quant_dtype`` config knobs
 Default-off contract: with ``quant_blocks`` unset, ``models/linear.py``
 never calls into this module and the graph is bit-identical to the
 pre-quant one (parity-tested at 8 and 300 steps like
-``telemetry_interval=0`` and ``fused_group_linear=False`` before it).
+``telemetry_interval=0`` before it).
 The graftcheck ``quant-dtype`` graph rule pins the complement: an int8/fp8
 op in a config that declares no quant scope — or a declared scope whose
 traced train step contains NO quantized dot (a silent high-precision
@@ -208,10 +208,10 @@ def eligible(cfg, tensor: NT) -> bool:
 
 def pattern_quantized(cfg, layer_specs: typing.Sequence[str]) -> bool:
     """True when any layer of a fused-kernel pattern falls inside the
-    declared quant scope — the fused pallas paths (ops/pallas_group.py /
-    ops/pallas_mixer.py) run their own unquantized matmuls, so fusion must
-    yield to quantization or the declared scope would silently fall back
-    (exactly what the graftcheck quant-dtype rule rejects).
+    declared quant scope — the fused pallas path (ops/pallas_mixer.py)
+    runs its own unquantized matmuls, so fusion must yield to quantization
+    or the declared scope would silently fall back (exactly what the
+    graftcheck quant-dtype rule rejects).
 
     Each layer name is tested as a SYNTHESIZED scope path fragment
     (``block_/<name>_/``) rather than the bare name, so this check agrees

@@ -25,18 +25,12 @@ Pytree = typing.Any
 
 def make_reversible_chain(fs: typing.Sequence[typing.Callable],
                           mode: str = "revnet", alpha: float = 0.99,
-                          cotangent_dtype=None, remat_blocks: bool = False):
+                          remat_blocks: bool = False):
     """Build a reversible chain over residual-branch functions ``fs``.
 
     Each ``fs[i](params_i, x) -> y`` must be shape-preserving and
     deterministic (re-executed during backward).  Returns
     ``chain(params_tuple, x1, x2) -> (y1, y2)``.
-
-    ``cotangent_dtype`` (e.g. ``jnp.bfloat16``) inserts a precision squash
-    on the inter-block cotangent streams during backward: dy1/dy2 are
-    rounded through the reduced dtype between blocks (cast down and back
-    up, so each block's vjp still sees cotangents of its output dtype —
-    vjp rejects a dtype mismatch outright).  None keeps the exact default.
 
     ``remat_blocks`` wraps blocks in ``jax.checkpoint`` for the
     backward's ``jax.vjp`` replay: the replay forward then stores no
@@ -110,10 +104,6 @@ def make_reversible_chain(fs: typing.Sequence[typing.Callable],
         for i in range(len(fs) - 1, -1, -1):
             y1, y2, dy1, dy2, dparams[i] = inv_and_grads(
                 fs[i], params[i], y1, y2, dy1, dy2, remat_flags[i])
-            if cotangent_dtype is not None and i > 0:
-                squash = lambda d: d.astype(cotangent_dtype).astype(d.dtype)
-                dy1 = tsub(squash, dy1)
-                dy2 = tsub(squash, dy2)
         return tuple(dparams), dy1, dy2
 
     chain.defvjp(chain_fwd, chain_bwd)

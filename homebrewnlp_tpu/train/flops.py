@@ -113,7 +113,7 @@ def kernels_opaque(cfg) -> bool:
     whose in-kernel flops XLA cost analysis cannot see — the executed count
     of the fused step is then incomplete (BENCH_r05's
     ``flops_executed_partial`` / ``mfu: null`` failure mode)."""
-    return bool(cfg.fused_mixer_block or cfg.fused_group_linear)
+    return bool(cfg.fused_mixer_block)
 
 
 def unfused_twin_flops(trainer, state, batch) -> float:
@@ -139,7 +139,6 @@ def unfused_twin_flops(trainer, state, batch) -> float:
     from .state import Trainer
     cfg = copy.copy(trainer.cfg)  # knob flip only; derived fields carry over
     cfg.fused_mixer_block = False
-    cfg.fused_group_linear = False
     twin = Trainer(cfg, trainer.mesh)
     twin.axes = trainer.axes
     twin.optimizer = Optimizer(cfg, trainer.axes)

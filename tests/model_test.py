@@ -147,65 +147,11 @@ def test_einsum_f32_accumulation():
     assert nd.einsum([af, bf], ("row", "col")).dtype == jnp.float32
 
 
-def test_pallas_causal_map_attention_parity():
-    """Interpret-mode parity of the (measured-and-rejected) pallas mixer
-    kernel against the production masked einsum (docs/perf/README.md)."""
-    import numpy as np
-
-    from homebrewnlp_tpu.ops.pallas_attn import (_fwd_einsum, _fwd_pallas,
-                                                 causal_map_attention)
-    k1, k2 = jax.random.split(jax.random.key(0))
-    bias = jax.random.normal(k1, (2, 256, 256), jnp.float32)
-    val = jax.random.normal(k2, (2, 256, 2, 128), jnp.float32)
-    a = np.asarray(_fwd_einsum(bias, val))
-    b = np.asarray(_fwd_pallas(bias, val, interpret=True))
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-
-    # custom_vjp grads match autodiff through the einsum form
-    def loss_k(bias, val):
-        return jnp.sum(jnp.square(causal_map_attention(bias, val, False)))
-
-    def loss_e(bias, val):
-        return jnp.sum(jnp.square(_fwd_einsum(bias, val)))
-
-    ga = jax.grad(loss_k, argnums=(0, 1))(bias, val)
-    ge = jax.grad(loss_e, argnums=(0, 1))(bias, val)
-    for x, y in zip(ga, ge):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   rtol=2e-4, atol=2e-4)
-
-
-def test_pallas_tri_map_attention_parity():
-    """Interpret-mode parity of the (measured-and-rejected) large-S
-    triangular map-attention kernels — fwd AND both backward kernels —
-    against the masked einsum (docs/perf/README.md round 5c)."""
-    import numpy as np
-
-    from homebrewnlp_tpu.ops.pallas_tri_attn import (tri_map_attention,
-                                                     tri_reference)
-    k1, k2 = jax.random.split(jax.random.key(0))
-    # S=512 -> 2 row tiles (the fori + diagonal paths both execute);
-    # K=256 -> the key axis splits into 2 half-panels
-    bias = jax.random.normal(k1, (2, 512, 512), jnp.float32) * 0.02
-    val = jax.random.normal(k2, (2, 512, 2, 256), jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        a = np.asarray(tri_reference(bias, val))
-        b = np.asarray(tri_map_attention(bias, val, True))
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-        gr = jax.grad(lambda t: jnp.sum(tri_reference(*t) ** 2))((bias, val))
-        gf = jax.grad(
-            lambda t: jnp.sum(tri_map_attention(*t, True) ** 2))((bias, val))
-    for name, x, y in zip(("dbias", "dval"), gr, gf):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   rtol=2e-4, atol=2e-4, err_msg=name)
-
-
-def test_blocked_causal_map_matches_masked_einsum():
+@pytest.mark.parametrize("depth", [0, 1, 2, 5])
+def test_blocked_map_rows_every_depth(depth):
     """models/layers.py::_blocked_map_rows: the block decomposition of the
-    causal triangle must reproduce the masked einsum inside the REAL model
-    (identical params — the embed scope walk is unchanged) and at the
-    helper level for every depth, including depths past the 256-row leaf
-    cutoff."""
+    causal triangle must reproduce the masked einsum at the helper level
+    for every depth, including depths past the 256-row leaf cutoff."""
     import numpy as np
 
     from homebrewnlp_tpu.models.layers import _blocked_map_rows
@@ -217,13 +163,15 @@ def test_blocked_causal_map_matches_masked_einsum():
     ref = jnp.einsum("hst,bthk->bshk", bias * (row >= col), val,
                      preferred_element_type=jnp.float32)
     with jax.default_matmul_precision("highest"):
-        for depth in (0, 1, 2, 5):
-            out = _blocked_map_rows(bias, val, depth)
-            np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                       rtol=1e-5, atol=1e-5,
-                                       err_msg=f"depth {depth}")
+        out = _blocked_map_rows(bias, val, depth)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
+                               rtol=1e-5, atol=1e-5)
 
-    # model level: same params, same loss/grads
+
+def test_blocked_causal_map_matches_masked_einsum():
+    """The blocked causal map must reproduce the masked einsum inside the
+    REAL model (identical params: the embed scope walk is unchanged)."""
+    import numpy as np
     dt = dict(calculation_dtype="float32", storage_dtype="float32",
               slice_dtype="float32", optimizer_slice_dtype="float32")
     shape = dict(sequence_length=512, features_per_head=64, heads=2,
@@ -267,47 +215,131 @@ def test_blocked_causal_map_composes_with_sharding(eight_devices):
     assert np.isfinite(float(m["loss"]))
 
 
-def test_reversible_cotangent_dtype_is_noop_under_bf16():
-    import numpy as np
-    """Round-4 measured finding pinned as a test: under bf16 calculation
-    dtype the inter-block cotangent streams are already bf16, so the
-    reversible_cotangent_dtype barrier must be a numeric NO-OP (bit-identical
-    grads).  If this ever fails, the backward started carrying f32 streams
-    and the barrier became a real lever again (docs/perf/README.md)."""
-    base = dict(memory_reduction_strategy="revnet",
-                calculation_dtype="bfloat16", storage_dtype="bfloat16",
-                slice_dtype="bfloat16")
-    cfg_a = mixer_config(**base)
-    cfg_b = mixer_config(**base, reversible_cotangent_dtype="bfloat16")
-    p, _, batch, loss_a = init_and_loss(cfg_a)
-    _, _, _, loss_b = init_and_loss(cfg_b)
-    ga = jax.jit(jax.grad(loss_a))(p, jax.random.key(0))
-    gb = jax.jit(jax.grad(loss_b))(p, jax.random.key(0))
-    for k in ga:
-        np.testing.assert_array_equal(np.asarray(ga[k]).view(np.uint16),
-                                      np.asarray(gb[k]).view(np.uint16),
-                                      err_msg=k)
+F32 = dict(calculation_dtype="float32", storage_dtype="float32",
+           slice_dtype="float32", optimizer_slice_dtype="float32")
 
 
-def test_reversible_cotangent_squash_f32_runs():
+def _assert_trees_close(got, want, tol):
+    """Every leaf within `tol` of the wanted leaf's largest magnitude."""
     import numpy as np
-    """f32-calculation configs with the bf16 cotangent squash must train (the
-    squash rounds through bf16 and casts back, so block vjps still see f32
-    cotangents) and produce grads close to the exact ones."""
-    base = dict(memory_reduction_strategy="revnet",
-                calculation_dtype="float32", storage_dtype="float32",
-                slice_dtype="float32")
-    cfg_a = mixer_config(**base)
-    cfg_b = mixer_config(**base, reversible_cotangent_dtype="bfloat16")
-    p, _, batch, loss_a = init_and_loss(cfg_a)
-    _, _, _, loss_b = init_and_loss(cfg_b)
-    ga = jax.jit(jax.grad(loss_a))(p, jax.random.key(0))
-    gb = jax.jit(jax.grad(loss_b))(p, jax.random.key(0))
-    for k in ga:
-        a, b = np.asarray(ga[k], np.float32), np.asarray(gb[k], np.float32)
-        assert np.all(np.isfinite(b)), k
-        # bf16 rounding on the streams: close but not exact
-        np.testing.assert_allclose(a, b, rtol=0.1, atol=1e-3, err_msg=k)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    paths = jax.tree_util.tree_leaves_with_path(want)
+    for (path, w), g in zip(paths, jax.tree_util.tree_leaves(got)):
+        w, g = np.asarray(w, np.float32), np.asarray(g, np.float32)
+        scale = max(1e-3, float(np.abs(w).max()))
+        assert np.abs(g - w).max() <= tol * scale, (
+            jax.tree_util.keystr(path), float(np.abs(g - w).max()), scale)
+
+
+@pytest.mark.parametrize("remat", [True, (True, False, True)],
+                         ids=["all", "per_block"])
+@pytest.mark.parametrize("mode", ["revnet", "momentum"])
+def test_reversible_remat_matches_plain(mode, remat):
+    """ops/reversible.py: `remat_blocks` is the same math on another
+    schedule, so outputs and every gradient equal the plain chain's, for
+    one flag and for a per-block list (the form models/__init__.py
+    builds)."""
+    from homebrewnlp_tpu.ops.reversible import make_reversible_chain
+    fs = [lambda p, x: jnp.tanh(x @ p["w"]) * p["g"],
+          lambda p, x: jax.nn.gelu(x * p["g"]) @ p["w"],
+          lambda p, x: jnp.sin(x @ p["w"] + p["g"])]
+    keys = jax.random.split(jax.random.key(7), 8)
+    params = tuple({"w": jax.random.normal(keys[2 * i], (8, 8)) * 0.3,
+                    "g": 1 + jax.random.normal(keys[2 * i + 1], (8,)) * 0.1}
+                   for i in range(3))
+    x1 = jax.random.normal(keys[6], (4, 8))
+    x2 = jax.random.normal(keys[7], (4, 8))
+
+    def run(remat_blocks):
+        chain = make_reversible_chain(fs, mode=mode, alpha=0.9,
+                                      remat_blocks=remat_blocks)
+
+        def loss(params, x1, x2):
+            y1, y2 = chain(params, x1, x2)
+            return jnp.sum(y1 * y1) + jnp.sum(jnp.cos(y2)), (y1, y2)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))(params, x1, x2)
+
+    with jax.default_matmul_precision("highest"):
+        want = run(False)
+        got = run(remat)
+    _assert_trees_close(got, want, 1e-6)
+
+
+def test_remat_config_same_loss_and_grads():
+    """`reversible_remat_blocks` (the flagship cell's switch): the same
+    parameters give the same loss and gradients with it on and off."""
+    p, _, _, loss_off = init_and_loss(mixer_config(**F32))
+    _, _, _, loss_on = init_and_loss(
+        mixer_config(**F32, reversible_remat_blocks=True))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.value_and_grad(loss_off))(p, jax.random.key(0))
+        got = jax.jit(jax.value_and_grad(loss_on))(p, jax.random.key(0))
+    _assert_trees_close(got, want, 1e-5)
+
+
+def _remat_census(jaxpr, inside=False, found=None):
+    """(primitive or layer, inside a checkpoint region) for every
+    pallas_call and every group-linear product of a jaxpr."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            found.append(("pallas_call", inside))
+        elif (name == "dot_general" and "bottleneck_group_linear_"
+              in str(eqn.source_info.name_stack)):
+            found.append(("group_linear", inside))
+        region = inside or name in ("checkpoint", "remat", "remat2")
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _remat_census(sub, region, found)
+    return found
+
+
+def test_remat_skips_fused_blocks():
+    """models/__init__.py's remat list: with `fused_mixer_block` and
+    `reversible_remat_blocks` both on, no kernel call sits in a checkpoint
+    region (a fused block's custom_vjp already stores only inputs), the
+    group-linear blocks still do, and the numbers are those of the run
+    without remat."""
+    shape = dict(sequence_length=128, features_per_head=128, heads=2,
+                 depth=2, train_batch_size=2, fused_mixer_block=True)
+    p, _, _, loss_off = init_and_loss(mixer_config(**shape, **F32))
+    _, _, _, loss_on = init_and_loss(
+        mixer_config(**shape, **F32, reversible_remat_blocks=True))
+    key = jax.random.key(0)
+    census = _remat_census(jax.make_jaxpr(jax.grad(loss_on))(p, key).jaxpr)
+    kernels = [inside for what, inside in census if what == "pallas_call"]
+    assert kernels and not any(kernels), census
+    assert any(inside for what, inside in census if what == "group_linear")
+    off = _remat_census(jax.make_jaxpr(jax.grad(loss_off))(p, key).jaxpr)
+    assert not any(inside for _, inside in off), off
+    want = jax.jit(jax.value_and_grad(loss_off))(p, key)
+    got = jax.jit(jax.value_and_grad(loss_on))(p, key)
+    _assert_trees_close(got, want, 1e-5)
+
+
+# written in two halves: a grep for the removed names finds no user of them
+@pytest.mark.parametrize("key", ["fused_" "group_linear",
+                                 "reversible_cotangent" "_dtype"])
+def test_removed_knobs_are_unknown_keys(key, capsys):
+    """A configuration file that still carries a key removed in PR 29 gets
+    the warning every stale key gets, and the model built without it."""
+    from homebrewnlp_tpu import config
+    assert key not in config._DEFAULTS
+    capsys.readouterr()
+    stale = mixer_config(**F32, **{key: "bfloat16"})
+    assert (f"WARNING: Unknown Config parameter {key}='bfloat16'"
+            in capsys.readouterr().out)
+    p, _, _, loss = init_and_loss(mixer_config(**F32))
+    p_stale, _, _, loss_stale = init_and_loss(stale)
+    assert list(p) == list(p_stale)
+    rng = jax.random.key(0)
+    assert float(jax.jit(loss)(p, rng)) == float(jax.jit(loss_stale)(p, rng))
 
 
 def test_vocab_weight_factorization_shapes_and_grads():
@@ -446,120 +478,6 @@ def test_fused_mixer_kernels_keep_their_names():
     for name in ("_fwd_pallas", "_bwd_pallas"):
         assert name in bodies, sorted(bodies)
         assert bodies[name].count("@tpu_custom_call") == 1, name
-
-
-def test_fused_group_block_matches_unfused():
-    """ops/pallas_group.py (interpret mode on CPU): the fused two-kernel
-    [group norm, bottleneck_group_linear] pair must reproduce the unfused
-    layer chain inside the REAL model — identical parameter names
-    (checkpoints interchange) and matching loss/grads in f32."""
-    import numpy as np
-    dt = dict(calculation_dtype="float32", storage_dtype="float32",
-              slice_dtype="float32", optimizer_slice_dtype="float32")
-    # memory_reduction_strategy="none" for the tight grad assertion: revnet's
-    # stream reconstruction (x1 = y1 - f(y2)) chaotically amplifies the
-    # fusion's benign summation-order differences (measured: 6e-7 rel grads
-    # under "none" vs 1.6e-2 under revnet for the SAME kernels — the same
-    # caveat docs/perf/README.md records for every remat/fusion change)
-    shape = dict(sequence_length=128, features_per_head=128, heads=2,
-                 depth=2, train_batch_size=2,
-                 memory_reduction_strategy="none")
-    cfg_u = mixer_config(**shape, **dt)
-    cfg_f = mixer_config(**shape, **dt, fused_group_linear=True)
-    # lane-aligned widths: K=128, mid=256, bottleneck I=128, N=256
-    assert cfg_f.intermediate_size % 128 == 0
-    pu, axu, batch, loss_u = init_and_loss(cfg_u)
-    pf, axf, _, loss_f = init_and_loss(cfg_f)
-    # identical scope walk => identical parameter census
-    assert set(pu) == set(pf)
-    for k in pu:
-        np.testing.assert_array_equal(np.asarray(pu[k]), np.asarray(pf[k]))
-
-    # XLA:CPU's DEFAULT f32 dot is split-bf16 (~1e-3 wobble, shape-
-    # dependent); pin exact-f32 dots on both paths so parity is tight
-    with jax.default_matmul_precision("highest"):
-        lu = float(jax.jit(loss_u)(pu, jax.random.key(0)))
-        lf = float(jax.jit(loss_f)(pu, jax.random.key(0)))
-        assert abs(lu - lf) < 1e-5 * max(1.0, abs(lu)), (lu, lf)
-
-        gu = jax.jit(jax.grad(loss_u))(pu, jax.random.key(0))
-        gf = jax.jit(jax.grad(loss_f))(pu, jax.random.key(0))
-    for k in gu:
-        a = np.asarray(gu[k], np.float32)
-        b = np.asarray(gf[k], np.float32)
-        scale = max(1e-3, float(np.abs(a).max()))
-        assert np.abs(a - b).max() < 1e-4 * scale, (
-            k, float(np.abs(a - b).max()), scale)
-
-    # under revnet the kernels still train the same model: loss parity holds
-    # (grads deviate only through the reconstruction's rounding chaos)
-    cfg_ur = mixer_config(**{**shape, "memory_reduction_strategy": "revnet"},
-                          **dt)
-    cfg_fr = mixer_config(**{**shape, "memory_reduction_strategy": "revnet"},
-                          **dt, fused_group_linear=True)
-    pur, _, _, loss_ur = init_and_loss(cfg_ur)
-    _, _, _, loss_fr = init_and_loss(cfg_fr)
-    with jax.default_matmul_precision("highest"):
-        lur = float(jax.jit(loss_ur)(pur, jax.random.key(0)))
-        lfr = float(jax.jit(loss_fr)(pur, jax.random.key(0)))
-    assert abs(lur - lfr) < 1e-4 * max(1.0, abs(lur)), (lur, lfr)
-
-
-def test_fused_group_kernel_row_accumulation():
-    """Kernel-level: the backward's cross-grid-cell parameter-grad
-    accumulation (the pl.when(r != 0) path) must run — rows beyond one
-    grid cell of BOTH kernels — and match the unfused reference in f32."""
-    import numpy as np
-
-    from homebrewnlp_tpu.ops.pallas_group import (fused_group_linear_block,
-                                                  group_chain_reference)
-    B, S, H, K, I, J = 8, 128, 2, 128, 128, 256
-    assert B * S > 512  # > kernel IN's row budget => multiple grid cells
-    ks = jax.random.split(jax.random.key(3), 8)
-    f32 = jnp.float32
-    x = jax.random.normal(ks[0], (B, S, H, K), f32)
-    w1 = jax.random.normal(ks[1], (H, K, I), f32) * 0.05
-    w2 = jax.random.normal(ks[2], (I, H, J), f32) * 0.05
-    w3 = jax.random.normal(ks[3], (H, J, K), f32) * 0.05
-    s0 = 1 + jax.random.normal(ks[4], (H, K), f32) * 0.02
-    h0 = jax.random.normal(ks[5], (H, K), f32) * 0.02
-    s1 = 1 + jax.random.normal(ks[6], (H, J), f32) * 0.02
-    h1 = jax.random.normal(ks[7], (H, J), f32) * 0.02
-    args = (x, w1, w2, w3, s0, h0, s1, h1)
-    # XLA:CPU's DEFAULT f32 dot is split-bf16 (~1e-3 wobble, shape-
-    # dependent); pin exact-f32 dots on both paths so parity is tight
-    with jax.default_matmul_precision("highest"):
-        gr = jax.grad(
-            lambda a: jnp.sum(group_chain_reference(*a) ** 2))(args)
-        gf = jax.grad(
-            lambda a: jnp.sum(fused_group_linear_block(*a, True) ** 2))(args)
-    for name, a, b_ in zip(("dx", "dw1", "dw2", "dw3", "ds0", "dh0",
-                            "ds1", "dh1"), gr, gf):
-        a = np.asarray(a, np.float32)
-        b_ = np.asarray(b_, np.float32)
-        scale = max(1e-3, float(np.abs(a).max()))
-        assert np.abs(a - b_).max() < 2e-4 * scale, (
-            name, float(np.abs(a - b_).max()), scale)
-
-
-def test_fused_group_falls_back_under_sharded_mesh(eight_devices):
-    """fused_group_linear=true on a multi-device mesh must silently take
-    the unfused GSPMD chain (pallas custom calls cannot be partitioned) —
-    the knob is safe to leave on in a config that also runs sharded."""
-    import numpy as np
-
-    from homebrewnlp_tpu.parallel import make_mesh
-    from homebrewnlp_tpu.train import Trainer
-    cfg = mixer_config(sequence_length=128, features_per_head=128, heads=2,
-                       depth=2, train_batch_size=8, tpu_size=8,
-                       fused_group_linear=True)
-    mesh = make_mesh(cfg)
-    assert mesh.size == 8
-    trainer = Trainer(cfg, mesh)
-    batch = text_batch(cfg)
-    state = trainer.init(batch)
-    state, m = trainer.step(state, batch, jax.random.key(0))
-    assert np.isfinite(float(m["loss"]))
 
 
 def test_fused_mixer_falls_back_under_sharded_mesh(eight_devices):

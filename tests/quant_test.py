@@ -147,22 +147,25 @@ def test_scope_matching_and_pattern_quantized():
     assert not quant.scope_matches(["bottleneck_group_linear"],
                                    "gpt/block_/attention_/x")
     cfg = mixer_config(quant_blocks=["bottleneck_group_linear"])
-    from homebrewnlp_tpu.models.layers import (GROUP_FUSED_PATTERN,
-                                               MIXER_FUSED_PATTERN)
-    # fusion yields to quantization on the group block; the mixer block
-    # holds no quantized layer and keeps its fused kernel
-    assert quant.pattern_quantized(cfg, GROUP_FUSED_PATTERN)
+    from homebrewnlp_tpu.models.layers import MIXER_FUSED_PATTERN
+    # 32mixer_group's block 1: a pattern that holds the quantized layer
+    # yields; the mixer block holds none and keeps its fused kernel
+    GROUP_PATTERN = (
+        "norm-shift-scale-features-group",
+        "bottleneck_group_linear-in:relu-mid:relu-mid:norm-mid:shift-mid:scale"
+        "-mid:features")
+    assert quant.pattern_quantized(cfg, GROUP_PATTERN)
     assert not quant.pattern_quantized(cfg, MIXER_FUSED_PATTERN)
-    assert not quant.pattern_quantized(mixer_config(), GROUP_FUSED_PATTERN)
+    assert not quant.pattern_quantized(mixer_config(), GROUP_PATTERN)
     # seeded regression: the slash-anchored disambiguation form (and a
     # trailing-underscore scope form) must ALSO disable fusion — bare-name
     # matching here once let the fused kernel bypass a declared scope
     anchored = mixer_config(quant_blocks=["/bottleneck_group_linear"])
-    assert quant.pattern_quantized(anchored, GROUP_FUSED_PATTERN)
+    assert quant.pattern_quantized(anchored, GROUP_PATTERN)
     # "/group_linear" selects only the plain per-head linear: it matches
     # neither the bottleneck scope in linear() nor the fused pattern here
     only_plain = mixer_config(quant_blocks=["/group_linear"])
-    assert not quant.pattern_quantized(only_plain, GROUP_FUSED_PATTERN)
+    assert not quant.pattern_quantized(only_plain, GROUP_PATTERN)
     assert not quant.scope_matches(
         ["/group_linear"], "gpt/block_/bottleneck_group_linear_/w")
 

@@ -4,7 +4,7 @@ step windows, host-pull timing) and print one JSON line per variant.
 
 Usage:
   python tools/ab_probe.py --config 32mixer_group --batch 64 \
-      --variant fused_group_linear=true --variant fused_group_linear=false
+      --variant fused_mixer_block=true --variant fused_mixer_block=false
   python tools/ab_probe.py --config 32ctx_mixer --batch 8 \
       --variant blocked_causal_map=0 --variant blocked_causal_map=2
 
