@@ -1,17 +1,37 @@
 """Causal softmax attention in blocks of query rows and of keys.
 
 ``[B, H, S, S]`` scores of a long sequence do not fit (8.6 GB a sequence of
-8,192 tokens over 32 heads in float32), so the rows are taken ``rows`` at a
+8,192 tokens over 32 heads in float32), so the rows are taken a block at a
 time against the keys up to the block's last row (the triangle the mask
-leaves, not the square), and those keys ``rows`` at a time with a running
-maximum, sum and output (the online softmax), in float32.  Each tile is
-recomputed in the backward, so only the running triple outlives it.  The
-tiles are unrolled (the blocks' key counts differ): ``S / rows`` blocks of
-rows, ``(S / rows + 1) / 2`` tiles each on average.
+leaves, not the square), and those keys a block at a time with a running
+maximum, sum and output (the online softmax), in float32.
 
-Square tiles are what the chip's compiler handles well here: with the
-softmax taken over whole rows of 8,192 keys it ran the row maximum and the
-exponentials at a tenth of this form's rate (498 against 50 ms for the
+What runs where is decided by the operands' shape alone
+(:func:`takes_kernels`).  A sequence of whole blocks of 512 at head widths
+of whole or half lane tiles (``kimi_linear_48b``: 8,192 tokens, keys 192
+wide, values 128) runs as the two Mosaic kernels of ``ops/pallas_mla.py``,
+forward and backward of one ``custom_vjp``: a tile's scores, running maximum
+and sum, exponentials and weights stay in VMEM, only ``q``, ``k``, ``v``, the
+output and one float32 statistic a row (the log of its sum of exponentials)
+cross HBM, and the backward recomputes each tile from them.  Their rounding
+points are ``_tile``'s: scores from operands in their own type added up in
+float32; mask, maximum, exponentials, sum and the output accumulator float32;
+the weights rounded to ``v``'s type before the second product; the output
+rounded once at the end; in the backward the weights, ``dS`` and ``dO``
+enter their products in the operands' type and every gradient is summed in
+float32 and rounded once (PERF.md, PR 30: 14.6 ms a forward and 44.2 forward
+and backward at the cell's shape, against 49.2 and 164.4 for the tiles).
+
+Every other shape (the toy configurations, odd sequence lengths, narrow
+heads) takes ``rows x rows`` tiles unrolled in XLA, which are also the
+kernels' oracle: each tile is recomputed in the backward, so only the
+running triple outlives it, and the blocks' key counts differ, so the tiles
+are unrolled: ``S / rows`` blocks of rows, ``(S / rows + 1) / 2`` tiles each
+on average, every one through HBM several times.
+
+Square tiles are what the chip's compiler handles well in that form: with
+the softmax taken over whole rows of 8,192 keys it ran the row maximum and
+the exponentials at a tenth of this form's rate (498 against 50 ms for the
 forward of one layer at 2 x 8,192 tokens, 32 heads; my chip run, PR 27).
 """
 from __future__ import annotations
@@ -22,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ..nd import einsum_f32
+from .pallas_mla import BLOCK, VMEM_BYTES, flash_attention, vmem_bytes
 
 
 @functools.partial(jax.checkpoint, static_argnums=(4,))
@@ -45,10 +66,32 @@ def _tile(carry, q, k, v, ahead: int):
     return new_top, total * keep + jnp.sum(weights, -1), out
 
 
+def takes_kernels(q, v) -> bool:
+    """Whether ``ops/pallas_mla.py``'s kernels run this shape: the sequence a
+    whole number of their blocks (so a tile fills the matrix unit), the head
+    widths whole or half lane tiles, and a head's keys, values and their
+    gradients within the kernels' VMEM."""
+    s, d, d_v = q.shape[1], q.shape[-1], v.shape[-1]
+    return (s % BLOCK == 0 and d % 64 == 0 and d_v % 64 == 0
+            and vmem_bytes(s, d, d_v, q.dtype.itemsize) <= VMEM_BYTES)
+
+
 def causal_attention(q, k, v, rows: int = 1024):
     """``softmax(q k^T + causal) v`` for ``q, k [B, S, H, D]`` (``q`` already
-    scaled) and ``v [B, S, H, Dv]``."""
+    scaled) and ``v [B, S, H, Dv]``; ``rows`` is the unrolled tiles' size."""
+    kernels = takes_kernels(q, v)
     q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    if kernels:
+        from . import pallas_interpret
+        out = flash_attention(q, k, v, BLOCK, pallas_interpret())
+    else:
+        out = _unrolled_tiles(q, k, v, rows)
+    return jnp.swapaxes(out, 1, 2)
+
+
+def _unrolled_tiles(q, k, v, rows: int):
+    """The same for heads-major ``[B, H, S, .]`` by the online softmax over
+    unrolled ``rows x rows`` tiles in XLA."""
     s = q.shape[2]
     blocks = []
     for first in range(0, s, rows):
@@ -65,5 +108,4 @@ def causal_attention(q, k, v, rows: int = 1024):
             carry = _tile(carry, mine, k[:, :, at:at + rows],
                           v[:, :, at:at + rows], first - at)
         blocks.append((carry[2] / carry[1][..., None]).astype(v.dtype))
-    out = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=2)
-    return jnp.swapaxes(out, 1, 2)
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=2)

@@ -11,6 +11,7 @@ reading rounds to 2**-9 there, so `sm3_leaf` and `change_leaf` get 4e-3 and
 the losses, which see the weights only through the learning rate, 1e-5.
 """
 import contextlib
+import functools
 import importlib.util
 import json
 import os
@@ -249,6 +250,78 @@ def test_blocked_attention_matches_the_full_matrix(rows):
     want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
     np.testing.assert_allclose(causal_attention(q, k, v, rows=rows), want,
                                **LAYER)
+
+
+def _attention_inputs(s, kind, b=1, h=2, d=192, d_v=128, seed=6):
+    """Queries (scaled), keys and values at the latent attention's head
+    widths, and the whole-matrix softmax of their float32 values."""
+    rng = np.random.default_rng(seed)
+    q, k, v = (jnp.asarray(rng.normal(size=(b, s, h, w)) * scale, kind)
+               for w, scale in ((d, d ** -0.5), (d, 1.0), (d_v, 1.0)))
+
+    def full(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest")
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v,
+                          precision="highest")
+
+    return (q, k, v), full
+
+
+@pytest.mark.parametrize("kind", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("blocks", [1, 3], ids=["one_block", "three_blocks"])
+def test_attention_kernels_match_the_tiles_and_the_full_matrix(blocks, kind,
+                                                               monkeypatch):
+    """ops/pallas_mla.py's kernel pair (interpreted here) at the latent
+    attention's widths, 192 and 128, against the unrolled tiles it replaces
+    at such shapes and against the softmax over the whole [S, S] matrix:
+    outputs and all three gradients.  One block is one diagonal tile; three
+    walk tiles below the diagonal too and add up a head's dK, dV over blocks
+    of rows.  In float32 all three agree to the order of their sums; in
+    bfloat16 the kernels stand no further from the exact result than the
+    tiles do (no rounding point lower than theirs)."""
+    from homebrewnlp_tpu.ops import block_attention, pallas_mla
+    s = blocks * pallas_mla.BLOCK
+    args, full = _attention_inputs(s, kind)
+    assert block_attention.takes_kernels(args[0], args[2])
+    f32 = lambda xs: [np.asarray(x.astype(jnp.float32)) for x in xs]
+
+    def results(f):
+        loss = lambda *a: jnp.sum(jnp.sin(f(*a).astype(jnp.float32)))
+        return f32([f(*args), *jax.grad(loss, range(3))(*args)])
+
+    kernels = results(block_attention.causal_attention)
+    exact = results(full)
+    monkeypatch.setattr(block_attention, "takes_kernels", lambda q, v: False)
+    tiles = results(functools.partial(block_attention.causal_attention,
+                                      rows=pallas_mla.BLOCK))
+    for mine, theirs, want in zip(kernels, tiles, exact):
+        assert np.all(np.isfinite(mine))
+        if kind == jnp.float32:
+            np.testing.assert_allclose(mine, want, rtol=1e-4, atol=1e-5)
+            np.testing.assert_allclose(mine, theirs, rtol=1e-4, atol=1e-5)
+        else:
+            off = lambda x: float(np.sqrt(np.mean((x - want) ** 2)))
+            assert off(mine) <= 1.1 * off(theirs), (off(mine), off(theirs))
+            assert off(mine) <= 1e-2 * float(np.sqrt(np.mean(want ** 2)))
+
+
+def test_the_shape_alone_chooses_the_attention_kernels():
+    """A sequence of whole blocks at head widths of whole or half lane tiles
+    takes the kernel pair, forward and backward; every other shape, the toy
+    configuration's and `test_blocked_attention_matches_the_full_matrix`'s
+    among them, keeps the unrolled tiles."""
+    from homebrewnlp_tpu.ops.block_attention import causal_attention
+    from homebrewnlp_tpu.ops.pallas_mla import BLOCK
+    for s, d, d_v, kernels in ((BLOCK, 192, 128, 2), (2 * BLOCK, 64, 64, 2),
+                               (48, 12, 8, 0), (BLOCK + 8, 192, 128, 0),
+                               (BLOCK, 12, 8, 0), (BLOCK, 192, 72, 0)):
+        (q, k, v), _ = _attention_inputs(s, jnp.float32, d=d, d_v=d_v)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(causal_attention(*a)), range(3)))(q, k, v))
+        assert text.count("pallas_call") == kernels, (s, d, d_v, kernels)
 
 
 def test_mla_layer_matches_the_reference():

@@ -88,6 +88,48 @@ def entry_instructions(hlo: str) -> dict:
     return out
 
 
+def mosaic_calls(insts: dict) -> dict:
+    """name -> operand names of the entry computation's Mosaic kernels."""
+    return {name: operands for name, (opcode, operands, line) in insts.items()
+            if opcode == "custom-call" and "tpu_custom_call" in line}
+
+
+def copies_beside(insts: dict, name: str, least: int) -> list:
+    """Copies and transposes of `least` elements or more that feed the
+    instruction `name` or take its results (through bitcasts and tuple
+    elements)."""
+    def resolve(n):
+        while insts[n][0] in ("bitcast", "get-tuple-element"):
+            n = insts[n][1][0]
+        return n
+
+    def big(n):
+        dims = re.match(r"\(?\w+\[([\d,]*)\]", insts[n][2].split(
+            " = ", 1)[1]).group(1)
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        return size >= least
+
+    users = {}
+    for user, (_, operands, _) in insts.items():
+        for o in operands:
+            if o in insts:      # not a called computation
+                users.setdefault(o, []).append(user)
+    around = [resolve(o) for o in insts[name][1] if o in insts]
+    pending = list(users.get(name, []))
+    while pending:
+        user = pending.pop()
+        if insts[user][0] in ("bitcast", "get-tuple-element"):
+            pending += users.get(user, [])
+        else:
+            around.append(user)
+    return [insts[n][2].strip()[:120] for n in around
+            if (insts[n][0] in ("copy", "transpose", "copy-start")
+                or n.startswith("copy_"))   # a fusion that only copies
+            and big(n)]
+
+
 def test_fused_mixer_block_crosses_its_kernels_without_a_copy(
         one_chip, no_compile_cache, monkeypatch):
     """The only test that sees a layout.  XLA stores the group model's
@@ -176,9 +218,7 @@ def test_delta_rule_gradient_runs_two_kernels_and_no_chunk_scan(
         stream, stream, stream, shape(jnp.float32, n_b, seq, n_h, key),
         shape(jnp.float32, n_b, seq, n_h)).compile().as_text()
     insts = entry_instructions(hlo)
-    kernels = {name: operands for name, (opcode, operands, line)
-               in insts.items()
-               if opcode == "custom-call" and "tpu_custom_call" in line}
+    kernels = mosaic_calls(insts)
     assert sorted(re.search(r"jit\((_kda_chunks_\w+)\)", insts[name][2]).group(1)
                   for name in kernels) == ["_kda_chunks_bwd",
                                            "_kda_chunks_fwd"], sorted(kernels)
@@ -186,36 +226,65 @@ def test_delta_rule_gradient_runs_two_kernels_and_no_chunk_scan(
                if opcode == "while"]
     assert carried and not any("bf16[32,8,2,32,32,128]" in line
                                for line in carried), carried
+    for name in kernels:
+        moved = copies_beside(insts, name, n_b * seq * n_h * key)
+        assert not moved, (name, moved)
 
-    def resolve(name):
-        while insts[name][0] in ("bitcast", "get-tuple-element"):
-            name = insts[name][1][0]
-        return name
 
-    def big(name):
-        dims = re.match(r"\(?\w+\[([\d,]*)\]", insts[name][2].split(
-            " = ", 1)[1]).group(1)
-        size = 1
-        for d in filter(None, dims.split(",")):
-            size *= int(d)
-        return size >= n_b * seq * n_h * key
+def test_mla_gradient_runs_two_kernels_and_no_score_tile(
+        one_chip, no_compile_cache, monkeypatch):
+    """`jax.grad` of the `mla` layer at the widths of kimi_linear_48b.train
+    (32 heads, keys 192 wide, values 128; the sequence whole, since at a
+    quarter of it XLA moves a 33 MB operand into fast memory ahead of the
+    call and that move reads as a copy): Mosaic accepts ops/pallas_mla.py's
+    forward and backward with a contraction of one and a half lane tiles,
+    they are the only
+    custom calls, nothing shaped like a score tile ([., ., R, R] for the
+    kernels' block or the unrolled tiles' 1,024 rows) is left under `mla_`,
+    and nothing the size of an operand is copied on its own next to a
+    kernel (the heads-major transposes fuse into the operands' producers)."""
+    import homebrewnlp_tpu.ops as ops
+    from homebrewnlp_tpu.models.ctx import Args
+    from homebrewnlp_tpu.models.registry import LAYER_FUNCTIONS
+    from homebrewnlp_tpu.ops.pallas_mla import BLOCK
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "kimi_linear_48b.json")) as f:
+        raw = {k: v for k, v in json.load(f).items() if k != "benchmark"}
+    cfg = Config(raw)
+    seq = cfg.sequence_length
+    names = ("batch", "sequence", "heads", "features_per_head")
+    n_b, n_h = cfg.train_batch_size, cfg.heads
 
-    users = {}
-    for name, (_, operands, _) in insts.items():
-        for o in operands:
-            if o in insts:      # not a called computation
-                users.setdefault(o, []).append(name)
-    for name, operands in kernels.items():
-        around = [resolve(o) for o in operands if o in insts]
-        pending = list(users.get(name, []))
-        while pending:
-            user = pending.pop()
-            if insts[user][0] in ("bitcast", "get-tuple-element"):
-                pending += users.get(user, [])
-            else:
-                around.append(user)
-        moved = [insts[n][2].strip()[:120] for n in around
-                 if (insts[n][0] in ("copy", "transpose", "copy-start")
-                     or n.startswith("copy_"))   # a fusion that only copies
-                 and big(n)]
+    def layer(params, x):
+        ctx = Ctx(cfg, params=params, train=params is not None)
+        out = ctx.scoped("mla_", LAYER_FUNCTIONS["mla"],
+                         Args(ctx, NT(x, names), []))
+        return out.x, ctx.collected
+
+    x = jax.ShapeDtypeStruct((n_b, seq, n_h, cfg.features_per_head),
+                             jnp.bfloat16, sharding=one_chip)
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+              for k, v in jax.eval_shape(lambda x: layer(None, x)[1],
+                                         x).items()}
+
+    def loss(p, x):
+        # the stream made by a fusion, as the block's norm makes it
+        return jnp.sum(jnp.square(layer(p, x * 2)[0].astype(jnp.float32)))
+
+    hlo = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    insts = entry_instructions(hlo)
+    kernels = mosaic_calls(insts)
+    assert sorted(re.search(r"jit\((_mla_attention_\w+)\)",
+                            insts[name][2]).group(1)
+                  for name in kernels) == ["_mla_attention_bwd",
+                                           "_mla_attention_fwd"], sorted(
+                                               kernels)
+    tiles = [line.strip()[:160] for line in hlo.splitlines()
+             if re.search(r"= \(?f32\[\d+,\d+,(%d,%d|1024,1024)\]"
+                          % (BLOCK, BLOCK), line) and "mla_" in line]
+    assert not tiles, tiles
+    for name in kernels:
+        moved = copies_beside(insts, name, n_b * seq * n_h * cfg.v_head_dim)
         assert not moved, (name, moved)
