@@ -36,6 +36,8 @@ PKM_VALUES = "product_key_value_dim"
 # short convolution and the width of a routed or shared expert
 MIXER_HEADS = "mixer_heads"
 MIXER_KEY = "mixer_key"
+# the K/V heads of grouped-query attention: fewer than its query heads
+KV_HEADS = "kv_heads"
 LATENT = "latent"
 LOW_RANK = "low_rank"
 CONV_TAP = "conv_tap"
@@ -54,8 +56,8 @@ from . import nd as _nd  # noqa: E402  (registry import, no cycle: nd is leaf)
 _nd.register_axis(BATCH, SEQUENCE, HEADS, KEY, INTERMEDIATE, VOCAB,
                   TOKEN_PATCH, HEIGHT, WIDTH, COLOR_CHANNELS, EXPERTS,
                   ROUTED_EXPERTS, PKM_AXES, PKM_VALUES, PIPE_STAGE,
-                  MIXER_HEADS, MIXER_KEY, LATENT, LOW_RANK, CONV_TAP,
-                  EXPERT_INTERMEDIATE)
+                  MIXER_HEADS, MIXER_KEY, KV_HEADS, LATENT, LOW_RANK,
+                  CONV_TAP, EXPERT_INTERMEDIATE)
 
 
 def anonymize_name(name: str) -> str:
@@ -77,14 +79,15 @@ DTYPES = {
 # carries beside this repo's keys, for the record of what was published:
 # nothing reads them (the block DSL says the same), so they are no typo
 UPSTREAM_KEYS = frozenset((
-    "first_k_dense_replace", "head_dim", "hidden_act", "hidden_size",
-    "intermediate_size", "mla_use_nope", "model_max_length", "model_type",
-    "moe_layer_freq", "moe_renormalize", "moe_router_activation_func",
-    "num_attention_heads", "num_expert_group", "num_experts",
-    "num_experts_per_token", "num_hidden_layers", "num_key_value_heads",
-    "num_nextn_predict_layers", "num_shared_experts", "q_lora_rank",
-    "rope_scaling", "rope_theta", "tie_word_embeddings", "topk_group",
-    "use_grouped_topk"))
+    "attention_bias", "first_k_dense_replace", "hidden_act", "hidden_size",
+    "intermediate_size", "layer_types", "max_position_embeddings",
+    "max_window_layers", "mla_use_nope", "mlp_layer_types",
+    "model_max_length", "model_type", "moe_layer_freq", "moe_renormalize",
+    "moe_router_activation_func", "norm_topk_prob", "num_expert_group",
+    "num_experts", "num_experts_per_tok", "num_experts_per_token",
+    "num_hidden_layers", "num_nextn_predict_layers", "num_shared_experts",
+    "q_lora_rank", "rope_scaling", "rope_theta", "tie_word_embeddings",
+    "topk_group", "use_grouped_topk", "use_sliding_window"))
 
 
 @dataclasses.dataclass
@@ -344,6 +347,17 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     qk_nope_head_dim=None,
     qk_rope_head_dim=None,
     v_head_dim=None,
+    # gqa (softmax attention over grouped K/V heads, rotary positions), as
+    # upstream names them: `num_attention_heads` query heads (None = heads)
+    # of `head_dim` read `num_key_value_heads` K/V heads; `rope_parameters`
+    # maps a layer type ("sliding_attention", "full_attention") to its
+    # rotary table's {"rope_type": "default" | "yarn", "rope_theta", ...};
+    # a sliding layer sees the last `sliding_window` positions
+    num_attention_heads=None,
+    num_key_value_heads=None,
+    head_dim=None,
+    rope_parameters=None,
+    sliding_window=None,
     # false: the table holds one stream-wide row a token, no factorisation
     factorized_embedding=True,
     # which block_config entries run at which depth: one list of indices a

@@ -52,6 +52,11 @@ _NO_CACHE_YET = {
            "key part of every position, with the up-projection absorbed "
            "into q and the output); without it every token rebuilds the "
            "whole sequence",
+    "gqa": "decoding needs a cache of the K/V heads alone (k and v of "
+           "num_key_value_heads heads a position, k rotated at its own "
+           "offset when it is written), a ring of sliding_window positions "
+           "for the sliding layers beside a whole one for the full layers; "
+           "without it every token rebuilds the whole sequence",
 }
 
 

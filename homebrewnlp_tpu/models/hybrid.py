@@ -1,6 +1,7 @@
-"""Block parts of sparse-expert hybrids of linear and latent attention.
+"""Block parts of sparse-expert decoders: linear, latent and grouped-query
+attention, routed experts.
 
-Five layers of the block DSL, each declared once (ROADMAP D8, R2, R3, R5):
+Six layers of the block DSL, each declared once (ROADMAP D8, R2, R3, R5):
 
 - ``rms_norm[-scale]``: a norm without centering, over the stream's features;
 - ``gated_feed_forward[-in:<act>]``: ``(act(x W_gate) * (x W_up)) W_down``;
@@ -9,14 +10,18 @@ Five layers of the block DSL, each declared once (ROADMAP D8, R2, R3, R5):
   (``ops/delta_rule.py``);
 - ``mla``: causal softmax attention whose keys and values are expanded from
   one low-rank latent a token, with no positions (``ops/block_attention.py``);
+- ``gqa-<layer type>``: causal softmax attention whose query heads share
+  fewer K/V heads, with rotary positions from the layer type's table and,
+  on a ``sliding_attention`` layer, a window (``ops/rotary.py``,
+  ``ops/block_attention.py``);
 - ``routed_moe[-topk<k>][-sigmoid][-bias][-gated][-shared<n>][-in:<act>]``:
   the one routed expert layer, with nothing dropped (``ops/grouped_ffn.py``).
 
 The mixers' heads and head widths are their own (``linear_attn_config``,
-``qk_nope_head_dim`` ...): the stream's ``(heads, features_per_head)`` pair
-says how wide the residual is, not how wide a layer is inside.  Serving these
-layers is out of scope here: ``infer/kv_cache.py::cache_eligible`` says what
-is missing.
+``qk_nope_head_dim``, ``head_dim`` ...): the stream's ``(heads,
+features_per_head)`` pair says how wide the residual is, not how wide a
+layer is inside.  Serving these layers is out of scope here:
+``infer/kv_cache.py::cache_eligible`` says what is missing.
 """
 from __future__ import annotations
 
@@ -26,11 +31,12 @@ import jax
 import jax.numpy as jnp
 
 from .. import nd
-from ..config import (CONV_TAP, EXPERT_INTERMEDIATE, INTERMEDIATE, LATENT,
-                      LOW_RANK, MIXER_HEADS, MIXER_KEY, ROUTED_EXPERTS,
-                      SEQUENCE)
+from ..config import (CONV_TAP, EXPERT_INTERMEDIATE, INTERMEDIATE, KV_HEADS,
+                      LATENT, LOW_RANK, MIXER_HEADS, MIXER_KEY,
+                      ROUTED_EXPERTS, SEQUENCE)
 from ..nd import NT
 from ..ops import grouped_ffn as gf
+from ..ops import rotary
 from ..ops.activations import PLAIN, activate
 from ..ops.block_attention import causal_attention
 from ..ops.delta_rule import chunked_kda
@@ -38,9 +44,23 @@ from .ctx import Args
 from .linear import Dim, linear, normal_var, orthogonal_var
 
 
-#: selected pairs a chunk of the grouped product, as a multiple of the tokens:
-#: a share of 1/32 of the experts under top-8 gets a quarter of this
-EXPERT_CHUNK_TOKENS = 1
+#: rows of a chunk of the grouped product, in balanced loads of this share
+EXPERT_CHUNK_LOADS = 4
+
+
+def expert_chunk(tokens: int, topk: int, held: int, experts: int) -> int:
+    """Rows of a chunk of the grouped product (``ops/grouped_ffn.py``), from
+    the share of the experts held here: ``EXPERT_CHUNK_LOADS`` times what a
+    balanced router sends this share (``topk * held / experts`` pairs a
+    token), and no more than all pairs.  8 of 256 experts under top-8 get
+    ``tokens`` rows; 16 of 64 get all ``tokens * topk`` pairs, so their loop
+    takes one trip whatever the routing and the step's time cannot follow
+    it (random weights on the toy language send up to 110,000 of a step's
+    131,072 pairs to 16 held experts, and 33,000 on another seed: under a
+    chunk of twice the balanced load the trips, and 8% of ``tokens_per_s``,
+    followed the seed; PERF.md, PR 31)."""
+    balanced = -(-tokens * topk * held // experts)
+    return min(EXPERT_CHUNK_LOADS * balanced, tokens * topk)
 
 
 def _fdims(args: Args) -> typing.List[Dim]:
@@ -208,6 +228,53 @@ def mla(args: Args) -> NT:
                     fdims).transpose_to(u.names)
 
 
+# -- gqa ----------------------------------------------------------------------
+
+def gqa(args: Args) -> NT:
+    """Grouped-query attention with rotary positions; the one extra names
+    the layer type, upstream's ``layer_types`` entry, which picks the rotary
+    table (``rope_parameters[<layer type>]``) and, for ``sliding_attention``,
+    the window (``sliding_window``):
+
+        q = u W_q -> [heads, head_dim];  k, v = u W_k, u W_v -> [kv heads, .]
+        q, k = rot(q, pos), rot(k, pos)          (ops/rotary.py, float32)
+        query head h reads K/V head h // (heads / kv heads)
+        y = concat_h softmax(q_h k^T / sqrt(head_dim) + mask) v  W_o
+        mask: key <= row, and under a window also row - key < sliding_window
+
+    No bias and no norm on q or k.  The scale goes into ``q`` with the
+    rotation, before the one rounding to the stream's type.
+    """
+    cfg, ctx, u = args.cfg, args.ctx, args.tensor
+    layer_type, = args.name_extras
+    width = (MIXER_KEY, cfg.head_dim)
+    heads = (MIXER_HEADS, cfg.num_attention_heads or cfg.heads)
+    kv_heads = (KV_HEADS, cfg.num_key_value_heads)
+    if layer_type not in (cfg.rope_parameters or {}):
+        raise ValueError(f"gqa-{layer_type}: rope_parameters names no such "
+                         f"layer type: {cfg.rope_parameters}")
+    if not kv_heads[1] or heads[1] % kv_heads[1]:
+        raise ValueError(f"{heads[1]} query heads share no whole number of "
+                         f"{kv_heads[1]} K/V heads")
+    window = cfg.sliding_window if layer_type == "sliding_attention" else None
+    fdims = _fdims(args)
+    with ctx.scope("proj"):
+        q = _project(args, "q_proj", u, fdims, [heads, width])
+        k = _project(args, "k_proj", u, fdims, [kv_heads, width])
+        v = _project(args, "v_proj", u, fdims, [kv_heads, width])
+    with ctx.scope("rotary"):
+        cos, sin = rotary.table(cfg.rope_parameters[layer_type], width[1],
+                                q.dim_size(SEQUENCE))
+        q_rot = (rotary.rotate(q.x, cos, sin) * width[1] ** -0.5).astype(
+            u.dtype)
+        k_rot = rotary.rotate(k.x, cos, sin).astype(u.dtype)
+    with ctx.scope("attention"):
+        o = causal_attention(q_rot, k_rot, v.x, window=window)
+    with ctx.scope("out"):
+        return _project(args, "out_proj", NT(o, q.names), [heads, width],
+                        fdims).transpose_to(u.names)
+
+
 # -- routed experts -----------------------------------------------------------
 
 def routed_mixture_of_experts(args: Args) -> NT:
@@ -279,7 +346,7 @@ def routed_mixture_of_experts(args: Args) -> NT:
             ctx.aux_losses.append(f32(cfg.moe_balance_weight) * cfg.experts
                                   * jnp.sum(load * jnp.mean(share, 0)) / topk)
     with ctx.scope("dispatch"):
-        chunk = min(int(EXPERT_CHUNK_TOKENS * tokens), tokens * topk)
+        chunk = expert_chunk(tokens, topk, held[1], cfg.experts)
         routing = gf.route(picked, cfg.expert_offset, held[1], chunk)
         ctx.expert_load.append(routing.counts)
     with ctx.scope("experts"):

@@ -87,5 +87,6 @@ LAYER_FUNCTIONS: typing.Dict[str, typing.Callable[[Args], NT]] = {
     "gated_feed_forward": hybrid.gated_feed_forward,
     "kda": hybrid.kda,
     "mla": hybrid.mla,
+    "gqa": hybrid.gqa,
     "routed_moe": hybrid.routed_mixture_of_experts,
 }

@@ -6,10 +6,19 @@ time against the keys up to the block's last row (the triangle the mask
 leaves, not the square), and those keys a block at a time with a running
 maximum, sum and output (the online softmax), in float32.
 
+Two layers call it (``models/hybrid.py``): ``mla`` with as many K/V heads
+as query heads, and ``gqa``, whose ``k`` and ``v`` have fewer heads (query
+head ``h`` reads K/V head ``h // group``) and whose sliding layers pass a
+``window``: a row then sees its last ``window`` positions alone, itself
+among them, and only the key tiles that meet that band are computed.
+
 What runs where is decided by the operands' shape alone
-(:func:`takes_kernels`).  A sequence of whole blocks of 512 at head widths
-of whole or half lane tiles (``kimi_linear_48b``: 8,192 tokens, keys 192
-wide, values 128) runs as the two Mosaic kernels of ``ops/pallas_mla.py``,
+(:func:`takes_kernels`; any group and any window run on either path).  A
+sequence of whole blocks of 512 at head widths of whole or half lane tiles
+(``kimi_linear_48b``: 8,192 tokens, keys 192 wide, values 128;
+``mellum2_12b``: 8,192 tokens, 32 query heads over 4 K/V heads, 128 wide,
+window 1,024 on three layers of four) runs as the two Mosaic kernels of
+``ops/pallas_mla.py``,
 forward and backward of one ``custom_vjp``: a tile's scores, running maximum
 and sum, exponentials and weights stay in VMEM, only ``q``, ``k``, ``v``, the
 output and one float32 statistic a row (the log of its sum of exponentials)
@@ -45,8 +54,8 @@ from ..nd import einsum_f32
 from .pallas_mla import BLOCK, VMEM_BYTES, flash_attention, vmem_bytes
 
 
-@functools.partial(jax.checkpoint, static_argnums=(4,))
-def _tile(carry, q, k, v, ahead: int):
+@functools.partial(jax.checkpoint, static_argnums=(4, 5))
+def _tile(carry, q, k, v, ahead: int, window=None):
     """One tile of the online softmax: rows ``q [B, H, R, D]`` against keys
     ``k [B, H, T, D]``, the first of which lies ``ahead`` positions before
     the first row.  ``carry`` is the running (maximum, sum, output).
@@ -57,8 +66,16 @@ def _tile(carry, q, k, v, ahead: int):
         seen = (ahead + jnp.arange(q.shape[2]))[:, None] >= jnp.arange(
             k.shape[2])[None, :]
         scores = jnp.where(seen, scores, -jnp.inf)
+    far = window is not None and ahead + q.shape[2] > window
+    if far:                            # a tile across the band's far edge
+        near = (ahead + jnp.arange(q.shape[2]))[:, None] - jnp.arange(
+            k.shape[2])[None, :] < window
+        scores = jnp.where(near, scores, -jnp.inf)
     # the result does not depend on the running maximum: no gradient
     new_top = jax.lax.stop_gradient(jnp.maximum(top, jnp.max(scores, -1)))
+    if far:
+        # a row the far edge hides this whole tile from, before its first key
+        new_top = jnp.where(new_top > -jnp.inf, new_top, 0.0)
     weights = jnp.exp(scores - new_top[..., None])
     keep = jnp.exp(top - new_top)
     out = out * keep[..., None] + einsum_f32(
@@ -76,23 +93,29 @@ def takes_kernels(q, v) -> bool:
             and vmem_bytes(s, d, d_v, q.dtype.itemsize) <= VMEM_BYTES)
 
 
-def causal_attention(q, k, v, rows: int = 1024):
-    """``softmax(q k^T + causal) v`` for ``q, k [B, S, H, D]`` (``q`` already
-    scaled) and ``v [B, S, H, Dv]``; ``rows`` is the unrolled tiles' size."""
+def causal_attention(q, k, v, rows: int = 1024, window=None):
+    """``softmax(q k^T + mask) v`` for ``q [B, S, H, D]`` (already scaled),
+    ``k [B, S, H / group, D]`` and ``v [B, S, H / group, Dv]``: a row sees
+    the keys up to itself and, with ``window``, only the last ``window`` of
+    them; ``rows`` is the unrolled tiles' size."""
     kernels = takes_kernels(q, v)
     q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     if kernels:
         from . import pallas_interpret
-        out = flash_attention(q, k, v, BLOCK, pallas_interpret())
+        out = flash_attention(q, k, v, BLOCK, window, pallas_interpret())
     else:
-        out = _unrolled_tiles(q, k, v, rows)
+        out = _unrolled_tiles(q, k, v, rows, window)
     return jnp.swapaxes(out, 1, 2)
 
 
-def _unrolled_tiles(q, k, v, rows: int):
+def _unrolled_tiles(q, k, v, rows: int, window=None):
     """The same for heads-major ``[B, H, S, .]`` by the online softmax over
-    unrolled ``rows x rows`` tiles in XLA."""
-    s = q.shape[2]
+    unrolled ``rows x rows`` tiles in XLA; a group's K/V head is repeated
+    plainly (the small shapes that come here can afford it, and autodiff
+    sums its gradient over the group)."""
+    s, group = q.shape[2], q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     blocks = []
     for first in range(0, s, rows):
         mine = q[:, :, first:first + rows]
@@ -105,7 +128,9 @@ def _unrolled_tiles(q, k, v, rows: int):
                  jnp.zeros(mine.shape[:3], jnp.float32),
                  jnp.zeros(mine.shape[:3] + v.shape[-1:], jnp.float32))
         for at in range(0, min(first + rows, s), rows):
+            if window is not None and first - (at + rows - 1) >= window:
+                continue                # the whole tile lies before the band
             carry = _tile(carry, mine, k[:, :, at:at + rows],
-                          v[:, :, at:at + rows], first - at)
+                          v[:, :, at:at + rows], first - at, window)
         blocks.append((carry[2] / carry[1][..., None]).astype(v.dtype))
     return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=2)

@@ -6,11 +6,12 @@ expert's pairs are one run of rows, and the runs are multiplied with their
 experts' matrices by ``jax.lax.ragged_dot`` (one grouped product over all the
 rows: its cost follows the rows, not rows times experts).  The rows are taken
 ``chunk`` at a time, in a loop as long as the held pairs need, read on the
-device.  ``chunk`` is several times the load a balanced router gives this
-share and every chunk is multiplied whole (the rows past the last pair are
-zero rows of weight zero), so one chunk is the rule, its time does not follow
-the routing, and a second one runs only when the imbalance asks for it:
-every pair is computed whatever the load, and no ``[tokens, experts,
+device.  ``chunk`` follows the share of the experts held here
+(``models/hybrid.py::expert_chunk``: four times the load a balanced router
+gives this share, all pairs at most) and every chunk is multiplied whole (the rows past the last pair
+are zero rows of weight zero), so one chunk is the rule, its time does not
+follow the routing, and a second one runs only when the imbalance asks for
+it: every pair is computed whatever the load, and no ``[tokens, experts,
 capacity]`` tensor stands for it.
 """
 from __future__ import annotations

@@ -155,8 +155,12 @@ class Trainer:
                 m["accuracy"] = o.accuracy
             if o.expert_load is not None:
                 # routed experts held here: the selected pairs that fell on
-                # them, and the largest and mean load of one, this update
+                # them, and the largest and mean load of one, this update;
+                # the fullest layer's pairs, over the grouped product's
+                # chunk (hybrid.expert_chunk), are the trips of its loop
                 m["expert_pairs_held"] = jnp.sum(o.expert_load)
+                m["expert_pairs_layer_max"] = jnp.max(
+                    jnp.sum(o.expert_load, -1))
                 m["expert_load_max"] = jnp.max(o.expert_load)
                 m["expert_load_mean"] = jnp.mean(
                     o.expert_load.astype(jnp.float32))

@@ -397,7 +397,7 @@ def test_no_token_is_dropped_when_one_expert_takes_them_all(chunk,
     raw, whole, params, u = _expert_case(10)
     params["routed_moe_/router_bias"] = jnp.zeros((32,)).at[3].set(10.0)
     from homebrewnlp_tpu.models import hybrid
-    monkeypatch.setattr(hybrid, "EXPERT_CHUNK_TOKENS", chunk / 96)
+    monkeypatch.setattr(hybrid, "expert_chunk", lambda *share: chunk)
     got, ctx = run_layer(Config(raw), MOE, _share(params, 0, 8), u)
     assert int(ctx.expert_load[0][3]) == 96
     want = ref._experts(_share(params, 0, 8), u, whole._replace(held=8),

@@ -288,3 +288,65 @@ def test_mla_gradient_runs_two_kernels_and_no_score_tile(
     for name in kernels:
         moved = copies_beside(insts, name, n_b * seq * n_h * cfg.v_head_dim)
         assert not moved, (name, moved)
+
+
+@pytest.mark.parametrize("layer_type", ["sliding_attention", "full_attention"])
+def test_gqa_gradient_runs_two_kernels_and_repeats_no_kv_head(
+        layer_type, one_chip, no_compile_cache, monkeypatch):
+    """`jax.grad` of the `gqa` layer at the widths of mellum2_12b.train (32
+    query heads over 4 K/V heads, 128 wide, 8,192 tokens; a window of 1,024
+    on the sliding layer): Mosaic accepts ops/pallas_mla.py's forward and
+    backward with a K/V head shared by a group of 8 and with the band-limited
+    walk, they are the only custom calls, `k` and `v` reach them with their
+    4 heads (nothing of 32 heads' size is made from them), nothing shaped
+    like a score tile is left under `gqa_`, and nothing the size of `q` is
+    copied on its own next to a kernel."""
+    import homebrewnlp_tpu.ops as ops
+    from homebrewnlp_tpu.models.ctx import Args
+    from homebrewnlp_tpu.models.registry import LAYER_FUNCTIONS
+    from homebrewnlp_tpu.ops.pallas_mla import BLOCK
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "mellum2_12b.json")) as f:
+        raw = {k: v for k, v in json.load(f).items() if k != "benchmark"}
+    cfg = Config(raw)
+    seq = cfg.sequence_length
+    names = ("batch", "sequence", "heads", "features_per_head")
+    n_b, n_h = cfg.train_batch_size, cfg.heads
+
+    def layer(params, x):
+        ctx = Ctx(cfg, params=params, train=params is not None)
+        out = ctx.scoped("gqa_", LAYER_FUNCTIONS["gqa"],
+                         Args(ctx, NT(x, names), [layer_type]))
+        return out.x, ctx.collected
+
+    x = jax.ShapeDtypeStruct((n_b, seq, n_h, cfg.features_per_head),
+                             jnp.bfloat16, sharding=one_chip)
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+              for k, v in jax.eval_shape(lambda x: layer(None, x)[1],
+                                         x).items()}
+
+    def loss(p, x):
+        # the stream made by a fusion, as the block's norm makes it
+        return jnp.sum(jnp.square(layer(p, x * 2)[0].astype(jnp.float32)))
+
+    hlo = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    insts = entry_instructions(hlo)
+    kernels = mosaic_calls(insts)
+    assert sorted(re.search(r"jit\((_mla_attention_\w+)\)",
+                            insts[name][2]).group(1)
+                  for name in kernels) == ["_mla_attention_bwd",
+                                           "_mla_attention_fwd"], sorted(
+                                               kernels)
+    kv = "bf16[%d,%d,%d,%d]" % (n_b, cfg.num_key_value_heads, seq,
+                                cfg.head_dim)
+    for name in kernels:
+        assert insts[name][2].count(kv) >= 2, insts[name][2][:400]
+    tiles = [line.strip()[:160] for line in hlo.splitlines()
+             if re.search(r"= \(?f32\[\d+,\d+,(%d,%d|1024,1024)\]"
+                          % (BLOCK, BLOCK), line) and "gqa_" in line]
+    assert not tiles, tiles
+    for name in kernels:
+        moved = copies_beside(insts, name, n_b * seq * n_h * cfg.head_dim)
+        assert not moved, (name, moved)
