@@ -38,6 +38,8 @@ class ModelOutput(typing.NamedTuple):
     token_out: typing.Optional[NT]
     # [routed layers, experts held]: selected pairs that fell on each
     expert_load: typing.Optional[jnp.ndarray] = None
+    # [routed layers]: rows each layer's grouped products multiplied
+    expert_rows: typing.Optional[jnp.ndarray] = None
 
 
 # -- input ------------------------------------------------------------------
@@ -217,7 +219,8 @@ def _body(ctx: Ctx, src: NT) -> NT:
                     # per-block count is static (set by the block's layer
                     # specs), so the pytree structure is stable
                     return out, (tuple(bctx.aux_losses),
-                                 tuple(bctx.expert_load))
+                                 tuple(bctx.expert_load),
+                                 tuple(bctx.expert_rows))
                 return out
 
             return f
@@ -253,6 +256,7 @@ def _body(ctx: Ctx, src: NT) -> NT:
                 out, aux = f(p, out)
             ctx.aux_losses.extend(aux[0])
             ctx.expert_load.extend(aux[1])
+            ctx.expert_rows.extend(aux[2])
         return out
 
 
@@ -617,9 +621,10 @@ def build(ctx: Ctx, batch: typing.Dict[str, NT]) -> ModelOutput:
     total = loss_list[0]
     for l in loss_list[1:]:
         total = total + l
-    load = jnp.stack(ctx.expert_load) if ctx.expert_load else None
+    load, rows = (jnp.stack(x) if x else None
+                  for x in (ctx.expert_load, ctx.expert_rows))
     return ModelOutput(total, tuple(loss_list), video_loss, acc, token_loss,
-                       frame_out, token_out, load)
+                       frame_out, token_out, load, rows)
 
 
 def _pipeline_seq(cfg: Config):

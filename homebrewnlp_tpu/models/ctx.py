@@ -83,6 +83,9 @@ class Ctx:
         # pairs that fell on each held expert, one vector a routed layer;
         # leaves the body as aux_losses does, for the step's counters
         self.expert_load: typing.List[jnp.ndarray] = []
+        # rows each routed layer's grouped products multiply this step
+        # (trips of its loop times a chunk's rows), beside expert_load
+        self.expert_rows: typing.List[jnp.ndarray] = []
         self.param_count = 0
 
     @property

@@ -40,22 +40,25 @@ from ..ops import rotary
 from ..ops.activations import PLAIN, activate
 from ..ops.block_attention import causal_attention
 from ..ops.delta_rule import chunked_kda
+from ..ops.pallas_gmm import grouped_dot, row_tile
 from .ctx import Args
 from .linear import Dim, linear, normal_var, orthogonal_var
 
 
-#: rows of a chunk of the grouped product, in balanced loads of this share
+#: pairs of a chunk of the grouped product, in balanced loads of this share
 EXPERT_CHUNK_LOADS = 4
 
 
 def expert_chunk(tokens: int, topk: int, held: int, experts: int) -> int:
-    """Rows of a chunk of the grouped product (``ops/grouped_ffn.py``), from
+    """Pairs of a chunk of the grouped product (``ops/grouped_ffn.py``), from
     the share of the experts held here: ``EXPERT_CHUNK_LOADS`` times what a
     balanced router sends this share (``topk * held / experts`` pairs a
-    token), and no more than all pairs.  8 of 256 experts under top-8 get
-    ``tokens`` rows; 16 of 64 get all ``tokens * topk`` pairs, so their loop
-    takes one trip whatever the routing and the step's time cannot follow
-    it (random weights on the toy language send up to 110,000 of a step's
+    token), and no more than all pairs.  A chunk is multiplied by
+    ``ops/pallas_gmm.py``'s Mosaic kernels where its shape lets them (both
+    cells' does), as one row tile an expert more rows than pairs, else by
+    ``jax.lax.ragged_dot``.  8 of 256 experts under top-8 get ``tokens``
+    pairs; 16 of 64 get all ``tokens * topk`` pairs, so their loop takes one
+    trip whatever the routing and the step's time cannot follow it (random weights on the toy language send up to 110,000 of a step's
     131,072 pairs to 16 held experts, and 33,000 on another seed: under a
     chunk of twice the balanced load the trips, and 8% of ``tokens_per_s``,
     followed the seed; PERF.md, PR 31)."""
@@ -294,7 +297,8 @@ def routed_mixture_of_experts(args: Args) -> NT:
 
     With ``moe_balance_weight > 0`` a Switch-style balance term (1.0 at a
     uniform load) joins ``ctx.aux_losses``; the load of each held expert
-    joins ``ctx.expert_load`` for the step's counters.
+    joins ``ctx.expert_load`` and the rows its products multiplied
+    ``ctx.expert_rows``, for the step's counters.
     """
     cfg, ctx, t = args.cfg, args.ctx, args.tensor
     topk, shared = 1, 0
@@ -347,16 +351,19 @@ def routed_mixture_of_experts(args: Args) -> NT:
                                   * jnp.sum(load * jnp.mean(share, 0)) / topk)
     with ctx.scope("dispatch"):
         chunk = expert_chunk(tokens, topk, held[1], cfg.experts)
-        routing = gf.route(picked, cfg.expert_offset, held[1], chunk)
+        tile = row_tile(chunk, held[1], width, inter[1], x.dtype.itemsize)
+        routing = gf.route(picked, cfg.expert_offset, held[1])
         ctx.expert_load.append(routing.counts)
+        ctx.expert_rows.append(gf.rows_multiplied(routing, chunk, tile))
     with ctx.scope("experts"):
         def expert(rows, sizes, *mats):
-            hidden = PLAIN[act](jax.lax.ragged_dot(rows, mats[0], sizes))
+            hidden = PLAIN[act](grouped_dot(rows, mats[0], sizes))
             if gated:
-                hidden = hidden * jax.lax.ragged_dot(rows, mats[1], sizes)
-            return jax.lax.ragged_dot(hidden, mats[-1], sizes)
+                hidden = hidden * grouped_dot(rows, mats[1], sizes)
+            return grouped_dot(hidden, mats[-1], sizes)
 
-        y = gf.grouped_ffn(expert, chunk, x, tuple(stacks), weight, routing)
+        y = gf.grouped_ffn(expert, chunk, tile, x, tuple(stacks), weight,
+                           routing)
     out = NT(y.reshape(xt.x.shape), xt.names)
     if shared:
         with ctx.scope("shared"):

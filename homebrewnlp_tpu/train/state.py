@@ -157,10 +157,15 @@ class Trainer:
                 # routed experts held here: the selected pairs that fell on
                 # them, and the largest and mean load of one, this update;
                 # the fullest layer's pairs, over the grouped product's
-                # chunk (hybrid.expert_chunk), are the trips of its loop
-                m["expert_pairs_held"] = jnp.sum(o.expert_load)
-                m["expert_pairs_layer_max"] = jnp.max(
-                    jnp.sum(o.expert_load, -1))
+                # chunk (hybrid.expert_chunk), are the trips of its loop, and
+                # over the rows those trips multiply (the rows that align a
+                # run to the kernels' tile and the last chunk's tail among
+                # them) the share of its products' rows that hold a pair
+                pairs = jnp.sum(o.expert_load, -1)
+                m["expert_pairs_held"] = jnp.sum(pairs)
+                m["expert_pairs_layer_max"] = jnp.max(pairs)
+                m["expert_rows_filled"] = jnp.max(pairs) / jnp.maximum(
+                    o.expert_rows[jnp.argmax(pairs)], 1)
                 m["expert_load_max"] = jnp.max(o.expert_load)
                 m["expert_load_mean"] = jnp.mean(
                     o.expert_load.astype(jnp.float32))

@@ -462,6 +462,21 @@ def test_step_scope_gives_every_new_layer_its_own_layer_and_pass():
              "backward")):
         assert P.step_scope(name) == (pass_, name.split("/body/")[-1][:4],
                                       "kda"), name
+    # ops/pallas_gmm.py's kernels in the experts' loops (the toy width keeps
+    # `ragged_dot`): the forward's products again inside the backward's
+    # `jax.vjp`, then the rows' and the stacks' gradients
+    under = "/block_/routed_moe_/experts/while/body/"
+    for name, pass_ in (
+            (step + "jvp(gpt)/body/gpt/body/%s" + under
+             + "jit(_gmm_rows)/pallas_call", "forward"),
+            (back + "gpt/body/%s" + under
+             + "jvp(jit(_gmm_rows))/pallas_call", "backward"),
+            (back + "gpt/body/%s" + under
+             + "transpose(jvp(jit(_gmm_rows)))/pallas_call", "backward"),
+            (back + "gpt/body/%s" + under
+             + "transpose(jvp(jit(_gmm_weights)))/pallas_call", "backward")):
+        assert P.step_scope(name % "d4_1") == (pass_, "d4_1",
+                                               "routed_moe"), name
 
 
 # -- (f) the two configuration files ------------------------------------------

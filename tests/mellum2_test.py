@@ -163,6 +163,19 @@ def test_step_reports_the_load_of_the_held_experts(followed):
     assert float(metrics["expert_load_mean"]) == pytest.approx(pairs / 32)
 
 
+def test_step_reports_the_share_of_multiplied_rows_that_hold_a_pair(followed):
+    """`expert_rows_filled`: the fullest layer's held pairs over the rows its
+    loop multiplied.  At the toy width no run is laid out on tiles, so those
+    are the loop's trips times the chunk (all 768 pairs here: one trip)."""
+    program = followed[2]
+    program.state, metrics = program.trainer.step(
+        program.state, program.ring[1], jax.random.key(1))
+    fullest = float(metrics["expert_pairs_layer_max"])
+    assert 0 < fullest <= 768
+    assert float(metrics["expert_rows_filled"]) == pytest.approx(
+        fullest / 768)
+
+
 @pytest.mark.parametrize("case", sorted(ref.LOWER))
 def test_every_planted_fault_moves_the_reference(followed, case):
     """Each case of `LOWER` is a different model at the toy size too: it
@@ -504,6 +517,21 @@ def test_step_scope_gives_the_new_scopes_their_layer_and_pass():
              "backward")):
         assert P.step_scope(name) == (pass_, name.split("/body/")[-1][:4],
                                       "gqa"), name
+    # ops/pallas_gmm.py's kernels in the experts' loops (the toy width keeps
+    # `ragged_dot`): the forward's products again inside the backward's
+    # `jax.vjp`, then the rows' and the stacks' gradients
+    under = "/block_/routed_moe_/experts/while/body/"
+    for name, pass_ in (
+            (step + "jvp(gpt)/body/gpt/body/%s" + under
+             + "jit(_gmm_rows)/pallas_call", "forward"),
+            (back + "gpt/body/%s" + under
+             + "jvp(jit(_gmm_rows))/pallas_call", "backward"),
+            (back + "gpt/body/%s" + under
+             + "transpose(jvp(jit(_gmm_rows)))/pallas_call", "backward"),
+            (back + "gpt/body/%s" + under
+             + "transpose(jvp(jit(_gmm_weights)))/pallas_call", "backward")):
+        assert P.step_scope(name % "d3_2") == (pass_, "d3_2",
+                                               "routed_moe"), name
 
 
 # -- (f) the two configuration files ------------------------------------------

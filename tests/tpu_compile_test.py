@@ -75,9 +75,14 @@ def compiled_gradient_hlo(cfg: Config, sharding) -> str:
 def entry_instructions(hlo: str) -> dict:
     """name -> (opcode, operand names, the instruction's text) of the entry
     computation."""
-    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo, re.S | re.M).group(1)
+    return instructions(
+        re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo, re.S | re.M).group(1))
+
+
+def instructions(computation: str) -> dict:
+    """The same for the body of any one computation."""
     out = {}
-    for line in entry.splitlines():
+    for line in computation.splitlines():
         m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)", line)
         if not m:
             continue
@@ -350,3 +355,71 @@ def test_gqa_gradient_runs_two_kernels_and_repeats_no_kv_head(
     for name in kernels:
         moved = copies_beside(insts, name, n_b * seq * n_h * cfg.head_dim)
         assert not moved, (name, moved)
+
+
+def _prefetch(line: str) -> bool:
+    """Whether a copy only moves its operand to another memory space (the
+    compiler's own prefetch for the next fusion: same shape, same tiling)."""
+    shapes = re.findall(r"(\w+\[[\d,]*\]\{[^}]*\})", line)[:2]
+    return (line.startswith("%copy-start") and len(shapes) == 2
+            and len({re.sub(r"S\(\d+\)", "", s) for s in shapes}) == 1)
+
+
+def test_expert_layer_gradient_runs_the_grouped_kernels_alone(
+        one_chip, no_compile_cache, monkeypatch):
+    """`jax.grad` of one `routed_moe` layer at the widths of
+    mellum2_12b.train (16 of 64 experts 896 wide on a stream of 2,304, all
+    131,072 pairs of 16,384 tokens in one chunk): Mosaic accepts
+    ops/pallas_gmm.py's two kernels, they are the loops' only custom calls
+    (three `_gmm_rows` in the forward loop; in the backward three more, the
+    three transposed ones and three `_gmm_weights`), no `ragged-dot` is
+    left, a stack reaches the transposed product as it is stored (nothing a
+    stack's size is copied or transposed next to a kernel), and nothing the
+    size of a chunk's narrower operand either."""
+    import homebrewnlp_tpu.ops as ops
+    from homebrewnlp_tpu.models.ctx import Args
+    from homebrewnlp_tpu.models.registry import LAYER_FUNCTIONS
+    from homebrewnlp_tpu.ops.pallas_gmm import ROW_TILE, aligned_rows
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "mellum2_12b.json")) as f:
+        raw = {k: v for k, v in json.load(f).items() if k != "benchmark"}
+    cfg = Config(raw)
+    names = ("batch", "sequence", "heads", "features_per_head")
+    spec = [e for part in raw["block_config"] for e in part["layer"]
+            if e.startswith("routed_moe")][0].split("-")[1:]
+
+    def layer(params, x):
+        ctx = Ctx(cfg, params=params, train=params is not None)
+        out = ctx.scoped("routed_moe_", LAYER_FUNCTIONS["routed_moe"],
+                         Args(ctx, NT(x, names), spec))
+        return out.x, ctx.collected
+
+    x = jax.ShapeDtypeStruct(
+        (cfg.train_batch_size, cfg.sequence_length, cfg.heads,
+         cfg.features_per_head), jnp.bfloat16, sharding=one_chip)
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+              for k, v in jax.eval_shape(lambda x: layer(None, x)[1],
+                                         x).items()}
+
+    def loss(p, x):
+        return jnp.sum(jnp.square(layer(p, x * 2)[0].astype(jnp.float32)))
+
+    hlo = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
+    assert not re.search(r" ragged-dot\(", hlo)
+    held, inter = cfg.experts_held, cfg.moe_intermediate_size
+    rows = aligned_rows(16384 * 8, held, ROW_TILE)
+    found = []
+    for body in re.findall(r"^%?[\w.\-]+ \([^\n]*\{\n(.*?)^\}", hlo,
+                           re.S | re.M):
+        insts = instructions(body)
+        for name in mosaic_calls(insts):
+            found.append(re.search(r"jit\((_gmm_\w+)\)",
+                                   insts[name][2]).group(1))
+            assert "bf16[%d," % rows in insts[name][2], insts[name][2][:300]
+            moved = [line for line in copies_beside(
+                insts, name, min(rows, held * 2304) * inter)
+                if not _prefetch(line)]
+            assert not moved, (name, moved)
+    assert sorted(found) == ["_gmm_rows"] * 9 + ["_gmm_weights"] * 3, found
