@@ -79,13 +79,14 @@ DTYPES = {
 # carries beside this repo's keys, for the record of what was published:
 # nothing reads them (the block DSL says the same), so they are no typo
 UPSTREAM_KEYS = frozenset((
-    "attention_bias", "first_k_dense_replace", "hidden_act", "hidden_size",
-    "intermediate_size", "layer_types", "max_position_embeddings",
-    "max_window_layers", "mla_use_nope", "mlp_layer_types",
-    "model_max_length", "model_type", "moe_layer_freq", "moe_renormalize",
-    "moe_router_activation_func", "norm_topk_prob", "num_expert_group",
-    "num_experts", "num_experts_per_tok", "num_experts_per_token",
-    "num_hidden_layers", "num_nextn_predict_layers", "num_shared_experts",
+    "attention_bias", "first_k_dense_replace", "gqa_interval", "gqa_layers",
+    "hidden_act", "hidden_size", "intermediate_size", "layer_types",
+    "max_position_embeddings", "max_window_layers", "mla_use_nope",
+    "mlp_layer_types", "model_max_length", "model_type", "moe_layer_freq",
+    "moe_renormalize", "moe_router_activation_func", "n_routed_experts",
+    "n_shared_experts", "norm_topk_prob", "num_expert_group", "num_experts",
+    "num_experts_per_tok", "num_experts_per_token", "num_hidden_layers",
+    "num_nextn_predict_layers", "num_shared_experts", "partial_rotary_factor",
     "q_lora_rank", "rope_scaling", "rope_theta", "tie_word_embeddings",
     "topk_group", "use_grouped_topk", "use_sliding_window"))
 
@@ -340,8 +341,13 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     routed_scaling_factor=1.0,
     rms_norm_eps=1e-5,
     # kda: {"num_heads", "head_dim", "short_conv_kernel_size"} as upstream
-    # names them (the two low-rank gate pairs take head_dim as their rank)
+    # names them (the two low-rank gate pairs take head_dim as their rank);
+    # `kda_allow_neg_eigval`: beta = 2 * sigmoid(.), so that the eigenvalues
+    # of I - beta k k^T lie in (-1, 1); `kda_use_full_proj` true (full-rank
+    # gates in the low-rank pairs' place) is not written and is refused
     linear_attn_config=None,
+    kda_allow_neg_eigval=False,
+    kda_use_full_proj=False,
     # mla (latent K/V attention, no positions)
     kv_lora_rank=None,
     qk_nope_head_dim=None,
@@ -352,12 +358,18 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     # of `head_dim` read `num_key_value_heads` K/V heads; `rope_parameters`
     # maps a layer type ("sliding_attention", "full_attention") to its
     # rotary table's {"rope_type": "default" | "yarn", "rope_theta", ...};
-    # a sliding layer sees the last `sliding_window` positions
+    # a sliding layer sees the last `sliding_window` positions.  `use_rope`
+    # false: no rotation at all, and the block part names no table
+    # (`gqa-nope`); `use_gqa_gate`: sigmoid(u W_g), one gate a channel of
+    # every head, on the attention's result before the output matrix
+    # (`gqa-...-gated`)
     num_attention_heads=None,
     num_key_value_heads=None,
     head_dim=None,
     rope_parameters=None,
     sliding_window=None,
+    use_rope=True,
+    use_gqa_gate=False,
     # false: the table holds one stream-wide row a token, no factorisation
     factorized_embedding=True,
     # which block_config entries run at which depth: one list of indices a
@@ -966,6 +978,11 @@ class Config:
             raise ValueError(
                 f"experts_held={self.experts_held} from expert_offset="
                 f"{self.expert_offset} is no share of experts={self.experts}")
+        if self.kda_use_full_proj:
+            raise ValueError(
+                "kda_use_full_proj=true asks for full-rank gate maps in kda; "
+                "only the two low-rank pairs (decay_down/up, out_down/up) "
+                "are written")
 
         # video patch arithmetic (reference dataclass.py:262-271)
         self.time_patch_size = self.sequence_length // self.time_patch

@@ -55,8 +55,11 @@ _NO_CACHE_YET = {
     "gqa": "decoding needs a cache of the K/V heads alone (k and v of "
            "num_key_value_heads heads a position, k rotated at its own "
            "offset when it is written), a ring of sliding_window positions "
-           "for the sliding layers beside a whole one for the full layers; "
-           "without it every token rebuilds the whole sequence",
+           "for the sliding layers beside a whole one for the full layers "
+           "(a layer without positions, gqa-nope, writes k as it is; a "
+           "gated one reads its gate from the decoded token's own input "
+           "and caches nothing for it); without it every token rebuilds "
+           "the whole sequence",
 }
 
 

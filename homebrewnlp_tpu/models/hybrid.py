@@ -10,10 +10,12 @@ Six layers of the block DSL, each declared once (ROADMAP D8, R2, R3, R5):
   (``ops/delta_rule.py``);
 - ``mla``: causal softmax attention whose keys and values are expanded from
   one low-rank latent a token, with no positions (``ops/block_attention.py``);
-- ``gqa-<layer type>``: causal softmax attention whose query heads share
-  fewer K/V heads, with rotary positions from the layer type's table and,
-  on a ``sliding_attention`` layer, a window (``ops/rotary.py``,
-  ``ops/block_attention.py``);
+- ``gqa-<layer type>[-gated]``: causal softmax attention whose query heads
+  share fewer K/V heads, with rotary positions from the layer type's table
+  and, on a ``sliding_attention`` layer, a window (``ops/rotary.py``,
+  ``ops/block_attention.py``); ``gqa-nope[-gated]`` is the layer without
+  positions (``use_rope`` false), ``gated`` the one whose result a sigmoid
+  gate of the layer's input multiplies (``use_gqa_gate``);
 - ``routed_moe[-topk<k>][-sigmoid][-bias][-gated][-shared<n>][-in:<act>]``:
   the one routed expert layer, with nothing dropped (``ops/grouped_ffn.py``).
 
@@ -151,6 +153,9 @@ def kda(args: Args) -> NT:
         g = -exp(a_log) * softplus((u W_fa) W_fb + dt_bias)  (per channel)
         beta = sigmoid(u W_beta)                             (per head)
         y = (rms_head(o) * sigmoid((u W_ga) W_gb)) W_o
+
+    Under ``kda_allow_neg_eigval`` ``beta = 2 sigmoid(u W_beta)``: ``I - beta
+    k k^T`` then has its eigenvalue along ``k`` in (-1, 1), not in (0, 1).
     """
     cfg, ctx, u = args.cfg, args.ctx, args.tensor
     conf = cfg.linear_attn_config
@@ -179,6 +184,8 @@ def kda(args: Args) -> NT:
             decay.x.astype(f32) + dt_bias.x.astype(f32))
         beta = jax.nn.sigmoid(_project(args, "beta", u, fdims, [heads]
                                        ).x.astype(f32))
+        if cfg.kda_allow_neg_eigval:
+            beta = 2 * beta
         low = _project(args, "out_down", u, fdims, [rank])
         gate = jax.nn.sigmoid(_project(args, "out_up", low, [rank],
                                        [heads, width]).x.astype(f32))
@@ -189,7 +196,8 @@ def kda(args: Args) -> NT:
                                              keepdims=True) + 1e-6)
         kind = u.x.dtype
         o = chunked_kda((unit(q.x) * width[1] ** -0.5).astype(kind),
-                        unit(k.x).astype(kind), v.x, g, beta)
+                        unit(k.x).astype(kind), v.x, g, beta,
+                        wide_beta=cfg.kda_allow_neg_eigval)
     with ctx.scope("out"):
         scale = normal_var(args, [width], mean=1.0, name="norm_scale")
         o = NT((_rms(o, scale.x, cfg.rms_norm_eps) * gate).astype(kind),
@@ -234,45 +242,72 @@ def mla(args: Args) -> NT:
 # -- gqa ----------------------------------------------------------------------
 
 def gqa(args: Args) -> NT:
-    """Grouped-query attention with rotary positions; the one extra names
-    the layer type, upstream's ``layer_types`` entry, which picks the rotary
-    table (``rope_parameters[<layer type>]``) and, for ``sliding_attention``,
-    the window (``sliding_window``):
+    """Grouped-query attention.  With rotary positions (``use_rope``, the
+    default) the one extra besides ``gated`` names the layer type, upstream's
+    ``layer_types`` entry, which picks the rotary table
+    (``rope_parameters[<layer type>]``) and, for ``sliding_attention``, the
+    window (``sliding_window``); without them the part reads ``gqa-nope``:
+    no table is built, nothing is rotated, every earlier position is seen.
 
         q = u W_q -> [heads, head_dim];  k, v = u W_k, u W_v -> [kv heads, .]
         q, k = rot(q, pos), rot(k, pos)          (ops/rotary.py, float32)
         query head h reads K/V head h // (heads / kv heads)
-        y = concat_h softmax(q_h k^T / sqrt(head_dim) + mask) v  W_o
+        o = concat_h softmax(q_h k^T / sqrt(head_dim) + mask) v
+        y = o W_o,  gated (use_gqa_gate): y = (o * sigmoid(u W_g)) W_o
         mask: key <= row, and under a window also row - key < sliding_window
 
     No bias and no norm on q or k.  The scale goes into ``q`` with the
-    rotation, before the one rounding to the stream's type.
+    rotation, or alone, before the one rounding to the stream's type.  The
+    gate is one number a channel of every query head, from the layer's
+    input, in float32.  The part's spelling and the config's two keys say
+    the same layer twice on purpose: a program that knows neither key (it
+    would only warn of them) fails on the spelling at build.
     """
     cfg, ctx, u = args.cfg, args.ctx, args.tensor
-    layer_type, = args.name_extras
+    layer_types = [e for e in args.name_extras if e != "gated"]
+    spelt = "-".join(["gqa"] + args.name_extras)
+    if (layer_types == ["nope"]) == cfg.use_rope or (
+            "gated" in args) != cfg.use_gqa_gate:
+        raise ValueError(
+            f"{spelt} beside use_rope={cfg.use_rope}, use_gqa_gate="
+            f"{cfg.use_gqa_gate}: a layer without positions is spelt "
+            f"gqa-nope and has use_rope false, a gated one ends in -gated "
+            f"and has use_gqa_gate true")
     width = (MIXER_KEY, cfg.head_dim)
     heads = (MIXER_HEADS, cfg.num_attention_heads or cfg.heads)
     kv_heads = (KV_HEADS, cfg.num_key_value_heads)
-    if layer_type not in (cfg.rope_parameters or {}):
-        raise ValueError(f"gqa-{layer_type}: rope_parameters names no such "
+    if cfg.use_rope and (len(layer_types) != 1 or layer_types[0] not in (
+            cfg.rope_parameters or {})):
+        raise ValueError(f"{spelt}: rope_parameters names no such "
                          f"layer type: {cfg.rope_parameters}")
     if not kv_heads[1] or heads[1] % kv_heads[1]:
         raise ValueError(f"{heads[1]} query heads share no whole number of "
                          f"{kv_heads[1]} K/V heads")
-    window = cfg.sliding_window if layer_type == "sliding_attention" else None
+    window = (cfg.sliding_window if layer_types == ["sliding_attention"]
+              else None)
     fdims = _fdims(args)
+    scale = width[1] ** -0.5
     with ctx.scope("proj"):
         q = _project(args, "q_proj", u, fdims, [heads, width])
         k = _project(args, "k_proj", u, fdims, [kv_heads, width])
         v = _project(args, "v_proj", u, fdims, [kv_heads, width])
-    with ctx.scope("rotary"):
-        cos, sin = rotary.table(cfg.rope_parameters[layer_type], width[1],
-                                q.dim_size(SEQUENCE))
-        q_rot = (rotary.rotate(q.x, cos, sin) * width[1] ** -0.5).astype(
-            u.dtype)
-        k_rot = rotary.rotate(k.x, cos, sin).astype(u.dtype)
+        if not cfg.use_rope:
+            q_in = (q.x.astype(jnp.float32) * scale).astype(u.dtype)
+            k_in = k.x
+    if cfg.use_rope:
+        with ctx.scope("rotary"):
+            cos, sin = rotary.table(cfg.rope_parameters[layer_types[0]],
+                                    width[1], q.dim_size(SEQUENCE))
+            q_in = (rotary.rotate(q.x, cos, sin) * scale).astype(u.dtype)
+            k_in = rotary.rotate(k.x, cos, sin).astype(u.dtype)
     with ctx.scope("attention"):
-        o = causal_attention(q_rot, k_rot, v.x, window=window)
+        o = causal_attention(q_in, k_in, v.x, window=window)
+    if cfg.use_gqa_gate:
+        with ctx.scope("gate"):
+            gate = jax.nn.sigmoid(_project(args, "gate_proj", u, fdims,
+                                           [heads, width]).x.astype(
+                                               jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(u.dtype)
     with ctx.scope("out"):
         return _project(args, "out_proj", NT(o, q.names), [heads, width],
                         fdims).transpose_to(u.names)

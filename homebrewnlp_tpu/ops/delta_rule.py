@@ -28,6 +28,21 @@ tokens and splits every other pair's decay at a point between ``i`` and
 The decay, ``A``, ``P`` and the inverse are float32; the products against the
 state take their operands in the type of ``q`` and add up in float32.
 
+``beta`` is an operand and nothing here bounds it.  One place leans on its
+size all the same, in float32 only: ``(I + A)^-1`` by repeated squaring
+carries the powers of ``A`` up to ``A^(C/2)``, whose entries grow like
+``binom(C, C/2) (beta k_t.k_i)^(C/2)`` before they cancel in the product.
+With ``beta <= 1`` and keys a seeded model makes they stay near 1; with
+``beta`` up to 2 (``wide_beta``: ``kda_allow_neg_eigval``) and keys that lie
+close together they pass 1e4 beside an inverse of 1: at the widths of
+``solar_open2_250b.train`` the third and fourth layers stood 1.3e-3 of their
+scale from the recurrence, and keys at cosines of 0.9 lose the result
+whole (tests/solar_open2_test.py).  There the
+squaring runs only inside diagonal blocks of ``sub`` tokens and the blocks
+are merged pair by pair (``[[P, 0], [R, Q]]^-1 = [[P^-1, 0], [-Q^-1 R P^-1,
+Q^-1]]``): every intermediate is the inverse of a part of the chunk, as
+large as the result and no larger, for as many matrix products.
+
 Two stages: the chunks' own work (``A``, ``P``, the inverse) runs ``group``
 chunks at a time, each group recomputed in the backward so that the
 ``[C, C, d_k]`` products never outlive their step; the state then walks the
@@ -81,23 +96,39 @@ def _pair_products(rows, k, run, sub: int):
     return out.reshape(out.shape[:-4] + (c, c))
 
 
-def _unit_lower_inverse(a):
+def _unit_lower_inverse(a, inside: int):
     """``(I + a)^-1`` for strictly lower-triangular ``a [..., C, C]``, in
-    float32 matrix products: ``a`` is nilpotent, so with ``n = -a`` the
-    inverse is ``(I + n)(I + n^2)(I + n^4) ...`` up to the power ``C / 2``.
-    (The chip's triangular solve took a quarter of the mixer's time.)"""
+    float32 matrix products.  Inside diagonal blocks of ``inside`` rows:
+    ``a`` is nilpotent, so with ``n = -a`` the inverse is ``(I + n)(I +
+    n^2)(I + n^4) ...`` up to the power ``inside / 2``.  (The chip's
+    triangular solve took a quarter of the mixer's time.)  Blocks smaller
+    than the chunk are then merged pair by pair: with ``m`` the inverse of
+    the blocks on the diagonal and ``r`` what ``a`` holds between the two
+    blocks of a pair, the pairs' inverse is ``m - m r m``."""
     c = a.shape[-1]
     power = -a
+    if inside < c:
+        at = jnp.arange(c)
+        together = lambda size: at[:, None] // size == at[None, :] // size
+        power = jnp.where(together(inside), power, 0.0)
     inverse = power + jnp.eye(c, dtype=a.dtype)
-    for _ in range(max(0, (c - 1).bit_length() - 1)):
+    for _ in range(max(0, (min(inside, c) - 1).bit_length() - 1)):
         power = jnp.matmul(power, power, precision=_HIGHEST)
         inverse = inverse + jnp.matmul(inverse, power, precision=_HIGHEST)
+    size = inside
+    while size < c:
+        between = jnp.where(together(2 * size) & ~together(size), a, 0.0)
+        inverse = inverse - jnp.matmul(
+            jnp.matmul(inverse, between, precision=_HIGHEST), inverse,
+            precision=_HIGHEST)
+        size *= 2
     return inverse
 
 
-def _within_chunk(q, k, v, g, beta, sub: int):
+def _within_chunk(q, k, v, g, beta, sub: int, inside: int):
     """What a chunk needs besides the state it starts from.  All arguments
-    ``[..., C, d]`` float32 (``beta`` ``[..., C]``)."""
+    ``[..., C, d]`` float32 (``beta`` ``[..., C]``); ``inside`` is
+    :func:`_unit_lower_inverse`'s."""
     c = q.shape[-2]
     d_k = k.shape[-1]
     run = jnp.cumsum(g, axis=-2)                                  # G, <= 0
@@ -108,7 +139,8 @@ def _within_chunk(q, k, v, g, beta, sub: int):
                   pairs[..., 1, :, :] * beta[..., None], 0.0)     # i < t
     into = jnp.exp(run)
     rhs = jnp.concatenate([k * into, v], -1) * beta[..., None]
-    solved = jnp.matmul(_unit_lower_inverse(a), rhs, precision=_HIGHEST)
+    solved = jnp.matmul(_unit_lower_inverse(a, inside), rhs,
+                        precision=_HIGHEST)
     out_of = jnp.exp(run[..., -1:, :] - run)                      # G_C - G_t
     return (solved[..., :d_k], solved[..., d_k:], p, q * into, k * out_of,
             into[..., -1, :])
@@ -139,10 +171,12 @@ def _walk_state(w, u, p, q_in, k_out, last):
 
 
 def chunked_kda(q, k, v, g, beta, chunk: int = 32, sub: int = 8,
-                group: int = 0):
+                group: int = 0, wide_beta: bool = False):
     """``o`` of the recurrence above for ``q, k [B, T, H, d_k]``, ``v [B, T,
     H, d_v]``, ``g [B, T, H, d_k]`` (``<= 0``) and ``beta [B, T, H]``; ``T``
-    need be no multiple of the chunk, the chunk is one of ``sub``.  The
+    need be no multiple of the chunk, the chunk is one of ``sub``.
+    ``wide_beta`` says that ``beta`` may pass 1 (up to 2): the chunk's
+    inverse is then squared inside blocks of ``sub`` tokens only.  The
     result has the type of ``v``."""
     if chunk % sub:
         raise ValueError(f"a chunk of {chunk} is no multiple of {sub}")
@@ -151,18 +185,21 @@ def chunked_kda(q, k, v, g, beta, chunk: int = 32, sub: int = 8,
     n = -(-t // chunk)
     group = min(group or max(1, 256 // chunk), n)   # 256 tokens a step
     n_pad = -(-n // group) * group
+    inside = sub if wide_beta else chunk
 
     if not (q.shape[-1] % 128 or d_v % 128 or sub % 8):
-        parts = _kernel_parts(q, k, v, g, beta, chunk, sub, group, n_pad)
+        parts = _kernel_parts(q, k, v, g, beta, chunk, sub, group, n_pad,
+                              inside)
     else:
-        parts = _scan_parts(q, k, v, g, beta, chunk, sub, group, n_pad)
+        parts = _scan_parts(q, k, v, g, beta, chunk, sub, group, n_pad,
+                            inside)
     out = _walk_state(*parts)
     out = jnp.moveaxis(out, 0, 1)                                 # [B,N,H,C,d]
     out = jnp.moveaxis(out, 2, 3).reshape(b, n_pad * chunk, h, d_v)
     return out[:, :t].astype(v.dtype)
 
 
-def _scan_parts(q, k, v, g, beta, chunk, sub, group, n_pad):
+def _scan_parts(q, k, v, g, beta, chunk, sub, group, n_pad, inside):
     """``_walk_state``'s arguments by a scan over groups of chunks: every
     shape's path, and the kernel's oracle."""
     kind = q.dtype
@@ -180,7 +217,8 @@ def _scan_parts(q, k, v, g, beta, chunk, sub, group, n_pad):
 
     @jax.checkpoint
     def within(_, part):
-        w, u, p, q_in, k_out, last = _within_chunk(*part, sub=sub)
+        w, u, p, q_in, k_out, last = _within_chunk(*part, sub=sub,
+                                                    inside=inside)
         return None, (w.astype(kind), u.astype(kind), p.astype(kind),
                       q_in.astype(kind), k_out.astype(kind), last)
 
@@ -189,7 +227,7 @@ def _scan_parts(q, k, v, g, beta, chunk, sub, group, n_pad):
     return tuple(x.reshape((n_pad,) + x.shape[2:]) for x in parts)
 
 
-def _kernel_parts(q, k, v, g, beta, chunk, sub, group, n_pad):
+def _kernel_parts(q, k, v, g, beta, chunk, sub, group, n_pad, inside):
     """The same by ``ops/pallas_kda.py``'s kernels, for head widths that are
     whole lane tiles: operands heads-major in float32, as ``_scan_parts``
     casts them, results chunk axis first as the kernel writes them."""
@@ -205,5 +243,5 @@ def _kernel_parts(q, k, v, g, beta, chunk, sub, group, n_pad):
     f32 = lambda x: heads_major(x.astype(jnp.float32))
     *parts, last = kda_chunks(
         f32(q), f32(k), f32(v), f32(g), f32(beta), jnp.dtype(q.dtype), chunk,
-        sub, group, pallas_interpret())
+        sub, group, inside, pallas_interpret())
     return (*parts, last[..., 0, :])

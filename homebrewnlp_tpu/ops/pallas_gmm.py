@@ -24,6 +24,8 @@ products summed in float32, every result rounded once to the operands' type.
 from __future__ import annotations
 
 import functools
+import logging
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -56,10 +58,28 @@ def vmem_bytes(k: int, n: int, itemsize: int) -> int:
     return blocks + 4 * max(ROW_TILE * max(k, n), 2 * k * n) + 4 * 2 ** 20
 
 
+def refusals(m: int, groups: int, k: int, n: int, itemsize: int
+             ) -> typing.List[str]:
+    """Every clause by which the kernels turn a product away; none where
+    they take it."""
+    out = []
+    if m % ROW_TILE:
+        out.append(f"{m} rows are no whole number of tiles of {ROW_TILE}")
+    if k % 128 or n % 128:
+        out.append(f"K {k} or N {n} is no multiple of 128")
+    least = (ROWS_A_TILE_ROW + 1) * groups * ROW_TILE
+    if m < least:
+        out.append(f"{m} rows are fewer than {ROWS_A_TILE_ROW + 1} tiles a "
+                   f"group ({least})")
+    need = vmem_bytes(k, n, itemsize)
+    if need > VMEM_BYTES:
+        out.append(f"the blocks of [{k} x {n}] need {need} bytes of VMEM, "
+                   f"over {VMEM_BYTES}")
+    return out
+
+
 def _fits(m: int, groups: int, k: int, n: int, itemsize: int) -> bool:
-    return (m % ROW_TILE == 0 and k % 128 == 0 and n % 128 == 0
-            and m >= (ROWS_A_TILE_ROW + 1) * groups * ROW_TILE
-            and vmem_bytes(k, n, itemsize) <= VMEM_BYTES)
+    return not refusals(m, groups, k, n, itemsize)
 
 
 def takes_kernels(rows, stack) -> bool:
@@ -80,10 +100,23 @@ def row_tile(pairs: int, groups: int, k: int, n: int, itemsize: int) -> int:
     """What the runs of ``pairs`` rows in all must start on multiples of
     before :func:`grouped_dot` multiplies them with stacks ``[groups, k, n]``
     and ``[groups, n, k]``: ``ROW_TILE`` where the kernels take the rows so
-    laid out, else 1 (``ragged_dot`` takes any run)."""
-    fits = _fits(aligned_rows(pairs, groups, ROW_TILE), groups, k, n,
-                 itemsize)
-    return ROW_TILE if fits else 1
+    laid out, else 1 (``ragged_dot`` takes any run).  The log says once a
+    shape which of the two it is, and by which clauses."""
+    why = refusals(aligned_rows(pairs, groups, ROW_TILE), groups, k, n,
+                   itemsize)
+    _say_once(pairs, groups, k, n, "; ".join(why))
+    return 1 if why else ROW_TILE
+
+
+@functools.lru_cache(maxsize=None)
+def _say_once(pairs: int, groups: int, k: int, n: int, why: str) -> None:
+    log = logging.getLogger(__name__)
+    what = f"grouped products of {pairs} pairs with {groups} x [{k} x {n}]"
+    if why:
+        log.warning("%s run as jax.lax.ragged_dot: %s", what, why)
+    else:
+        log.info("%s run as the Mosaic kernels _gmm_rows / _gmm_weights",
+                 what)
 
 
 def tile_groups(sizes, tiles: int):

@@ -188,23 +188,36 @@ def _pairs_transposed(cots, rows, k, decays, chunk: int, sub: int):
              for o, h in zip(below, d_rows)], d_key + _flat(d_here))
 
 
-def _inverse(a, chunk: int):
+def _inverse(a, chunk: int, inside: int):
     """``(I + a)^-1`` for ``a`` strictly lower-triangular in blocks of one
     chunk on the diagonal, as ``_unit_lower_inverse`` takes it and by the
     same products (the powers of such an ``a`` keep its blocks, so all
     chunks of the span share each product): a level's ``inverse @ power``
     and ``power @ power`` are the two halves of one product of the stacked
-    pair."""
+    pair.  The squaring stays inside diagonal blocks of ``inside`` rows;
+    blocks smaller than the chunk are merged pair by pair."""
     c = a.shape[0]
-    levels = max(0, (chunk - 1).bit_length() - 1)
+    levels = max(0, (inside - 1).bit_length() - 1)
     power = -a
+    if inside < chunk:      # traced only here: a whole-chunk trace stays
+        row, col = _iota((c, c), 0), _iota((c, c), 1)
+        together = lambda size: row // size == col // size
+        power = jnp.where(together(inside), power, 0.0)
     inverse = power + (_iota((c, c), 0) == _iota((c, c), 1)).astype(_F32)
     if levels:
         power = _dot(power, power)
     for level in range(levels - 1):
         both = _dot(jnp.concatenate([inverse, power], 0), power)
         inverse, power = inverse + both[:c], both[c:]
-    return inverse + _dot(inverse, power) if levels else inverse
+    inverse = inverse + _dot(inverse, power) if levels else inverse
+    size = inside
+    while size < chunk:
+        between = jnp.where(jnp.logical_and(together(2 * size),
+                                            jnp.logical_not(together(size))),
+                            a, 0.0)
+        inverse = inverse - _dot(_dot(inverse, between), inverse)
+        size *= 2
+    return inverse
 
 
 def _running_sum(x, chunk: int, reverse: bool = False):
@@ -242,7 +255,7 @@ def _span(k, g, beta_row, chunk: int, sub: int):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref, p_ref,
                 q_in_ref, k_out_ref, last_ref, *, chunk: int, sub: int,
-                wide: int):
+                wide: int, inside: int):
     from jax.experimental import pallas as pl
     kind = w_ref.dtype
     span = wide * chunk
@@ -253,7 +266,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref, p_ref,
         run, decays, beta, same, _, strict = _span(
             k, g_ref[0, 0, at, :], beta_ref[n, 0, 0], chunk, sub)
         p, a = _pairs([q, k], k, decays, same, chunk, sub)
-        inverse = _inverse(jnp.where(strict, a * beta, 0.0), chunk)
+        inverse = _inverse(jnp.where(strict, a * beta, 0.0), chunk, inside)
         into = jnp.exp(run)
         whole = _cut(run, chunk)
         results = ((w_ref, _dot(inverse, k * into * beta)),
@@ -272,7 +285,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref, p_ref,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, dw_ref, du_ref, dp_ref,
                 dq_in_ref, dk_out_ref, dlast_ref, dq_ref, dk_ref, dv_ref,
-                dg_ref, dbeta_ref, *, chunk: int, sub: int, wide: int):
+                dg_ref, dbeta_ref, *, chunk: int, sub: int, wide: int,
+                inside: int):
     from jax.experimental import pallas as pl
     span = wide * chunk
 
@@ -290,7 +304,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, dw_ref, du_ref, dp_ref,
         run, decays, beta, same, lower, strict = _span(
             k, g_ref[0, 0, at, :], beta_ref[n, 0, 0], chunk, sub)
         a, = _pairs([k], k, decays, same, chunk, sub)
-        inverse = _inverse(jnp.where(strict, a * beta, 0.0), chunk)
+        inverse = _inverse(jnp.where(strict, a * beta, 0.0), chunk, inside)
         into = jnp.exp(run)
         whole = _cut(run, chunk)
         out_of = _flat(jnp.exp(whole[:, -1:] - whole))
@@ -363,19 +377,19 @@ def _specs(q, v, kind, chunk: int, group: int):
     return grid, inputs, parts, shapes
 
 
-def _call(kernel, grid, chunk, sub, group, **kwargs):
+def _call(kernel, grid, chunk, sub, group, inside, **kwargs):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     return pl.pallas_call(
         functools.partial(kernel, chunk=chunk, sub=sub,
-                          wide=_wide(chunk, group)),
+                          wide=_wide(chunk, group), inside=inside),
         grid=grid,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         **kwargs)
 
 
-_STATIC = ("kind", "chunk", "sub", "group", "interpret")
+_STATIC = ("kind", "chunk", "sub", "group", "inside", "interpret")
 
 
 def _beta_rows(beta, chunk: int, group: int):
@@ -388,45 +402,47 @@ def _beta_rows(beta, chunk: int, group: int):
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _kda_chunks_fwd(q, k, v, g, beta, kind, chunk: int, sub: int, group: int,
-                    interpret: bool = False):
+                    inside: int, interpret: bool = False):
     grid, inputs, parts, shapes = _specs(q, v, kind, chunk, group)
-    return _call(_fwd_kernel, grid, chunk, sub, group, in_specs=inputs,
+    return _call(_fwd_kernel, grid, chunk, sub, group, inside, in_specs=inputs,
                  out_specs=parts, out_shape=shapes, interpret=interpret)(
                      q, k, v, g, _beta_rows(beta, chunk, group))
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _kda_chunks_bwd(q, k, v, g, beta, cotangents, kind, chunk: int, sub: int,
-                    group: int, interpret: bool = False):
+                    group: int, inside: int, interpret: bool = False):
     grid, inputs, parts, _ = _specs(q, v, kind, chunk, group)
     rows = _beta_rows(beta, chunk, group)
     *grads, d_rows = _call(
-        _bwd_kernel, grid, chunk, sub, group, in_specs=inputs + parts,
+        _bwd_kernel, grid, chunk, sub, group, inside,
+        in_specs=inputs + parts,
         out_specs=inputs, out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                                      for x in (q, k, v, g, rows)],
         interpret=interpret)(q, k, v, g, rows, *cotangents)
     return (*grads, jnp.moveaxis(d_rows, 0, 2).reshape(beta.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def kda_chunks(q, k, v, g, beta, kind, chunk: int, sub: int, group: int,
-               interpret: bool):
+               inside: int, interpret: bool):
     """``_within_chunk`` for every chunk: ``q, k [B, H, T, d_k]``, ``v [B,
     H, T, d_v]``, ``g`` and ``beta [B, H, T]`` float32; ``T`` a multiple of
-    ``group * chunk``.  Returns ``w, u, p, q_in,
+    ``group * chunk``; ``inside`` is ``_inverse``'s.  Returns ``w, u, p, q_in,
     k_out [T / chunk, B, H, chunk, .]`` in the type ``kind`` and ``last [T /
     chunk, B, H, 1, d_k]`` float32."""
     return _kda_chunks_fwd(q, k, v, g, beta, kind=kind, chunk=chunk, sub=sub,
-                           group=group, interpret=interpret)
+                           group=group, inside=inside, interpret=interpret)
 
 
 def _vjp_fwd(q, k, v, g, beta, *static):
     return kda_chunks(q, k, v, g, beta, *static), (q, k, v, g, beta)
 
 
-def _vjp_bwd(kind, chunk, sub, group, interpret, saved, cotangents):
+def _vjp_bwd(kind, chunk, sub, group, inside, interpret, saved, cotangents):
     return _kda_chunks_bwd(*saved, tuple(cotangents), kind=kind, chunk=chunk,
-                           sub=sub, group=group, interpret=interpret)
+                           sub=sub, group=group, inside=inside,
+                           interpret=interpret)
 
 
 kda_chunks.defvjp(_vjp_fwd, _vjp_bwd)

@@ -236,6 +236,45 @@ def test_delta_rule_gradient_runs_two_kernels_and_no_chunk_scan(
         assert not moved, (name, moved)
 
 
+def test_delta_rule_gradient_at_16_heads_and_beta_to_2_runs_two_kernels(
+        one_chip, no_compile_cache, monkeypatch):
+    """The same at the shape of solar_open2_250b.train (one sequence, a
+    host's share of 16 heads) with `wide_beta`: Mosaic accepts the inverse
+    squared inside blocks of 8 tokens and merged pair by pair (masks by
+    `iota // size` on a `[128, 128]` span, two more products a merge), in
+    the forward and in the backward."""
+    import homebrewnlp_tpu.ops as ops
+    from homebrewnlp_tpu.ops.delta_rule import chunked_kda
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    n_b, seq, n_h, key = 1, 8192, 16, 128
+
+    def shape(kind, *dims):
+        return jax.ShapeDtypeStruct(dims, kind, sharding=one_chip)
+
+    stream = shape(jnp.bfloat16, n_b, seq, n_h, key)
+
+    def loss(q, k, v, g, beta):
+        out = chunked_kda(q * 2, k * 2, v * 2, g * 2, beta * 2,
+                          wide_beta=True)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    hlo = jax.jit(jax.grad(loss, range(5))).lower(
+        stream, stream, stream, shape(jnp.float32, n_b, seq, n_h, key),
+        shape(jnp.float32, n_b, seq, n_h)).compile().as_text()
+    insts = entry_instructions(hlo)
+    kernels = mosaic_calls(insts)
+    assert sorted(re.search(r"jit\((_kda_chunks_\w+)\)", insts[name][2]).group(1)
+                  for name in kernels) == ["_kda_chunks_bwd",
+                                           "_kda_chunks_fwd"], sorted(kernels)
+    for name in kernels:
+        # at a quarter of the Kimi operands the compiler prefetches some
+        # into fast memory: a `copy-start` of the same shape and tiling
+        moved = [line for line in copies_beside(insts, name,
+                                                n_b * seq * n_h * key)
+                 if not _prefetch(line)]
+        assert not moved, (name, moved)
+
+
 def test_mla_gradient_runs_two_kernels_and_no_score_tile(
         one_chip, no_compile_cache, monkeypatch):
     """`jax.grad` of the `mla` layer at the widths of kimi_linear_48b.train
@@ -423,3 +462,86 @@ def test_expert_layer_gradient_runs_the_grouped_kernels_alone(
                 if not _prefetch(line)]
             assert not moved, (name, moved)
     assert sorted(found) == ["_gmm_rows"] * 9 + ["_gmm_weights"] * 3, found
+
+
+def compiled_step(cfg: Config, device):
+    """`Trainer`'s whole update (gradient, clipping, the optimizer chain)
+    compiled for the described chip, the state and the batch given as shapes
+    laid out as `Trainer.init` and the feed lay them out."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from homebrewnlp_tpu.optim import Optimizer
+    from homebrewnlp_tpu.parallel import make_mesh, param_shardings, spec_for
+    from homebrewnlp_tpu.train import Trainer
+    from homebrewnlp_tpu.train.state import TrainState
+    mesh = make_mesh(cfg, [device])
+    trainer = Trainer(cfg, mesh)
+    on = lambda names: NamedSharding(mesh, spec_for(names, mesh))
+    shape = (cfg.train_batch_size, cfg.sequence_length, cfg.token_patch_size)
+    tok = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=on(TOKEN_NAMES))
+    batch = {k: NT(tok, TOKEN_NAMES) for k in ("token_x", "token_y")}
+    axes: dict = {}
+
+    def collect():
+        ctx = Ctx(cfg, params=None, seed=0, train=False)
+        build(ctx, {k: NT(jnp.zeros(shape, jnp.int32), TOKEN_NAMES)
+                    for k in batch})
+        axes.update(ctx.axis_names)
+        return ctx.collected
+
+    abstract = jax.eval_shape(collect)
+    trainer.axes, trainer.optimizer = axes, Optimizer(cfg, axes)
+    shard = param_shardings(axes, mesh)
+    params = {k: jax.ShapeDtypeStruct(v.shape, jnp.dtype(cfg.slice_dtype),
+                                      sharding=shard[k])
+              for k, v in abstract.items()}
+    slot_axes = trainer.optimizer.slot_axis_names()
+    slots = {name: {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                            sharding=on(slot_axes[name][k]))
+                    for k, v in leaf.items()}
+             for name, leaf in jax.eval_shape(trainer.optimizer.init,
+                                              params).items()}
+    scalar = NamedSharding(mesh, PartitionSpec())
+    state = TrainState(params, slots, jax.ShapeDtypeStruct(
+        (), jnp.int32, sharding=scalar))
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=scalar)
+    with mesh:
+        return trainer._make_step().lower(
+            state, batch, key, *trainer.step_extra_args()).compile()
+
+
+def test_solar_open2_step_fits_the_chip_and_keeps_its_kernels(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole update of solar_open2_250b.train (905.76 M parameters, one
+    sequence of 8,192 tokens) compiled for the described v5e: its state,
+    gradients and scratch stay under 15.0 GB (at two sequences they read
+    17.4 GB, which is why the cell takes one: PERF.md section 4); the delta
+    rule and the attention run as their four Mosaic kernels, the attention's
+    take `k`, `v` with the 2 K/V heads held here, and nothing shaped like a
+    score tile is left under `gqa_`."""
+    import homebrewnlp_tpu.ops as ops
+    from homebrewnlp_tpu.ops.pallas_mla import BLOCK
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "solar_open2_250b.json")) as f:
+        raw = {k: v for k, v in json.load(f).items() if k != "benchmark"}
+    cfg = Config(raw)
+    compiled = compiled_step(cfg, next(iter(one_chip.device_set)))
+    memory = compiled.memory_analysis()
+    need = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert 12.5e9 < need < 15.0e9, need
+    hlo = compiled.as_text()
+    assert sorted(set(re.findall(r'jit\((_\w+)\)[^"]*pallas_call', hlo))) == [
+        "_kda_chunks_bwd", "_kda_chunks_fwd", "_mla_attention_bwd",
+        "_mla_attention_fwd"]
+    kv = "bf16[%d,%d,%d,%d]" % (cfg.train_batch_size, cfg.num_key_value_heads,
+                                cfg.sequence_length, cfg.head_dim)
+    attention = [line for line in hlo.splitlines()
+                 if "tpu_custom_call" in line and "_mla_attention_" in line]
+    assert attention and all(line.count(kv) >= 2 for line in attention)
+    tiles = [line.strip()[:160] for line in hlo.splitlines()
+             if re.search(r"= \(?f32\[\d+,\d+,(%d,%d|1024,1024)\]"
+                          % (BLOCK, BLOCK), line) and "gqa_" in line]
+    assert not tiles, tiles
+
