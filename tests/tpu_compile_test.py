@@ -414,7 +414,10 @@ def test_expert_layer_gradient_runs_the_grouped_kernels_alone(
     three transposed ones and three `_gmm_weights`), no `ragged-dot` is
     left, a stack reaches the transposed product as it is stored (nothing a
     stack's size is copied or transposed next to a kernel), and nothing the
-    size of a chunk's narrower operand either."""
+    size of a chunk's narrower operand either.  The chunk's rows come back
+    to their tokens by gathers (PR 34): no scatter anywhere writes rows of
+    2,304 into an array of a row a token, and neither loop's body holds a
+    float32 array of the chunk's rows (what such a scatter's updates were)."""
     import homebrewnlp_tpu.ops as ops
     from homebrewnlp_tpu.models.ctx import Args
     from homebrewnlp_tpu.models.registry import LAYER_FUNCTIONS
@@ -449,10 +452,15 @@ def test_expert_layer_gradient_runs_the_grouped_kernels_alone(
     assert not re.search(r" ragged-dot\(", hlo)
     held, inter = cfg.experts_held, cfg.moe_intermediate_size
     rows = aligned_rows(16384 * 8, held, ROW_TILE)
+    assert not re.findall(r"= f32\[1638[45],2304\]\S* scatter\(", hlo)
     found = []
     for body in re.findall(r"^%?[\w.\-]+ \([^\n]*\{\n(.*?)^\}", hlo,
                            re.S | re.M):
         insts = instructions(body)
+        if mosaic_calls(insts):
+            wide = [line.strip()[:160] for _, _, line in insts.values()
+                    if " = f32[%d,2304]" % rows in line]
+            assert not wide, wide
         for name in mosaic_calls(insts):
             found.append(re.search(r"jit\((_gmm_\w+)\)",
                                    insts[name][2]).group(1))
