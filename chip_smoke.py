@@ -92,38 +92,28 @@ def log(message: str) -> None:
 
 # -- measurement helpers ------------------------------------------------------
 
-class CompileLog:
-    """What JAX compiled, from its own monitoring events: seconds in the
-    backend (an XLA compile, or the retrieval of a persistent-cache entry),
-    seconds tracing and lowering, and the persistent cache's hits and misses
-    (a miss is an executable compiled and then written to the cache)."""
+def compile_totals() -> dict:
+    """What JAX compiled so far, from the counters of the program's compile
+    log (``obs/compile_log.py``, installed by ``enable_compilation_cache``):
+    seconds in the backend (an XLA compile, or the load of a persistent-
+    cache entry), seconds tracing and lowering, and the persistent cache's
+    hits and misses (a miss is an executable compiled and then stored)."""
+    from homebrewnlp_tpu.obs.registry import REGISTRY
 
-    def __init__(self):
-        import jax.monitoring
-        self.totals = dict(backend_s=0.0, trace_s=0.0, cache_hits=0,
-                           cache_misses=0)
-        jax.monitoring.register_event_listener(self._event)
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
+    def value(name, **labels):
+        return REGISTRY.get(f"hbnlp_jax_{name}_total").value(**labels)
 
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.totals["cache_hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.totals["cache_misses"] += 1
+    return dict(
+        backend_s=sum(value("build_seconds", cache=c)
+                      for c in ("hit", "miss", "unstored")),
+        trace_s=value("trace_seconds") + value("lower_seconds"),
+        cache_hits=int(value("builds", cache="hit")),
+        cache_misses=int(value("builds", cache="miss")))
 
-    def _duration(self, event: str, seconds: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.totals["backend_s"] += seconds
-        elif event in ("/jax/core/compile/jaxpr_trace_duration",
-                       "/jax/core/compile/jaxpr_to_mlir_module_duration"):
-            self.totals["trace_s"] += seconds
 
-    def mark(self) -> dict:
-        return dict(self.totals)
-
-    def since(self, mark: dict) -> dict:
-        return {k: round(v - mark[k], 2) if isinstance(v, float)
-                else v - mark[k] for k, v in self.totals.items()}
+def compiled_since(mark: dict) -> dict:
+    return {k: round(v - mark[k], 2) if isinstance(v, float)
+            else v - mark[k] for k, v in compile_totals().items()}
 
 
 def device_memory() -> dict:
@@ -395,12 +385,11 @@ def main() -> None:
     cached = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
     log(f"device {json.dumps(device)}; jax {jax.__version__}; compile cache "
         f"{cache_dir} ({cached} entries before this run)")
-    compiles = CompileLog()
 
     def run(name, phase):
-        mark, t0 = compiles.mark(), time.perf_counter()
+        mark, t0 = compile_totals(), time.perf_counter()
         result = phase()
-        result["compile"] = compiles.since(mark)
+        result["compile"] = compiled_since(mark)
         result["phase_s"] = round(time.perf_counter() - t0, 1)
         gc.collect()  # the phase's device buffers go with its last reference
         result["memory_after"] = device_memory()
@@ -417,7 +406,7 @@ def main() -> None:
                                 serve_max_batch=SERVE_LANES,
                                 model_path=os.path.join(SMOKE_DIR, "serve"))
     run("serve", lambda: phase_serve(serve_cfg))
-    total = compiles.mark()
+    total = compile_totals()
     log(f"persistent cache: {total['cache_hits']} hit(s), "
         f"{total['cache_misses']} miss(es) written; backend compile "
         f"{total['backend_s']:.1f}s, trace+lower {total['trace_s']:.1f}s")

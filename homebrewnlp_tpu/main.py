@@ -209,12 +209,11 @@ def _train_loop(cfg, args, obs, grace) -> None:
     from .data import RunLog, dataset, to_global
     from .data.feed import DeviceFeeder
     from .data.synthetic import synthetic_text_batch
-    from .obs import device_telemetry, spans
+    from .obs import compile_log, device_telemetry, spans
     from .reliability import dist, faults
     from .train import AsyncMetricWriter, MetricWriter, color_print
     from .train.metrics import config_hash
 
-    have_data = _have_dataset_files(cfg)
     from .parallel import make_mesh
     # elastic runs suppress the "axis shrunk" fold warnings: when the fleet
     # resumes degraded (the device count no longer factors the declared
@@ -225,14 +224,17 @@ def _train_loop(cfg, args, obs, grace) -> None:
     # a pod config on one bench chip is deliberate, not degraded.
     from .parallel.mesh import MODEL_AXIS
     elastic = dist.settings(cfg) is not None
-    mesh = make_mesh(cfg, quiet=elastic)
-    n_avail = len(jax.devices())
-    if elastic and jax.process_index() == 0 and (
-            int(dict(mesh.shape).get(MODEL_AXIS, 1)) != cfg.mesh_model
-            or mesh.size < n_avail):
-        # process 0 only: the search re-traces the config (seconds on a
-        # flagship) and every host would log the identical suggestion
-        dist.log_mesh_suggestion(cfg, mesh, n_devices=n_avail)
+    # set-up runs under ``setup/*`` spans (docs/observability.md "Set-up
+    # and compiles"): the compile log's ``jax/*`` spans nest inside them
+    with spans.span("setup/mesh"):
+        mesh = make_mesh(cfg, quiet=elastic)
+        n_avail = len(jax.devices())
+        if elastic and jax.process_index() == 0 and (
+                int(dict(mesh.shape).get(MODEL_AXIS, 1)) != cfg.mesh_model
+                or mesh.size < n_avail):
+            # process 0 only: the search re-traces the config (seconds on
+            # a flagship) and every host would log the identical suggestion
+            dist.log_mesh_suggestion(cfg, mesh, n_devices=n_avail)
     # processes sharing a data-axis coordinate (pipe axis spanning hosts)
     # read the SAME dataset slice (data/feed.py::data_slice_for_process);
     # data-major topologies reduce to (process_index, process_count)
@@ -242,15 +244,19 @@ def _train_loop(cfg, args, obs, grace) -> None:
     # dataloader_placement.py:40-44)
     local_batch = cfg.train_batch_size * cfg.macro_batching // slice_count
 
-    if have_data:
-        # probe pipeline (no prefetch thread): one template batch for init,
-        # then discarded — the real pipeline is built after checkpoint
-        # restore so its cursor and prefetcher start from the right place
-        probe = dataset(cfg, local_batch, slice_index, slice_count,
-                        prefetch=False)
-        first_np = next(iter(probe))
-    else:
-        first_np = synthetic_text_batch(cfg, 0)
+    with spans.span("setup/probe_batch"):
+        have_data = _have_dataset_files(cfg)
+        if have_data:
+            # probe pipeline (no prefetch thread): one template batch for
+            # init, then discarded — the real pipeline is built after
+            # checkpoint restore so its cursor and prefetcher start from
+            # the right place
+            probe = dataset(cfg, local_batch, slice_index, slice_count,
+                            prefetch=False)
+            first_np = next(iter(probe))
+        else:
+            first_np = synthetic_text_batch(cfg, 0)
+        template_gb = to_global(first_np, cfg, mesh)
     # which source feeds the run is never silent: it is printed, and rides
     # the run-start marker below so a reader of metrics.jsonl (the chip
     # smoke) can refuse a run that fell back to noise
@@ -258,8 +264,9 @@ def _train_loop(cfg, args, obs, grace) -> None:
     color_print(f"data source: {data_source}"
                 + (f" ({[d['path'] for d in cfg.dataset_configs]})"
                    if have_data else " (no dataset files found)"))
-    template_gb = to_global(first_np, cfg, mesh)
-    trainer, state, ckpt, data_state = _build_state(cfg, template_gb, mesh)
+    with spans.span("setup/init_or_restore"):
+        trainer, state, ckpt, data_state = _build_state(cfg, template_gb,
+                                                        mesh)
     if int(state.step) == 0 and cfg.current_step > 0:
         # config-forced starting step with no checkpoint (the reference reads
         # it from estimator internals and skips data accordingly,
@@ -280,9 +287,10 @@ def _train_loop(cfg, args, obs, grace) -> None:
     if have_data:
         # the real (prefetched) pipeline, with the checkpointed cursor
         # restored before the first read
-        pipe = dataset(cfg, local_batch, slice_index, slice_count)
-        if data_state and "pipeline" in data_state:
-            pipe.load_state_dict(data_state["pipeline"])
+        with spans.span("setup/pipeline"):
+            pipe = dataset(cfg, local_batch, slice_index, slice_count)
+            if data_state and "pipeline" in data_state:
+                pipe.load_state_dict(data_state["pipeline"])
 
     _dump_run_artifacts(cfg, trainer, state.params)
     # device telemetry (docs/observability.md "Device telemetry"): static
@@ -291,42 +299,48 @@ def _train_loop(cfg, args, obs, grace) -> None:
     # serves every loop step) — plus the drain-side anomaly monitor
     telemetry_on = cfg.telemetry_interval > 0
     util = anomaly = None
-    if telemetry_on:
-        from .obs.device_telemetry import AnomalyMonitor
-        from .train import flops as flops_mod
-        anomaly = AnomalyMonitor(cfg.anomaly_policy, registry=obs.registry
-                                 if obs.enabled else None)
-        # template_gb is reused from init: cost analysis only LOWERS the
-        # step, so no second H2D transfer of a full global batch
-        util = flops_mod.utilization_for(
-            trainer, state, template_gb,
-            tokens_per_step=cfg.train_batch_size * max(1, cfg.macro_batching)
-            * cfg.sequence_length)
-        color_print(f"device telemetry on: {util.flops_per_step:.3e} "
-                    f"flops/step ({util.device_kind}), anomaly_policy="
-                    f"{cfg.anomaly_policy}")
-    if args.profile and trainer._compiled is None:
-        # graftprof attribution (docs/observability.md "Profile
-        # attribution") needs the step executable's HLO metadata to map
-        # trace events back to model scopes: AOT-compile now (the loop
-        # reuses the kept executable, so this is the same compile the
-        # first step would have paid — not an extra one) and the op-map
-        # sidecar below comes for free.  Best-effort: a failing AOT path
-        # only degrades per-scope attribution, never the run.
-        try:
-            trainer.step_cost_analysis(state, template_gb)
-        except Exception as e:
-            color_print(f"profile op-map pre-compile failed ({e}); "
-                        "per-scope attribution will be unavailable")
+    # where the update is built ahead of the loop (both AOT paths below
+    # keep the executable the loop then calls); with neither, the first
+    # ``step`` span holds the build
+    with spans.span("setup/step_build"):
+        if telemetry_on:
+            from .obs.device_telemetry import AnomalyMonitor
+            from .train import flops as flops_mod
+            anomaly = AnomalyMonitor(cfg.anomaly_policy, registry=obs.registry
+                                     if obs.enabled else None)
+            # template_gb is reused from init: cost analysis only LOWERS
+            # the step, so no second H2D transfer of a full global batch
+            util = flops_mod.utilization_for(
+                trainer, state, template_gb,
+                tokens_per_step=cfg.train_batch_size
+                * max(1, cfg.macro_batching) * cfg.sequence_length)
+            color_print(f"device telemetry on: {util.flops_per_step:.3e} "
+                        f"flops/step ({util.device_kind}), anomaly_policy="
+                        f"{cfg.anomaly_policy}")
+        if args.profile and trainer._compiled is None:
+            # graftprof attribution (docs/observability.md "Profile
+            # attribution") needs the step executable's HLO metadata to map
+            # trace events back to model scopes: AOT-compile now (the loop
+            # reuses the kept executable, so this is the same compile the
+            # first step would have paid — not an extra one) and the op-map
+            # sidecar below comes for free.  Best-effort: a failing AOT
+            # path only degrades per-scope attribution, never the run.
+            try:
+                trainer.step_cost_analysis(state, template_gb)
+            except Exception as e:
+                color_print(f"profile op-map pre-compile failed ({e}); "
+                            "per-scope attribution will be unavailable")
     del template_gb  # release the init batch's device buffers for the run
     # deferred metrics drain: debug_train_step keeps the reference's
     # synchronous per-step prints, so it forces the window to 0
     window = 0 if cfg.debug_train_step else cfg.async_inflight_steps
-    writer = AsyncMetricWriter(MetricWriter(cfg.model_path), window=window,
-                               health=obs.health if obs.enabled else None,
-                               registry=obs.registry if obs.enabled else None,
-                               anomaly=anomaly,
-                               reporter=obs.fleet_reporter)
+    # the TensorBoard writer's imports are seconds of a start
+    with spans.span("setup/metric_writer"):
+        writer = AsyncMetricWriter(
+            MetricWriter(cfg.model_path), window=window,
+            health=obs.health if obs.enabled else None,
+            registry=obs.registry if obs.enabled else None,
+            anomaly=anomaly, reporter=obs.fleet_reporter)
     if util is not None:
         writer.set_utilization(util, run_start=run_t0)
         if obs.enabled:
@@ -359,9 +373,14 @@ def _train_loop(cfg, args, obs, grace) -> None:
     else:
         source = (synthetic_text_batch(cfg, i) for i in itertools.count(u0))
         state_fn = None
-    feeder = DeviceFeeder(source, cfg, trainer.mesh,
-                          depth=cfg.device_prefetch_depth, state_fn=state_fn,
-                          registry=obs.registry if obs.enabled else None)
+    with spans.span("setup/pipeline"):
+        feeder = DeviceFeeder(source, cfg, trainer.mesh,
+                              depth=cfg.device_prefetch_depth,
+                              state_fn=state_fn,
+                              registry=obs.registry if obs.enabled else None)
+    # a program built once the first update is out is a fault worth a line
+    # ("which step recompiled"): the compile log says which and how long
+    rebuilt_seen, rebuilt_after = None, 0.0
     tracing = False
     u_done = u0  # updates actually dispatched (exhaustion can end early)
     # the try owns cleanup from the moment producer threads exist: an
@@ -433,11 +452,20 @@ def _train_loop(cfg, args, obs, grace) -> None:
                 # stay clean because skip_step masks the update in-graph
                 if "nan" in faults.take("grads", value=host_step):
                     grad_scale = np.nan
-            with spans.span("step", update=u):
+            with spans.span("step", update=u,
+                            **({"first": True} if u == u0 else {})):
                 state, metrics = trainer.step(state, gb,
                                               jax.random.fold_in(rng, u),
                                               grad_scale=grad_scale)
             u_done = u + 1
+            if rebuilt_seen != compile_log.LOG.recompiles:
+                if rebuilt_seen is not None:
+                    for record in compile_log.LOG.rebuilt(rebuilt_after):
+                        color_print(f"update {u} (step {host_step}): JAX "
+                                    f"built a program again: "
+                                    f"{compile_log.describe(record)}")
+                rebuilt_seen = compile_log.LOG.recompiles
+                rebuilt_after = time.perf_counter()
             if telemetry_on:
                 # host-side thinning: norm-class telemetry keys off the
                 # telemetry_interval grid never transfer; sentinels always

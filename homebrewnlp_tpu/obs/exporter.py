@@ -28,6 +28,7 @@ import traceback
 import typing
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+from . import compile_log, spans
 from .registry import REGISTRY, MetricsRegistry
 from ..sync import make_lock
 
@@ -434,6 +435,25 @@ def dump_diagnostics(model_path: str, health: typing.Optional[Health] = None,
     return path
 
 
+def startup_position() -> str:
+    """Where a run that has finished no step stands: the live spans each
+    thread is inside (``setup/*`` or the first ``step``; with ``obs_spans``)
+    and the last thing JAX traced, lowered or built."""
+    tracer = spans.get_tracer()
+    if tracer is None:
+        where = "no span tracer (obs_spans off)"
+    else:
+        where = "; ".join(
+            f"{thread} inside {' > '.join(stack)}"
+            for thread, stack in sorted(tracer.open_spans().items())
+        ) or "no span open"
+    last = compile_log.LOG.last()
+    if last is None:
+        return f"{where}; JAX has built nothing yet"
+    return (f"{where}; JAX's last: {compile_log.describe(last)} ended "
+            f"{time.perf_counter() - last.t1:.1f}s ago")
+
+
 class Watchdog(threading.Thread):
     """Dump diagnostics when ``Health.stalled()`` trips — no step within
     ``stall_factor`` x the EMA step time (floored at ``min_stall_s``), or a
@@ -503,8 +523,7 @@ class Watchdog(threading.Thread):
                    f"max_pause_s ({paused_s:.1f}s > {h.max_pause_s}s)")
         elif threshold is None:
             why = (f"no step cadence established within startup_stall_s "
-                   f"({h.startup_stall_s}s) — wedged in compile/restore/"
-                   f"first step")
+                   f"({h.startup_stall_s}s) — {startup_position()}")
         else:
             why = (f"no step completed in "
                    f"{h.seconds_since_last_step():.2f}s (threshold "

@@ -60,6 +60,7 @@ class _Span:
             self._ann = self.tracer._mirror(self.name)
             self._ann.__enter__()
         self._t0 = time.perf_counter()
+        self.tracer._opened(self.name)
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -67,7 +68,7 @@ class _Span:
         if self._ann is not None:
             self._ann.__exit__(*exc)
             self._ann = None
-        self.tracer._record(self.name, self._t0, t1, self.args)
+        self.tracer._record(self.name, self._t0, t1, self.args, closes=True)
         return False
 
 
@@ -92,6 +93,9 @@ class SpanTracer:
         self._recorded = 0
         self._totals: typing.Dict[str, float] = {}
         self._thread_names: typing.Dict[int, str] = {}
+        # names of the live spans each thread is inside, outermost first:
+        # what a hang report names (``open_spans``)
+        self._open: typing.Dict[int, typing.List[str]] = {}
         # virtual tracks (serving lane timelines): negative synthetic tids,
         # allocated per track name, so they can never collide with a real
         # thread ident and sort ahead of the thread tracks in the viewer
@@ -142,9 +146,33 @@ class SpanTracer:
             t0, t1 = t1, t0
         self._record(name, t0, t1, args, track=track)
 
-    def _record(self, name: str, t0: float, t1: float, args: dict,
-                track: typing.Optional[str] = None) -> None:
+    def _opened(self, name: str) -> None:
+        th = threading.current_thread()
         with self._lock:
+            self._thread_names[th.ident] = th.name
+            self._open.setdefault(th.ident, []).append(name)
+
+    def open_spans(self) -> typing.Dict[str, typing.List[str]]:
+        """{thread name: names of the live spans it is inside, outermost
+        first} for every thread inside one: where each actor stands now,
+        which the closed spans of the ring cannot say."""
+        with self._lock:
+            return {self._thread_names.get(tid, str(tid)): list(stack)
+                    for tid, stack in self._open.items() if stack}
+
+    def _record(self, name: str, t0: float, t1: float, args: dict,
+                track: typing.Optional[str] = None,
+                closes: bool = False) -> None:
+        with self._lock:
+            if closes:
+                # a span closed by another thread than opened it finds no
+                # stack of its own; a server makes a thread a request, so
+                # an empty one goes
+                stack = self._open.get(threading.get_ident())
+                if stack:
+                    stack.pop()
+                if not stack:
+                    self._open.pop(threading.get_ident(), None)
             if track is not None:
                 tid = self._track_ids.get(track)
                 if tid is None:

@@ -24,8 +24,14 @@ def enable_compilation_cache() -> str:
       found again by the next process.
 
     ``JAX_ENABLE_COMPILATION_CACHE=false`` in the environment switches the
-    cache off (JAX's own flag).  Returns the directory in use."""
+    cache off (JAX's own flag).  Returns the directory in use.
+
+    Whoever turns the cache on also counts its hits: the compile log's
+    listeners (``obs/compile_log.py``) are registered here, before the
+    first build of every entry point."""
     import jax
+    from ..obs import compile_log
+    compile_log.install()
     if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(REPO_ROOT, ".jax_cache"))
