@@ -56,11 +56,13 @@ def expert_chunk(tokens: int, topk: int, held: int, experts: int) -> int:
     the share of the experts held here: ``EXPERT_CHUNK_LOADS`` times what a
     balanced router sends this share (``topk * held / experts`` pairs a
     token), and no more than all pairs.  A chunk is multiplied by
-    ``ops/pallas_gmm.py``'s Mosaic kernels where its shape lets them (both
-    cells' does), as one row tile an expert more rows than pairs, else by
-    ``jax.lax.ragged_dot``.  8 of 256 experts under top-8 get ``tokens``
-    pairs; 16 of 64 get all ``tokens * topk`` pairs, so their loop takes one
-    trip whatever the routing and the step's time cannot follow it (random weights on the toy language send up to 110,000 of a step's
+    ``ops/pallas_gmm.py``'s Mosaic kernels where its shape lets them (all
+    three hybrid cells' does), as one row tile an expert more rows than
+    pairs, else by ``jax.lax.ragged_dot``.  8 of 256 experts under top-8 get
+    ``tokens`` pairs; 8 of 320 get 6,556 for 8,192 tokens (8,704 rows on
+    tiles); 16 of 64 get all ``tokens * topk`` pairs, so their loop takes
+    one trip whatever the routing and the step's time cannot follow it
+    (random weights on the toy language send up to 110,000 of a step's
     131,072 pairs to 16 held experts, and 33,000 on another seed: under a
     chunk of twice the balanced load the trips, and 8% of ``tokens_per_s``,
     followed the seed; PERF.md, PR 31)."""

@@ -5,7 +5,8 @@ The pairs that fell on an expert held here are sorted by expert, so each
 expert's pairs are one run of rows, and the runs are multiplied with their
 experts' matrices by ``ops/pallas_gmm.py::grouped_dot`` (one grouped product
 over all the rows: its cost follows the rows, not rows times experts).  At
-lane-tile widths and enough rows that is a pair of Mosaic kernels, which ask
+lane-tile widths and half a row tile of pairs an expert or more that is a
+pair of Mosaic kernels, which ask
 that every run start on a multiple of their row tile: the rows are laid out
 so (``tile``, from ``pallas_gmm.row_tile``; a run's last tile is filled with
 zero rows of weight zero, at most one tile an expert).  Every other shape

@@ -76,21 +76,101 @@ def test_transposed_product_reads_the_stack_as_it_is_stored():
     assert "transpose" not in outside, outside
 
 
+#: the stack's gradient in 2 and 3 blocks of ``N``: ``K`` 256, so that a
+#: ``VMEM_BYTES`` lowered to what `_gmm_rows` needs leaves the whole ``[K, N]``
+#: sum outside (at ``K`` 128 the two kernels' needs are equal to the byte)
+K_WIDE = 256
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("blocks", [2, 3])
+@pytest.mark.parametrize("runs", sorted(RUNS))
+def test_blocked_weights_kernel_matches_ragged_dot(runs, blocks, dtype,
+                                                   monkeypatch):
+    """`_gmm_weights` summing a block ``[K, N / blocks]`` at a time, reached
+    as a cell reaches it: the module's VMEM is too small for the whole sum
+    and `weight_blocks` answers the least count that fits.  The result and
+    both gradients against `ragged_dot`, and the call's grid and blocks."""
+    n, itemsize = 128 * blocks, jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(gmm, "VMEM_BYTES",
+                        gmm.rows_vmem_bytes(K_WIDE, n, itemsize))
+    assert gmm.weight_blocks(K_WIDE, n, itemsize) == blocks
+    assert gmm.weights_vmem_bytes(K_WIDE, n, itemsize, 1) > gmm.VMEM_BYTES
+    sizes = jnp.array(RUNS[runs], jnp.int32) * TILE
+    rows = _normal(0, (40 * TILE, K_WIDE), dtype)
+    stack = _normal(1, (4, K_WIDE, n), dtype) * 0.1
+    assert gmm.takes_kernels(rows, stack)
+
+    def loss(dot):
+        return lambda r, s: jnp.sum(jnp.sin(dot(r, s, sizes).astype(
+            jnp.float32)))
+
+    grad = jax.grad(loss(gmm.grouped_dot), (0, 1))
+    calls = _pallas_calls(jax.make_jaxpr(grad)(rows, stack).jaxpr)
+    assert [c[0] for c in calls] == ["_gmm_rows", "_gmm_weights",
+                                     "_gmm_rows"], calls
+    assert calls[1][1:] == ((blocks, 40), [(TILE, K_WIDE), (TILE, 128),
+                                           (1, K_WIDE, 128)])
+    got = grad(rows, stack)
+    want = jax.grad(loss(jax.lax.ragged_dot), (0, 1))(rows, stack)
+    _close(gmm.grouped_dot(rows, stack, sizes),
+           jax.lax.ragged_dot(rows, stack, sizes), dtype)
+    _close(got[0], want[0], dtype)
+    _close(got[1], want[1], dtype, of_largest=True)
+    empty = np.asarray(sizes) == 0
+    assert not np.any(np.asarray(got[1], np.float32)[empty])
+
+
+def _pallas_calls(jaxpr, inside=None):
+    """(jitted function, grid, block shapes) of every `pallas_call` under
+    ``jaxpr``, in program order."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            mapping = eqn.params["grid_mapping"]
+            out.append((inside, tuple(mapping.grid),
+                        [tuple(getattr(d, "block_size", d)
+                               for d in b.block_shape)
+                         for b in mapping.block_mappings]))
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", None)
+            if inner is not None:
+                out += _pallas_calls(getattr(inner, "jaxpr", inner),
+                                     eqn.params.get("name", inside))
+    return out
+
+
 # -- what chooses -------------------------------------------------------------
 
 def test_the_shape_alone_chooses_the_kernels():
     bf16 = 2
-    # the two cells: 16 of 64 experts 896 wide on all 131,072 pairs; 8 of
-    # 256 experts 1,024 wide on 16,384 rows
+    # the three cells: 16 of 64 experts 896 wide on all 131,072 pairs; 8 of
+    # 256 experts 1,024 wide on 16,384 rows; 8 of 320 experts 1,280 wide on
+    # a stream of 4,096, 6,556 pairs (820 a group, 8,704 rows on tiles)
     assert gmm.row_tile(131072, 16, 2304, 896, bf16) == TILE
     assert gmm.row_tile(16384, 8, 2304, 1024, bf16) == TILE
+    assert gmm.row_tile(6556, 8, 4096, 1280, bf16) == TILE
     assert gmm.aligned_rows(131072, 16, TILE) == 131072 + 16 * TILE
+    assert gmm.aligned_rows(6556, 8, TILE) == 8704
     # toy widths, a decoding step's few rows, matrices beyond VMEM
     assert gmm.row_tile(768, 8, 72, 16, 4) == 1
     assert gmm.row_tile(131072, 16, 2304, 900, bf16) == 1
     assert gmm.row_tile(64, 16, 2304, 896, bf16) == 1
-    assert gmm.row_tile(8 * 16 * TILE - TILE, 16, 2304, 896, bf16) == 1
+    # the rule counts a group's pairs: on tiles, `pairs` rows are one tile a
+    # group and the tiles that hold `pairs - groups` rows
+    least = 16 * gmm.PAIRS_A_GROUP - TILE + 16
+    assert gmm.row_tile(least, 16, 2304, 896, bf16) == 1
+    assert gmm.row_tile(least + 1, 16, 2304, 896, bf16) == TILE
     assert gmm.row_tile(131072, 16, 8192, 8192, bf16) == 1
+    why = gmm.refusals(gmm.aligned_rows(131072, 16, TILE), 16, 8192, 8192,
+                       bf16)
+    assert len(why) == 1 and "_gmm_rows' blocks" in why[0]
+    # one lane tile a block, `_gmm_weights` needs no more than `_gmm_rows`:
+    # no width that kernel takes is without a block count
+    for k, n in ((128, 128), (4096, 1280), (1280, 4096), (8192, 128),
+                 (128, 8192), (8192, 8192)):
+        assert gmm.weights_vmem_bytes(k, n, bf16, n // 128) <= \
+            gmm.rows_vmem_bytes(k, n, bf16)
 
     def path(m, groups, k, n):
         sizes = jnp.zeros((groups,), jnp.int32).at[0].set(m)
@@ -100,13 +180,62 @@ def test_the_shape_alone_chooses_the_kernels():
 
     for m, groups, k, n in ((131072 + 16 * TILE, 16, 2304, 896),
                             (131072 + 16 * TILE, 16, 896, 2304),
-                            (16384 + 8 * TILE, 8, 2304, 1024)):
+                            (16384 + 8 * TILE, 8, 2304, 1024),
+                            (8704, 8, 4096, 1280), (8704, 8, 1280, 4096)):
         assert "pallas_call" in path(m, groups, k, n)
         assert "ragged_dot" not in path(m, groups, k, n)
     for m, groups, k, n in ((768, 8, 72, 16), (16, 16, 2304, 896),
-                            (16384, 8, 2304, 1024)):
+                            (gmm.aligned_rows(64, 16, TILE), 16, 2304, 896),
+                            (16384 + 8 * TILE, 8, 8192, 8192)):
         assert "pallas_call" not in path(m, groups, k, n)
         assert "ragged_dot" in path(m, groups, k, n)
+
+
+#: cell -> (pairs of a chunk, held experts, stream, an expert's width, blocks
+#: of the stacks' gradients `[stream, width]` and `[width, stream]`)
+CELLS = {"mellum2_12b": (131072, 16, 2304, 896, 1, 1),
+         "kimi_linear_48b": (16384, 8, 2304, 1024, 1, 1),
+         "solar_open2_250b": (6556, 8, 4096, 1280, 2, 2)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_cells_grids_and_blocks(cell):
+    """What the shape chose for each cell's products: the Mellum and Kimi
+    shapes sum a stack's gradient whole (one block: PR 32's row tiles and
+    blocks, under a leading grid axis of one), the Solar shape in two
+    halves of ``N``; `_gmm_rows` is one cell a row tile everywhere."""
+    pairs, groups, k, n, blocks, blocks_back = CELLS[cell]
+    assert gmm.weight_blocks(k, n, 2) == blocks
+    assert gmm.weight_blocks(n, k, 2) == blocks_back
+    assert gmm.rows_vmem_bytes(k, n, 2) <= gmm.VMEM_BYTES
+    assert gmm.weights_vmem_bytes(k, n, 2, blocks) <= gmm.VMEM_BYTES
+    if blocks > 1:
+        assert gmm.weights_vmem_bytes(k, n, 2, blocks - 1) > gmm.VMEM_BYTES
+    m = gmm.aligned_rows(pairs, groups, TILE)
+    tiles = m // TILE
+    sizes = jnp.zeros((groups,), jnp.int32).at[0].set(m)
+    for k, n, blocks in ((k, n, blocks), (n, k, blocks_back)):
+        grad = jax.grad(lambda r, s: jnp.sum(gmm.grouped_dot(
+            r, s, sizes).astype(jnp.float32)), (0, 1))
+        calls = _pallas_calls(jax.make_jaxpr(grad)(
+            jax.ShapeDtypeStruct((m, k), jnp.bfloat16),
+            jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16)).jaxpr)
+        assert calls == [
+            ("_gmm_rows", (tiles,), [(TILE, k), (1, k, n), (TILE, n)]),
+            ("_gmm_weights", (blocks, tiles),
+             [(TILE, k), (TILE, n // blocks), (1, k, n // blocks)]),
+            ("_gmm_rows", (tiles,), [(TILE, n), (1, k, n), (TILE, k)])], calls
+
+
+def test_vmem_is_counted_a_kernel():
+    """The Solar shape: `_gmm_rows` fits as it is, the whole float32
+    ``[K, N]`` sum of `_gmm_weights` does not, its halves do."""
+    assert gmm.rows_vmem_bytes(4096, 1280, 2) == 34865152
+    assert gmm.weights_vmem_bytes(4096, 1280, 2, 1) == 72613888
+    assert gmm.weights_vmem_bytes(4096, 1280, 2, 2) == 40501248
+    assert gmm.weights_vmem_bytes(2304, 896, 2, 1) == int(30.75 * 2 ** 20)
+    assert gmm.weights_vmem_bytes(2304, 1024, 2, 1) == int(34.25 * 2 ** 20)
+    assert gmm.weight_blocks(4096, 1280, 2) == 2
 
 
 @pytest.mark.parametrize("groups", [1, 2, 8, 16, 64])
@@ -114,10 +243,14 @@ def test_the_layout_and_the_product_agree_on_who_multiplies(groups):
     """`row_tile` lays the runs out for the kernels exactly where
     `grouped_dot` then takes them: never whole tiles for `ragged_dot`'s sake,
     never runs the kernels cannot take."""
+    taken = set()
     for pairs in list(range(1, 70 * TILE, 97)) + [
             p * TILE + d for p in (8 * groups, 9 * groups) for d in (-1, 0, 1)
-            if p * TILE + d > 0]:
+            if p * TILE + d > 0] + [
+            groups * gmm.PAIRS_A_GROUP + d for d in range(-TILE - 1, TILE + 2)
+            if groups * gmm.PAIRS_A_GROUP + d > 0]:
         tile = gmm.row_tile(pairs, groups, K, N, 2)
+        taken.add(tile)
         rows = jax.ShapeDtypeStruct(
             (gmm.aligned_rows(pairs, groups, tile), K), jnp.bfloat16)
         stack = jax.ShapeDtypeStruct((groups, K, N), jnp.bfloat16)
@@ -125,6 +258,7 @@ def test_the_layout_and_the_product_agree_on_who_multiplies(groups):
         assert gmm.takes_kernels(
             jax.ShapeDtypeStruct(rows.shape[:1] + (N,), jnp.bfloat16),
             jax.ShapeDtypeStruct((groups, N, K), jnp.bfloat16)) == (tile > 1)
+    assert taken == {1, TILE}
 
 
 # -- through the experts' loop ------------------------------------------------

@@ -491,28 +491,38 @@ def test_the_balance_term_matches_the_reference_and_is_1_at_a_uniform_load():
 def test_the_chunk_and_the_products_path_at_8_of_320(caplog):
     """8 of 320 under top-8: a balanced router sends 0.2 pairs a token, the
     chunk is four such loads (6,556 pairs of the cell's 8,192 tokens; 13,108
-    of 16,384); laid out on row tiles it is 8,704 rows, which
-    ops/pallas_gmm.py turns away twice, and says so once a shape."""
+    of 16,384); laid out on row tiles it is 8,704 rows, 820 pairs a group,
+    which ops/pallas_gmm.py takes (PR 36): `_gmm_rows` as it is, the float32
+    ``[4096, 1280]`` sum of `_gmm_weights` in two halves, and says so once a
+    shape.  The Mellum and Kimi shapes keep one block and the same tile."""
     from homebrewnlp_tpu.models.hybrid import expert_chunk
     from homebrewnlp_tpu.ops import pallas_gmm as gmm
     assert expert_chunk(16384, 8, 8, 320) == 13108
     chunk = expert_chunk(8192, 8, 8, 320)
     assert chunk == 6556 == 4 * -(-8192 * 8 * 8 // 320)
     assert gmm.aligned_rows(chunk, 8, gmm.ROW_TILE) == 8704
-    why = gmm.refusals(8704, 8, 4096, 1280, 2)
-    assert len(why) == 2 and "fewer than 9 tiles" in why[0]
-    assert "72613888 bytes of VMEM" in why[1]
-    assert gmm.vmem_bytes(4096, 1280, 2) > gmm.VMEM_BYTES
+    assert not gmm.refusals(8704, 8, 4096, 1280, 2)
+    assert not gmm.refusals(8704, 8, 1280, 4096, 2)
+    assert gmm.rows_vmem_bytes(4096, 1280, 2) == 34865152 < gmm.VMEM_BYTES
+    assert gmm.weights_vmem_bytes(4096, 1280, 2, 1) == 72613888 \
+        > gmm.VMEM_BYTES
+    assert gmm.weight_blocks(4096, 1280, 2) == 2
+    assert gmm.weight_blocks(1280, 4096, 2) == 2
     gmm._say_once.cache_clear()
     with caplog.at_level("INFO"):
-        assert gmm.row_tile(chunk, 8, 4096, 1280, 2) == 1
-        assert gmm.row_tile(chunk, 8, 4096, 1280, 2) == 1
-        assert gmm.row_tile(16384, 8, 2304, 1024, 2) == gmm.ROW_TILE
-    assert caplog.text.count("run as jax.lax.ragged_dot") == 1
+        assert gmm.row_tile(chunk, 8, 4096, 1280, 2) == gmm.ROW_TILE == 256
+        assert gmm.row_tile(chunk, 8, 4096, 1280, 2) == gmm.ROW_TILE
+        # a decoding step's few pairs stay with `ragged_dot`
+        assert gmm.row_tile(64, 8, 4096, 1280, 2) == 1
     assert caplog.text.count("run as the Mosaic kernels") == 1
-    # both accepted cells keep the kernels
-    assert not gmm.refusals(gmm.aligned_rows(131072, 16, 256), 16, 2304, 896,
-                            2)
+    assert "on 8704 rows" in caplog.text and "in 2 and 2 blocks" in caplog.text
+    assert caplog.text.count("run as jax.lax.ragged_dot") == 1
+    # both cells that had the kernels keep them, one block and the same tile
+    for pairs, held, k, n in ((131072, 16, 2304, 896), (16384, 8, 2304, 1024)):
+        assert gmm.row_tile(pairs, held, k, n, 2) == 256
+        assert not gmm.refusals(gmm.aligned_rows(pairs, held, 256), held, k,
+                                n, 2)
+        assert gmm.weight_blocks(k, n, 2) == gmm.weight_blocks(n, k, 2) == 1
 
 
 # -- (e) scopes ---------------------------------------------------------------

@@ -404,11 +404,15 @@ def _prefetch(line: str) -> bool:
             and len({re.sub(r"S\(\d+\)", "", s) for s in shapes}) == 1)
 
 
+@pytest.mark.parametrize("cell", ["mellum2_12b", "solar_open2_250b"])
 def test_expert_layer_gradient_runs_the_grouped_kernels_alone(
-        one_chip, no_compile_cache, monkeypatch):
+        cell, one_chip, no_compile_cache, monkeypatch):
     """`jax.grad` of one `routed_moe` layer at the widths of
     mellum2_12b.train (16 of 64 experts 896 wide on a stream of 2,304, all
-    131,072 pairs of 16,384 tokens in one chunk): Mosaic accepts
+    131,072 pairs of 16,384 tokens in one chunk) and of
+    solar_open2_250b.train (8 of 320 experts 1,280 wide on a stream of
+    4,096, a chunk of 6,556 pairs on 8,704 rows, the stacks' gradients
+    summed in two blocks; PR 36): Mosaic accepts
     ops/pallas_gmm.py's two kernels, they are the loops' only custom calls
     (three `_gmm_rows` in the forward loop; in the backward three more, the
     three transposed ones and three `_gmm_weights`), no `ragged-dot` is
@@ -416,16 +420,18 @@ def test_expert_layer_gradient_runs_the_grouped_kernels_alone(
     stack's size is copied or transposed next to a kernel), and nothing the
     size of a chunk's narrower operand either.  The chunk's rows come back
     to their tokens by gathers (PR 34): no scatter anywhere writes rows of
-    2,304 into an array of a row a token, and neither loop's body holds a
-    float32 array of the chunk's rows (what such a scatter's updates were)."""
+    the stream's width into an array of a row a token, and neither loop's
+    body holds a float32 array of the chunk's rows (what such a scatter's
+    updates were)."""
     import homebrewnlp_tpu.ops as ops
     from homebrewnlp_tpu.models.ctx import Args
+    from homebrewnlp_tpu.models.hybrid import expert_chunk
     from homebrewnlp_tpu.models.registry import LAYER_FUNCTIONS
     from homebrewnlp_tpu.ops.pallas_gmm import ROW_TILE, aligned_rows
     monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with open(os.path.join(REPO, "benchmark", "configs",
-                           "mellum2_12b.json")) as f:
+                           cell + ".json")) as f:
         raw = {k: v for k, v in json.load(f).items() if k != "benchmark"}
     cfg = Config(raw)
     names = ("batch", "sequence", "heads", "features_per_head")
@@ -451,22 +457,30 @@ def test_expert_layer_gradient_runs_the_grouped_kernels_alone(
     hlo = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile().as_text()
     assert not re.search(r" ragged-dot\(", hlo)
     held, inter = cfg.experts_held, cfg.moe_intermediate_size
-    rows = aligned_rows(16384 * 8, held, ROW_TILE)
-    assert not re.findall(r"= f32\[1638[45],2304\]\S* scatter\(", hlo)
+    tokens = cfg.train_batch_size * cfg.sequence_length
+    stream = cfg.heads * cfg.features_per_head
+    topk = int([e for e in spec if e.startswith("topk")][0][len("topk"):])
+    rows = aligned_rows(expert_chunk(tokens, topk, held, cfg.experts), held,
+                        ROW_TILE)
+    assert (tokens, stream, rows) == {
+        "mellum2_12b": (16384, 2304, 135168),
+        "solar_open2_250b": (8192, 4096, 8704)}[cell]
+    assert not re.findall(r"= f32\[(%d|%d),%d\]\S* scatter\("
+                          % (tokens, tokens + 1, stream), hlo)
     found = []
     for body in re.findall(r"^%?[\w.\-]+ \([^\n]*\{\n(.*?)^\}", hlo,
                            re.S | re.M):
         insts = instructions(body)
         if mosaic_calls(insts):
             wide = [line.strip()[:160] for _, _, line in insts.values()
-                    if " = f32[%d,2304]" % rows in line]
+                    if " = f32[%d,%d]" % (rows, stream) in line]
             assert not wide, wide
         for name in mosaic_calls(insts):
             found.append(re.search(r"jit\((_gmm_\w+)\)",
                                    insts[name][2]).group(1))
             assert "bf16[%d," % rows in insts[name][2], insts[name][2][:300]
             moved = [line for line in copies_beside(
-                insts, name, min(rows, held * 2304) * inter)
+                insts, name, min(rows, held * stream) * inter)
                 if not _prefetch(line)]
             assert not moved, (name, moved)
     assert sorted(found) == ["_gmm_rows"] * 9 + ["_gmm_weights"] * 3, found
@@ -524,8 +538,10 @@ def test_solar_open2_step_fits_the_chip_and_keeps_its_kernels(
     gradients and scratch stay under 15.0 GB (at two sequences they read
     17.4 GB, which is why the cell takes one: PERF.md section 4); the delta
     rule and the attention run as their four Mosaic kernels, the attention's
-    take `k`, `v` with the 2 K/V heads held here, and nothing shaped like a
-    score tile is left under `gqa_`."""
+    take `k`, `v` with the 2 K/V heads held here, nothing shaped like a
+    score tile is left under `gqa_`, and the routed products run as the two
+    grouped kernels with no `ragged-dot` left (PR 36: 13.71 GB, 13.68 as
+    `ragged_dot`)."""
     import homebrewnlp_tpu.ops as ops
     from homebrewnlp_tpu.ops.pallas_mla import BLOCK
     monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
@@ -541,8 +557,9 @@ def test_solar_open2_step_fits_the_chip_and_keeps_its_kernels(
     assert 12.5e9 < need < 15.0e9, need
     hlo = compiled.as_text()
     assert sorted(set(re.findall(r'jit\((_\w+)\)[^"]*pallas_call', hlo))) == [
-        "_kda_chunks_bwd", "_kda_chunks_fwd", "_mla_attention_bwd",
-        "_mla_attention_fwd"]
+        "_gmm_rows", "_gmm_weights", "_kda_chunks_bwd", "_kda_chunks_fwd",
+        "_mla_attention_bwd", "_mla_attention_fwd"]
+    assert not re.search(r" ragged-dot\(", hlo)
     kv = "bf16[%d,%d,%d,%d]" % (cfg.train_batch_size, cfg.num_key_value_heads,
                                 cfg.sequence_length, cfg.head_dim)
     attention = [line for line in hlo.splitlines()
