@@ -38,6 +38,9 @@ MIXER_HEADS = "mixer_heads"
 MIXER_KEY = "mixer_key"
 # the K/V heads of grouped-query attention: fewer than its query heads
 KV_HEADS = "kv_heads"
+# the heads and head width of a sparse attention's indexer (sa_config)
+INDEX_HEADS = "index_heads"
+INDEX_KEY = "index_key"
 LATENT = "latent"
 LOW_RANK = "low_rank"
 CONV_TAP = "conv_tap"
@@ -57,7 +60,7 @@ _nd.register_axis(BATCH, SEQUENCE, HEADS, KEY, INTERMEDIATE, VOCAB,
                   TOKEN_PATCH, HEIGHT, WIDTH, COLOR_CHANNELS, EXPERTS,
                   ROUTED_EXPERTS, PKM_AXES, PKM_VALUES, PIPE_STAGE,
                   MIXER_HEADS, MIXER_KEY, KV_HEADS, LATENT, LOW_RANK,
-                  CONV_TAP, EXPERT_INTERMEDIATE)
+                  CONV_TAP, EXPERT_INTERMEDIATE, INDEX_HEADS, INDEX_KEY)
 
 
 def anonymize_name(name: str) -> str:
@@ -79,13 +82,14 @@ DTYPES = {
 # carries beside this repo's keys, for the record of what was published:
 # nothing reads them (the block DSL says the same), so they are no typo
 UPSTREAM_KEYS = frozenset((
-    "attention_bias", "first_k_dense_replace", "gqa_interval", "gqa_layers",
-    "hidden_act", "hidden_size", "intermediate_size", "layer_types",
-    "max_position_embeddings", "max_window_layers", "mla_use_nope",
-    "mlp_layer_types", "model_max_length", "model_type", "moe_layer_freq",
-    "moe_renormalize", "moe_router_activation_func", "n_routed_experts",
-    "n_shared_experts", "norm_topk_prob", "num_expert_group", "num_experts",
-    "num_experts_per_tok", "num_experts_per_token", "num_hidden_layers",
+    "attention_bias", "decoder_sparse_step", "first_k_dense_replace",
+    "gqa_interval", "gqa_layers", "hidden_act", "hidden_size",
+    "intermediate_size", "layer_types", "max_position_embeddings",
+    "max_window_layers", "mla_use_nope", "mlp_layer_types", "mlp_only_layers",
+    "model_max_length", "model_type", "moe_layer_freq", "moe_renormalize",
+    "moe_router_activation_func", "n_routed_experts", "n_shared_experts",
+    "norm_topk_prob", "num_expert_group", "num_experts", "num_experts_per_tok",
+    "num_experts_per_token", "num_hidden_layers", "num_local_experts",
     "num_nextn_predict_layers", "num_shared_experts", "partial_rotary_factor",
     "q_lora_rank", "rope_scaling", "rope_theta", "tie_word_embeddings",
     "topk_group", "use_grouped_topk", "use_sliding_window"))
@@ -362,7 +366,12 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     # false: no rotation at all, and the block part names no table
     # (`gqa-nope`); `use_gqa_gate`: sigmoid(u W_g), one gate a channel of
     # every head, on the attention's result before the output matrix
-    # (`gqa-...-gated`)
+    # (`gqa-...-gated`).  Where `rope_parameters` is not given and
+    # upstream's `rope_scaling` is, `full_attention` takes `rope_theta` and
+    # that entry (`mrope_section`: text positions, one table).  `sa_config`:
+    # upstream's sparse attention ({"indexer_num_heads", "indexer_head_dim",
+    # "indexer_num_kv_heads", "topk", "q_chunk_size", "kv_chunk_size"}),
+    # which a `gqa-...-sparse` layer reads
     num_attention_heads=None,
     num_key_value_heads=None,
     head_dim=None,
@@ -370,6 +379,7 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     sliding_window=None,
     use_rope=True,
     use_gqa_gate=False,
+    sa_config=None,
     # false: the table holds one stream-wide row a token, no factorisation
     factorized_embedding=True,
     # which block_config entries run at which depth: one list of indices a
@@ -918,6 +928,21 @@ class Config:
                     f"loss cannot cross the reversible custom_vjp boundary. "
                     f"Use 'none' or 'checkpoint', or set "
                     f"moe_balance_weight=0 to train without the balance term")
+        # a sparse gqa's indexer learns from its KL loss alone, which rides
+        # ctx.aux_losses as the balance loss does (with the dsa_* counters):
+        # the reversible chain and the pipelined body drop it, and the
+        # indexer would never learn
+        if any(s.split("-")[0] == "gqa" and "sparse" in s.split("-")[1:]
+               for s in body_specs) and (
+                self.memory_reduction_strategy in ("revnet", "momentum")
+                or self.pipeline_parallel > 1):
+            raise ValueError(
+                f"gqa-...-sparse cannot combine with memory_reduction_strategy="
+                f"'{self.memory_reduction_strategy}' or pipeline_parallel="
+                f"{self.pipeline_parallel}: the indexer's KL loss, its only "
+                f"gradient, cannot cross the reversible custom_vjp boundary "
+                f"or the pipeline's stages. Use 'none' or 'checkpoint' "
+                f"without pipeline_parallel")
         if self.weight_standardisation and not self.weight_centralisation:
             self.weight_centralisation = True
         if self.features is None and self.features_per_head is None:
@@ -978,6 +1003,10 @@ class Config:
             raise ValueError(
                 f"experts_held={self.experts_held} from expert_offset="
                 f"{self.expert_offset} is no share of experts={self.experts}")
+        if self.rope_parameters is None and getattr(
+                self, "rope_scaling", None) is not None:
+            self.rope_parameters = {"full_attention": dict(
+                self.rope_scaling, rope_theta=self.rope_theta)}
         if self.kda_use_full_proj:
             raise ValueError(
                 "kda_use_full_proj=true asks for full-rank gate maps in kda; "

@@ -58,8 +58,10 @@ _NO_CACHE_YET = {
            "for the sliding layers beside a whole one for the full layers "
            "(a layer without positions, gqa-nope, writes k as it is; a "
            "gated one reads its gate from the decoded token's own input "
-           "and caches nothing for it); without it every token rebuilds "
-           "the whole sequence",
+           "and caches nothing for it; a sparse one, gqa-...-sparse, also "
+           "the indexer's key of every position, which the decoded token's "
+           "indexer scores to keep its top-k rows); without it every token "
+           "rebuilds the whole sequence",
 }
 
 

@@ -40,6 +40,9 @@ class ModelOutput(typing.NamedTuple):
     expert_load: typing.Optional[jnp.ndarray] = None
     # [routed layers]: rows each layer's grouped products multiplied
     expert_rows: typing.Optional[jnp.ndarray] = None
+    # [sparse attention layers]: kept pairs over causal ones; indexer loss
+    dsa_kept: typing.Optional[jnp.ndarray] = None
+    dsa_kl: typing.Optional[jnp.ndarray] = None
 
 
 # -- input ------------------------------------------------------------------
@@ -213,14 +216,18 @@ def _body(ctx: Ctx, src: NT) -> NT:
                         bctx.scope(_block_scope(i, c)):
                     out = block_part_fn(bctx, conf, x)
                 if with_aux:
-                    # aux losses (routed-MoE balance term) and the experts'
-                    # load returned as real outputs so they cross
-                    # jax.checkpoint (the losses with gradients intact); the
-                    # per-block count is static (set by the block's layer
-                    # specs), so the pytree structure is stable
+                    # aux losses (routed-MoE balance term, the sparse
+                    # attention's indexer loss), the experts' load and the
+                    # sparse attention's counters returned as real outputs
+                    # so they cross jax.checkpoint (the losses with
+                    # gradients intact); the per-block count is static (set
+                    # by the block's layer specs), so the pytree structure
+                    # is stable
                     return out, (tuple(bctx.aux_losses),
                                  tuple(bctx.expert_load),
-                                 tuple(bctx.expert_rows))
+                                 tuple(bctx.expert_rows),
+                                 tuple(bctx.dsa_kept),
+                                 tuple(bctx.dsa_kl))
                 return out
 
             return f
@@ -257,6 +264,8 @@ def _body(ctx: Ctx, src: NT) -> NT:
             ctx.aux_losses.extend(aux[0])
             ctx.expert_load.extend(aux[1])
             ctx.expert_rows.extend(aux[2])
+            ctx.dsa_kept.extend(aux[3])
+            ctx.dsa_kl.extend(aux[4])
         return out
 
 
@@ -621,10 +630,11 @@ def build(ctx: Ctx, batch: typing.Dict[str, NT]) -> ModelOutput:
     total = loss_list[0]
     for l in loss_list[1:]:
         total = total + l
-    load, rows = (jnp.stack(x) if x else None
-                  for x in (ctx.expert_load, ctx.expert_rows))
+    load, rows, kept, kl = (jnp.stack(x) if x else None
+                            for x in (ctx.expert_load, ctx.expert_rows,
+                                      ctx.dsa_kept, ctx.dsa_kl))
     return ModelOutput(total, tuple(loss_list), video_loss, acc, token_loss,
-                       frame_out, token_out, load, rows)
+                       frame_out, token_out, load, rows, kept, kl)
 
 
 def _pipeline_seq(cfg: Config):

@@ -86,6 +86,10 @@ class Ctx:
         # rows each routed layer's grouped products multiply this step
         # (trips of its loop times a chunk's rows), beside expert_load
         self.expert_rows: typing.List[jnp.ndarray] = []
+        # a learned sparse attention's kept pairs over its causal pairs, and
+        # its indexer's loss, one scalar a layer (models/hybrid.py::gqa)
+        self.dsa_kept: typing.List[jnp.ndarray] = []
+        self.dsa_kl: typing.List[jnp.ndarray] = []
         self.param_count = 0
 
     @property

@@ -10,12 +10,15 @@ Six layers of the block DSL, each declared once (ROADMAP D8, R2, R3, R5):
   (``ops/delta_rule.py``);
 - ``mla``: causal softmax attention whose keys and values are expanded from
   one low-rank latent a token, with no positions (``ops/block_attention.py``);
-- ``gqa-<layer type>[-gated]``: causal softmax attention whose query heads
-  share fewer K/V heads, with rotary positions from the layer type's table
-  and, on a ``sliding_attention`` layer, a window (``ops/rotary.py``,
-  ``ops/block_attention.py``); ``gqa-nope[-gated]`` is the layer without
-  positions (``use_rope`` false), ``gated`` the one whose result a sigmoid
-  gate of the layer's input multiplies (``use_gqa_gate``);
+- ``gqa-<layer type>[-gated][-qknorm][-sparse]``: causal softmax attention
+  whose query heads share fewer K/V heads, with rotary positions from the
+  layer type's table and, on a ``sliding_attention`` layer, a window
+  (``ops/rotary.py``, ``ops/block_attention.py``); ``gqa-nope[-gated]`` is
+  the layer without positions (``use_rope`` false), ``gated`` the one whose
+  result a sigmoid gate of the layer's input multiplies (``use_gqa_gate``),
+  ``qknorm`` the one that norms q and k per head, ``sparse`` the one whose
+  rows see only the keys a learned indexer picks (``sa_config``,
+  ``ops/sparse_attention.py``);
 - ``routed_moe[-topk<k>][-sigmoid][-bias][-gated][-shared<n>][-in:<act>]``:
   the one routed expert layer, with nothing dropped (``ops/grouped_ffn.py``).
 
@@ -33,9 +36,9 @@ import jax
 import jax.numpy as jnp
 
 from .. import nd
-from ..config import (CONV_TAP, EXPERT_INTERMEDIATE, INTERMEDIATE, KV_HEADS,
-                      LATENT, LOW_RANK, MIXER_HEADS, MIXER_KEY,
-                      ROUTED_EXPERTS, SEQUENCE)
+from ..config import (CONV_TAP, EXPERT_INTERMEDIATE, INDEX_HEADS, INDEX_KEY,
+                      INTERMEDIATE, KV_HEADS, LATENT, LOW_RANK, MIXER_HEADS,
+                      MIXER_KEY, ROUTED_EXPERTS, SEQUENCE)
 from ..nd import NT
 from ..ops import grouped_ffn as gf
 from ..ops import rotary
@@ -245,28 +248,33 @@ def mla(args: Args) -> NT:
 
 def gqa(args: Args) -> NT:
     """Grouped-query attention.  With rotary positions (``use_rope``, the
-    default) the one extra besides ``gated`` names the layer type, upstream's
+    default) the first extra names the layer type, upstream's
     ``layer_types`` entry, which picks the rotary table
     (``rope_parameters[<layer type>]``) and, for ``sliding_attention``, the
     window (``sliding_window``); without them the part reads ``gqa-nope``:
     no table is built, nothing is rotated, every earlier position is seen.
 
         q = u W_q -> [heads, head_dim];  k, v = u W_k, u W_v -> [kv heads, .]
+        qknorm: q, k = rms_head(q) g_q, rms_head(k) g_k   (over head_dim)
         q, k = rot(q, pos), rot(k, pos)          (ops/rotary.py, float32)
         query head h reads K/V head h // (heads / kv heads)
         o = concat_h softmax(q_h k^T / sqrt(head_dim) + mask) v
         y = o W_o,  gated (use_gqa_gate): y = (o * sigmoid(u W_g)) W_o
-        mask: key <= row, and under a window also row - key < sliding_window
+        mask: key <= row, and under a window also row - key < sliding_window;
+          sparse: key in the row's kept set (``_indexed_attention``)
 
-    No bias and no norm on q or k.  The scale goes into ``q`` with the
-    rotation, or alone, before the one rounding to the stream's type.  The
-    gate is one number a channel of every query head, from the layer's
-    input, in float32.  The part's spelling and the config's two keys say
-    the same layer twice on purpose: a program that knows neither key (it
-    would only warn of them) fails on the spelling at build.
+    No bias.  ``qknorm`` norms q and k per head (Qwen3's decoder, which has
+    no key for it), in float32 and before the rotation.  The scale goes into
+    ``q`` with the rotation, or alone, before the one rounding to the
+    stream's type.  The gate is one number a channel of every query head,
+    from the layer's input, in float32.  ``sparse`` reads ``sa_config``.
+    The part's spelling and the config's keys say the same layer twice on
+    purpose: a program that knows neither key (it would only warn of them)
+    fails on the spelling at build.
     """
     cfg, ctx, u = args.cfg, args.ctx, args.tensor
-    layer_types = [e for e in args.name_extras if e != "gated"]
+    layer_types = [e for e in args.name_extras
+                   if e not in ("gated", "qknorm", "sparse")]
     spelt = "-".join(["gqa"] + args.name_extras)
     if (layer_types == ["nope"]) == cfg.use_rope or (
             "gated" in args) != cfg.use_gqa_gate:
@@ -275,6 +283,10 @@ def gqa(args: Args) -> NT:
             f"{cfg.use_gqa_gate}: a layer without positions is spelt "
             f"gqa-nope and has use_rope false, a gated one ends in -gated "
             f"and has use_gqa_gate true")
+    if ("sparse" in args) != (cfg.sa_config is not None):
+        raise ValueError(f"{spelt} beside sa_config={cfg.sa_config}: a "
+                         f"layer of learned sparse attention ends in -sparse "
+                         f"and has an sa_config")
     width = (MIXER_KEY, cfg.head_dim)
     heads = (MIXER_HEADS, cfg.num_attention_heads or cfg.heads)
     kv_heads = (KV_HEADS, cfg.num_key_value_heads)
@@ -293,17 +305,28 @@ def gqa(args: Args) -> NT:
         q = _project(args, "q_proj", u, fdims, [heads, width])
         k = _project(args, "k_proj", u, fdims, [kv_heads, width])
         v = _project(args, "v_proj", u, fdims, [kv_heads, width])
+        if "qknorm" in args:
+            q, k = (NT(_rms(x.x, normal_var(args, [width], mean=1.0,
+                                            name=f"{n}_norm").x,
+                            cfg.rms_norm_eps), x.names)
+                    for n, x in (("q", q), ("k", k)))
         if not cfg.use_rope:
             q_in = (q.x.astype(jnp.float32) * scale).astype(u.dtype)
-            k_in = k.x
+            k_in = k.x.astype(u.dtype)
     if cfg.use_rope:
         with ctx.scope("rotary"):
             cos, sin = rotary.table(cfg.rope_parameters[layer_types[0]],
                                     width[1], q.dim_size(SEQUENCE))
             q_in = (rotary.rotate(q.x, cos, sin) * scale).astype(u.dtype)
             k_in = rotary.rotate(k.x, cos, sin).astype(u.dtype)
-    with ctx.scope("attention"):
-        o = causal_attention(q_in, k_in, v.x, window=window)
+    if "sparse" in args:
+        if window is not None:
+            raise ValueError(f"{spelt}: a sparse layer's kept set takes no "
+                             f"window")
+        o = _indexed_attention(args, layer_types, q_in, k_in, v.x)
+    else:
+        with ctx.scope("attention"):
+            o = causal_attention(q_in, k_in, v.x, window=window)
     if cfg.use_gqa_gate:
         with ctx.scope("gate"):
             gate = jax.nn.sigmoid(_project(args, "gate_proj", u, fdims,
@@ -313,6 +336,80 @@ def gqa(args: Args) -> NT:
     with ctx.scope("out"):
         return _project(args, "out_proj", NT(o, q.names), [heads, width],
                         fdims).transpose_to(u.names)
+
+
+def _sparse_layers(cfg) -> int:
+    """How many ``gqa-...-sparse`` parts the schedule runs."""
+    def sparse(spec: str) -> bool:
+        name, *extras = spec.split("-")
+        return name == "gqa" and "sparse" in extras
+    return sum(any(sparse(s) for s in cfg.block_config[c].layer)
+               for row in cfg.block_schedule for c in row)
+
+
+def _indexed_attention(args: Args, layer_types, q, k, v):
+    """Attention over the keys a learned indexer picks, DeepSeek Sparse
+    Attention's as ``sa_config`` sizes it (``ops/sparse_attention.py``):
+
+        qI = u' W_qI -> [NI, DI];  kI = rms(u' W_kI) g_kI -> [DI]  (one head)
+        w = (u' W_w) NI^-1/2 DI^-1/2 -> [NI]         u' = u, no gradient
+        qI, kI = rot(qI, pos), rot(kI, pos)  (the layer's table, DI wide)
+        I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])           (s <= t)
+        S_t = the topk s of the largest I[t, s] (every s <= t if fewer)
+        o[t, h] = softmax over s in S_t of q[t, h] . k[s, h // group], of v
+        L_I = mean_t KL(mean_h a[t, h, .] || softmax_{S_t} I[t, .])
+
+    ``L_I`` joins ``ctx.aux_losses`` as the mean over the sparse layers; the
+    heads' mean attention takes no gradient, so the indexer's weights learn
+    from ``L_I`` alone and ``L_I`` teaches nothing else.  Per layer the kept
+    pairs' share of the causal ones and ``L_I`` join ``ctx.dsa_kept`` and
+    ``ctx.dsa_kl`` for the step's counters.  Sub-scopes: ``indexer`` (the
+    projections), ``select`` (the triangle's scores, block by block, and the
+    top-k), ``attention``, ``indexer_loss``.  ``q [B, S, H, D]`` (scaled),
+    ``k``, ``v [B, S, H / group, D]``."""
+    from ..ops import pallas_interpret
+    from ..ops import sparse_attention as sa
+    cfg, ctx, u = args.cfg, args.ctx, args.tensor
+    conf = cfg.sa_config
+    if conf.get("indexer_num_kv_heads", 1) != 1 or (
+            conf["q_chunk_size"] != conf["kv_chunk_size"]):
+        raise ValueError(f"sa_config {conf}: one key head of the indexer and "
+                         f"square tiles are written")
+    heads = (INDEX_HEADS, conf["indexer_num_heads"])
+    width = (INDEX_KEY, conf["indexer_head_dim"])
+    fdims = _fdims(args)
+    seq = u.dim_size(SEQUENCE)
+    block = sa.block_of(seq, conf["q_chunk_size"])
+    interpret = pallas_interpret()
+    f32 = jnp.float32
+    with ctx.scope("indexer"):
+        free = NT(jax.lax.stop_gradient(u.x), u.names)
+        qi = _project(args, "q_proj", free, fdims, [heads, width])
+        ki = _project(args, "k_proj", free, fdims, [width])
+        w = _project(args, "weights_proj", free, fdims, [heads])
+        norm = normal_var(args, [width], mean=1.0, name="k_norm")
+        entry = {k_: v_ for k_, v_ in cfg.rope_parameters[
+            layer_types[0]].items() if k_ != "mrope_section"}
+        cos, sin = rotary.table(entry, width[1], seq)
+        qi = jnp.swapaxes(rotary.rotate(qi.x, cos, sin).astype(u.dtype), 1, 2)
+        ki = rotary.rotate(_rms(ki.x, norm.x, cfg.rms_norm_eps)[:, :, None],
+                           cos, sin)[:, :, 0].astype(u.dtype)
+        w = w.x.astype(f32) * (heads[1] * width[1]) ** -0.5
+    with ctx.scope("select"):
+        mask, lse_i, kept = sa.select(qi, ki, w, conf["topk"], block,
+                                      interpret)
+    q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    with ctx.scope("attention"):
+        o, lse = sa.attention(q, k, v, mask, block, interpret)
+    with ctx.scope("indexer_loss"):
+        kl = sa.indexer_kl(jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
+                           jax.lax.stop_gradient(lse), qi, ki, w, mask, lse_i,
+                           block, interpret)
+        ctx.aux_losses.append(kl / _sparse_layers(cfg))
+        causal = q.shape[0] * seq * (seq + 1) / 2
+        ctx.dsa_kept.append(kept.astype(f32) / causal)
+        ctx.dsa_kl.append(jax.lax.stop_gradient(kl))
+    return jnp.swapaxes(o, 1, 2)
 
 
 # -- routed experts -----------------------------------------------------------

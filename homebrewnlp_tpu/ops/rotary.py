@@ -18,6 +18,12 @@ the angle ``p * inv_freq_i``:
   ``beta_slow`` times, and cos and sin are multiplied by
   ``attention_factor`` (``0.1 ln(factor) + 1`` where the entry gives none).
 
+An entry of upstream's ``rope_scaling`` may name its kind ``type`` and carry
+``mrope_section``: the pairs split between temporal, height and width
+positions (Qwen2-VL's multimodal rotary positions).  For text all three
+positions are the token's index, so the table is the one of the kind; the
+sections must still cover the head's ``d / 2`` pairs exactly.
+
 The frequencies are worked out on the host in numpy's double precision
 while the layer is traced (they depend on the configuration alone) and enter
 the trace as ``d / 2`` float32 constants; angles, cos and sin are float32.
@@ -35,7 +41,12 @@ def inverse_frequencies(conf: dict, dim: int
                         ) -> typing.Tuple[np.ndarray, float]:
     """``(inv_freq [dim / 2] float32, what cos and sin are multiplied by)``
     of one entry of ``rope_parameters``."""
-    kind, theta = conf.get("rope_type", "default"), float(conf["rope_theta"])
+    kind = conf.get("rope_type", conf.get("type", "default"))
+    theta = float(conf["rope_theta"])
+    sections = conf.get("mrope_section")
+    if sections is not None and sum(sections) != dim // 2:
+        raise ValueError(f"mrope_section {sections} covers {sum(sections)} "
+                         f"pairs of a head of {dim}: it must cover {dim // 2}")
     plain = theta ** (-np.arange(0, dim, 2) / dim)
     if kind == "default":
         return plain.astype(np.float32), 1.0

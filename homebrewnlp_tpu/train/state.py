@@ -169,6 +169,12 @@ class Trainer:
                 m["expert_load_max"] = jnp.max(o.expert_load)
                 m["expert_load_mean"] = jnp.mean(
                     o.expert_load.astype(jnp.float32))
+            if o.dsa_kept is not None:
+                # learned sparse attention, a counter a layer: the kept
+                # pairs' share of the causal ones, and the indexer's loss
+                for i in range(o.dsa_kept.shape[0]):
+                    m[f"dsa_kept_pairs/{i}"] = o.dsa_kept[i]
+                    m[f"dsa_indexer_kl/{i}"] = o.dsa_kl[i]
             return m
 
         # device telemetry (obs/device_telemetry.py): in-graph numerics and

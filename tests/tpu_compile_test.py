@@ -570,3 +570,68 @@ def test_solar_open2_step_fits_the_chip_and_keeps_its_kernels(
                           % (BLOCK, BLOCK), line) and "gqa_" in line]
     assert not tiles, tiles
 
+
+
+def _keye_config() -> Config:
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "keye_vl2_30b.json")) as f:
+        return Config({k: v for k, v in json.load(f).items()
+                       if k != "benchmark"})
+
+
+def test_keye_vl2_step_fits_the_chip_and_runs_the_sparse_kernels(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole update of keye_vl2_30b.train (659.2 M parameters, one
+    sequence of 16,384 tokens) compiled for the described v5e: its state,
+    gradients and scratch lie between a quarter of the chip and 95% of it
+    (PR 37: 9.58 GB); every sparse layer runs ops/sparse_attention.py's
+    five Mosaic kernels (the selection, attention forward, its two backward
+    kernels under one jit, the indexer's loss) and the experts' grouped
+    kernels, no dense attention kernel and no sort under `gqa_` (the
+    experts' dispatch sorts its pairs)."""
+    import homebrewnlp_tpu.ops as ops
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = compiled_step(_keye_config(), next(iter(one_chip.device_set)))
+    memory = compiled.memory_analysis()
+    need = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert 0.25 * 16e9 < need < 0.95 * 16e9, need
+    hlo = compiled.as_text()
+    assert sorted(set(re.findall(r'jit\((_\w+)\)[^"]*pallas_call', hlo))) == [
+        "_attention_bwd", "_attention_fwd", "_gmm_rows", "_gmm_weights",
+        "_indexer_kl", "_select"]
+    sorts = [line for line in hlo.splitlines()
+             if re.search(r" sort\(", line) and "gqa_" in line]
+    assert not sorts, sorts[:2]
+
+
+@pytest.mark.parametrize("kernel", ["select", "forward", "backward", "kl"])
+def test_sparse_attention_kernels_take_16384_rows_within_vmem(
+        kernel, one_chip, no_compile_cache):
+    """Mosaic accepts each kernel of ops/sparse_attention.py at the cell's
+    shape (16,384 tokens, 32 query heads of 128 over 4 K/V heads, an indexer
+    of 16 heads of 64, tiles of 512) under the 64 MiB VMEM limit: the key
+    tiles are a grid axis, so nothing a kernel holds grows with the
+    sequence but the indexer loss's float32 key gradient (4 MiB) and the
+    selection's block of 256 rows of keys (16 MiB)."""
+    from homebrewnlp_tpu.ops import sparse_attention as sa
+    b, h, g, t, d, ni, di, blk = 1, 32, 4, 16384, 128, 16, 64, 512
+    s = lambda shape, kind=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, kind, sharding=one_chip)
+    q, kv = s((b, h, t, d)), s((b, g, t, d))
+    qi, ki, w = s((b, ni, t, di)), s((b, t, di)), s((b, t, ni), jnp.float32)
+    mask = s((b, t, t), jnp.int8)
+    lse, lse_i = s((b, g, t, h // g), jnp.float32), s((b, t, 1), jnp.float32)
+    calls = {
+        "select": (lambda *a: sa.select(*a, 2048, blk, False), (qi, ki, w)),
+        "forward": (lambda *a: sa._attention_fwd(*a, block=blk),
+                    (q, kv, kv, mask)),
+        "backward": (lambda *a: sa._attention_bwd(*a, block=blk),
+                     (q, kv, kv, mask, q, lse, q)),
+        "kl": (lambda *a: sa._indexer_kl(*a, block=blk),
+               (q, kv, lse, qi, ki, w, mask, lse_i)),
+    }
+    fn, args = calls[kernel]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
