@@ -406,6 +406,8 @@ def _indexed_attention(args: Args, layer_types, q, k, v):
                            jax.lax.stop_gradient(lse), qi, ki, w, mask, lse_i,
                            block, interpret)
         ctx.aux_losses.append(kl / _sparse_layers(cfg))
+        if cfg.memory_reduction_strategy == "checkpoint":
+            sa.say_kept(sa.kept_bytes(q, v, qi, ki, w), _sparse_layers(cfg))
         causal = q.shape[0] * seq * (seq + 1) / 2
         ctx.dsa_kept.append(kept.astype(f32) / causal)
         ctx.dsa_kl.append(jax.lax.stop_gradient(kl))

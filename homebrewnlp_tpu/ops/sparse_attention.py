@@ -48,15 +48,47 @@ mask tile is read once for the group and no VMEM grows with the sequence.
 Rounding points, the helpers and the VMEM limit are ``ops/pallas_mla.py``'s:
 operands in their own type into float32 sums, the weights rounded to ``v``'s
 type, ``dS`` to ``q``'s.
+
+Under a block part's ``jax.checkpoint`` (``models/__init__.py::_body``) the
+three forward kernels' outputs are named (:data:`SAVED`) and kept, so the
+backward reads them instead of running the selection, the attention's
+forward and the indexer's loss again: the mask, ``lse_i``, ``o``, ``lse`` and
+the indexer's three scaled gradients.
 """
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .pallas_mla import _F32, _NT, _TN, VMEM_BYTES, _dot
+
+#: the names of the forward kernels' outputs a part's checkpoint keeps
+SAVED = ("sparse_select", "sparse_attention", "sparse_indexer_grad")
+
+
+def kept_bytes(q, v, qi, ki, w) -> int:
+    """Bytes of the :data:`SAVED` values of one sparse layer: the mask and
+    ``lse_i``, ``o`` and ``lse``, the gradients by ``qi``, ``ki`` and ``w``
+    (``q [B, H, T, D]``, ``v [B, H / group, T, DV]``, ``qi``, ``ki``, ``w``
+    as :func:`select` takes them)."""
+    b, h, t, _ = q.shape
+    return (b * t * t + 4 * b * t
+            + b * h * t * (v.shape[-1] * v.dtype.itemsize + 4)
+            + sum(x.size * x.dtype.itemsize for x in (qi, ki, w)))
+
+
+@functools.lru_cache(maxsize=None)
+def say_kept(part: int, parts: int) -> None:
+    """Logs once a size what a part's checkpoint keeps of its sparse
+    layer."""
+    logging.getLogger(__name__).info(
+        "a sparse part's checkpoint keeps %d bytes of its forward kernels' "
+        "outputs (%s), %d over %d parts", part, ", ".join(SAVED),
+        part * parts, parts)
 
 
 def block_of(length: int, chunk: int) -> int:
@@ -234,6 +266,7 @@ def select(qi, ki, w, topk: int, block: int, interpret: bool):
         chunk = block
     mask, lse, kept = _select(qi, ki, w, topk=min(topk, t), rows=rows,
                               chunk=chunk, interpret=interpret)
+    mask, lse = checkpoint_name((mask, lse), SAVED[0])
     return mask, lse, jnp.sum(kept).astype(jnp.int32)
 
 
@@ -434,7 +467,11 @@ def attention(q, k, v, mask, block: int, interpret: bool):
 
 
 def _fwd(q, k, v, mask, block, interpret):
-    o, lse = _attention_fwd(q, k, v, mask, block=block, interpret=interpret)
+    # one named value is both the output and the residual: a remat that
+    # keeps it has nothing left to run the kernel for
+    o, lse = checkpoint_name(
+        _attention_fwd(q, k, v, mask, block=block, interpret=interpret),
+        SAVED[1])
     return (o, lse), (q, k, v, mask, o, lse)
 
 
@@ -549,8 +586,9 @@ def _kl_fwd(q, k, lse, qi, ki, w, mask, lse_i, block, interpret):
     kl, dqi, dki, dw = _indexer_kl(q, k, lse, qi, ki, w, mask, lse_i,
                                    block=block, interpret=interpret)
     rows = q.shape[0] * q.shape[2]
-    return kl / rows, tuple((grad / rows).astype(x.dtype) for grad, x in
-                            zip((dqi, dki, dw), (qi, ki, w)))
+    return kl / rows, checkpoint_name(
+        tuple((grad / rows).astype(x.dtype) for grad, x in
+              zip((dqi, dki, dw), (qi, ki, w))), SAVED[2])
 
 
 def _kl_bwd(block, interpret, saved, g):

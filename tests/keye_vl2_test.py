@@ -447,6 +447,119 @@ def test_kernels_match_a_dense_masked_matrix(h, g, t, block, topk):
             np.testing.assert_allclose(x, y, rtol=1e-4, atol=1e-5)
 
 
+@contextlib.contextmanager
+def plain_checkpoint():
+    """`jax.checkpoint` with any policy dropped: every part recomputes its
+    whole forward in the backward, as it did before the sparse attention
+    named what to keep."""
+    real = jax.checkpoint
+    jax.checkpoint = lambda f, policy=None, **kw: real(f, **kw)
+    try:
+        yield
+    finally:
+        jax.checkpoint = real
+
+
+def _token_batch(x):
+    return {"token_x": NT(x[..., None], TOKENS),
+            "token_y": NT(jnp.roll(x, -1, 1)[..., None], TOKENS)}
+
+
+def _loss(cfg, x):
+    from homebrewnlp_tpu.models import build
+
+    def loss(p):
+        ctx = Ctx(cfg, params=p, train=True, rng=jax.random.key(0))
+        return build(ctx, _token_batch(x)).loss
+    return loss
+
+
+def _pallas_calls(jaxpr, counts):
+    """Kernel function name -> `pallas_call`s in `jaxpr` and every jaxpr
+    inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["jaxpr"].debug_info.func_name] += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, counts)
+    return counts
+
+
+@pytest.fixture(scope="module")
+def kept_and_plain():
+    """The toy model's gradient under the per-part checkpoint as it is and
+    under plain `jax.checkpoint(f)`: for each, the kernels its jaxpr holds
+    and the parameters' gradients (interpreted kernels)."""
+    import collections
+    raw = toy()
+    cfg, sz = Config(raw), ref.Sizes.from_config(raw)
+    params = ref.init_weights(sz, 7)
+    x = jnp.asarray(np.random.default_rng(8).integers(32, 123, (2, 64)),
+                    jnp.int32)
+    out = {}
+    for case in ("kept", "plain"):
+        with plain_checkpoint() if case == "plain" else contextlib.nullcontext():
+            grad = jax.grad(_loss(cfg, x))     # a new function: no trace kept
+            jaxpr = jax.make_jaxpr(grad)(params).jaxpr
+            out[case] = (_pallas_calls(jaxpr, collections.Counter()),
+                         jax.jit(grad)(params))
+    return out
+
+
+@pytest.mark.parametrize("kernel", ["_select_kernel", "_fwd_kernel",
+                                    "_kl_kernel"])
+def test_the_checkpoint_keeps_the_sparse_forward_kernels_outputs(
+        kernel, kept_and_plain):
+    """Each of the three forward kernels runs once a sparse layer in the
+    gradient (plain `jax.checkpoint` runs it again in the remat pass: twice),
+    the backward's two kernels once either way, and the parameters'
+    gradients are plain's bit for bit: the kept values are the arrays the
+    remat would recompute."""
+    (kept, got), (plain, want) = kept_and_plain["kept"], kept_and_plain["plain"]
+    layers = 2
+    assert kept[kernel] == layers and plain[kernel] == 2 * layers, (kept,
+                                                                    plain)
+    for backward in ("_dq_kernel", "_dkv_kernel"):
+        assert kept[backward] == plain[backward] == layers
+    assert sorted(got) == sorted(want)
+    for name in want:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+def _gradient_stablehlo(raw):
+    """StableHLO of the gradient of the toy's loss, locations stripped,
+    the parameters given as shapes."""
+    cfg = Config(raw)
+    x = jnp.zeros((cfg.train_batch_size, cfg.sequence_length), jnp.int32)
+
+    def collect():
+        from homebrewnlp_tpu.models import build
+        ctx = Ctx(cfg, params=None, seed=0, train=False)
+        build(ctx, _token_batch(x))
+        return ctx.collected
+
+    params = jax.eval_shape(collect)
+    return jax.jit(jax.grad(_loss(cfg, x))).lower(params).as_text()
+
+
+@pytest.mark.parametrize("module", ["mellum2_test", "solar_open2_test",
+                                    "kimi_linear_test"])
+def test_parts_without_a_sparse_layer_lower_as_under_plain_checkpoint(module):
+    """The toy of each cell that runs the per-part checkpoint without a
+    sparse attention: its gradient lowers to the same StableHLO under the
+    policy as under plain `jax.checkpoint(f)`, since none of its layers
+    names a value to keep."""
+    import importlib
+    raw = importlib.import_module(f"tests.{module}").toy()
+    kept = _gradient_stablehlo(raw)
+    with plain_checkpoint():
+        plain = _gradient_stablehlo(raw)
+    assert kept == plain
+
+
 @pytest.mark.parametrize("seed", [0, 1])
 def test_selection_breaks_ties_as_top_k(seed):
     """Scores of small whole numbers tie everywhere: the kernel's bisection

@@ -584,11 +584,14 @@ def test_keye_vl2_step_fits_the_chip_and_runs_the_sparse_kernels(
     """The whole update of keye_vl2_30b.train (659.2 M parameters, one
     sequence of 16,384 tokens) compiled for the described v5e: its state,
     gradients and scratch lie between a quarter of the chip and 95% of it
-    (PR 37: 9.58 GB); every sparse layer runs ops/sparse_attention.py's
-    five Mosaic kernels (the selection, attention forward, its two backward
+    (13.16 GB; 9.58 when the remat ran the forward kernels again); every
+    sparse layer runs ops/sparse_attention.py's five Mosaic kernels (the
+    selection, attention forward, its two backward
     kernels under one jit, the indexer's loss) and the experts' grouped
     kernels, no dense attention kernel and no sort under `gqa_` (the
-    experts' dispatch sorts its pairs)."""
+    experts' dispatch sorts its pairs).  The part's checkpoint keeps the
+    three forward kernels' outputs, so each runs once a layer: 6 calls, not
+    the 12 of a remat that runs them again."""
     import homebrewnlp_tpu.ops as ops
     monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -601,6 +604,10 @@ def test_keye_vl2_step_fits_the_chip_and_runs_the_sparse_kernels(
     assert sorted(set(re.findall(r'jit\((_\w+)\)[^"]*pallas_call', hlo))) == [
         "_attention_bwd", "_attention_fwd", "_gmm_rows", "_gmm_weights",
         "_indexer_kl", "_select"]
+    calls = re.findall(r'jit\((_\w+)\)[^"]*pallas_call', "\n".join(
+        line for line in hlo.splitlines() if "tpu_custom_call" in line))
+    for kernel in ("_select", "_attention_fwd", "_indexer_kl"):
+        assert calls.count(kernel) == 6, (kernel, calls.count(kernel))
     sorts = [line for line in hlo.splitlines()
              if re.search(r" sort\(", line) and "gqa_" in line]
     assert not sorts, sorts[:2]
