@@ -85,14 +85,15 @@ UPSTREAM_KEYS = frozenset((
     "attention_bias", "decoder_sparse_step", "first_k_dense_replace",
     "gqa_interval", "gqa_layers", "hidden_act", "hidden_size",
     "intermediate_size", "layer_types", "max_position_embeddings",
-    "max_window_layers", "mla_use_nope", "mlp_layer_types", "mlp_only_layers",
+    "max_window_layers", "mlp_layer_types", "mlp_only_layers",
     "model_max_length", "model_type", "moe_layer_freq", "moe_renormalize",
-    "moe_router_activation_func", "n_routed_experts", "n_shared_experts",
-    "norm_topk_prob", "num_expert_group", "num_experts", "num_experts_per_tok",
-    "num_experts_per_token", "num_hidden_layers", "num_local_experts",
-    "num_nextn_predict_layers", "num_shared_experts", "partial_rotary_factor",
-    "q_lora_rank", "rope_scaling", "rope_theta", "tie_word_embeddings",
-    "topk_group", "use_grouped_topk", "use_sliding_window"))
+    "moe_router_activation_func", "n_group", "n_routed_experts",
+    "n_shared_experts", "norm_topk_prob", "num_expert_group", "num_experts",
+    "num_experts_per_tok", "num_experts_per_token", "num_hidden_layers",
+    "num_local_experts", "num_nextn_predict_layers", "num_shared_experts",
+    "partial_rotary_factor", "q_lora_rank", "qk_head_dim", "rope_scaling",
+    "rope_theta", "scoring_func", "tie_word_embeddings", "topk_group",
+    "topk_method", "use_grouped_topk", "use_sliding_window"))
 
 
 @dataclasses.dataclass
@@ -352,11 +353,18 @@ _DEFAULTS: typing.Dict[str, typing.Any] = dict(
     linear_attn_config=None,
     kda_allow_neg_eigval=False,
     kda_use_full_proj=False,
-    # mla (latent K/V attention, no positions)
+    # mla (latent K/V attention over `num_attention_heads` heads, None =
+    # heads): `mla_use_nope` true is the layer without positions (`mla`),
+    # false the one whose decoupled query and key parts are turned by the
+    # default rotary table at `rope_theta` (`mla-rope`), in pairs
+    # (x_2i, x_2i+1) under `rope_interleave`, else rotate-half; upstream's
+    # names and defaults (DeepSeek-V3 has no `mla_use_nope` and rotates)
     kv_lora_rank=None,
     qk_nope_head_dim=None,
     qk_rope_head_dim=None,
     v_head_dim=None,
+    mla_use_nope=False,
+    rope_interleave=True,
     # gqa (softmax attention over grouped K/V heads, rotary positions), as
     # upstream names them: `num_attention_heads` query heads (None = heads)
     # of `head_dim` read `num_key_value_heads` K/V heads; `rope_parameters`

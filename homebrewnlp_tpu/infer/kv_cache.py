@@ -50,8 +50,10 @@ _NO_CACHE_YET = {
            "it every token rebuilds the whole sequence",
     "mla": "decoding needs a latent cache (the normed latent and the shared "
            "key part of every position, with the up-projection absorbed "
-           "into q and the output); without it every token rebuilds the "
-           "whole sequence",
+           "into q and the output; under mla-rope the shared key part "
+           "rotated at its own position when it is written, and the "
+           "decoded token's q_pe at its offset); without it every token "
+           "rebuilds the whole sequence",
     "gqa": "decoding needs a cache of the K/V heads alone (k and v of "
            "num_key_value_heads heads a position, k rotated at its own "
            "offset when it is written), a ring of sliding_window positions "

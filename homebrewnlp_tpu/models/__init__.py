@@ -18,7 +18,7 @@ from .. import nd
 from ..config import (BATCH, COLOR_CHANNELS, Config, HEADS, HEIGHT, INTERMEDIATE,
                       KEY, SEQUENCE, TOKEN_PATCH, VOCAB, WIDTH)
 from ..nd import NT
-from ..ops import sparse_attention
+from ..ops import pallas_mla, sparse_attention
 from ..ops.losses import accuracy as _accuracy_fn
 from ..ops.losses import softmax_cross_entropy_with_logits, video_l1_loss
 from ..ops.reversible import make_reversible_chain
@@ -257,10 +257,12 @@ def _body(ctx: Ctx, src: NT) -> NT:
             return y1 + y2
         fs = [make_f(k, i, c, with_aux=True) for k, (i, c) in enumerate(seq)]
         # a part recomputes its forward in the backward but for the values
-        # its layers name to keep (only a sparse attention names any: its
-        # forward kernels' outputs, ops/sparse_attention.py)
+        # its layers name to keep (only a sparse attention names any, its
+        # forward kernels' outputs, ops/sparse_attention.py; and attention
+        # that walks its keys in cells, the forward's output and row
+        # statistic, ops/pallas_mla.py)
         keep = jax.checkpoint_policies.save_only_these_names(
-            *sparse_attention.SAVED)
+            *sparse_attention.SAVED, pallas_mla.KEPT)
         out = src
         for f, p in zip(fs, subparams):
             if strategy == "checkpoint":

@@ -8,8 +8,10 @@ Six layers of the block DSL, each declared once (ROADMAP D8, R2, R3, R5):
 - ``kda``: the gated delta-rule mixer with a decay for every channel, short
   causal convolutions on q, k and v, and a gated norm on its output
   (``ops/delta_rule.py``);
-- ``mla``: causal softmax attention whose keys and values are expanded from
-  one low-rank latent a token, with no positions (``ops/block_attention.py``);
+- ``mla[-rope]``: causal softmax attention whose keys and values are
+  expanded from one low-rank latent a token, with no positions, or with
+  rotary positions on the decoupled query and key parts (``-rope``;
+  ``ops/rotary.py``, ``ops/block_attention.py``);
 - ``gqa-<layer type>[-gated][-qknorm][-sparse]``: causal softmax attention
   whose query heads share fewer K/V heads, with rotary positions from the
   layer type's table and, on a ``sliding_attention`` layer, a window
@@ -214,34 +216,79 @@ def kda(args: Args) -> NT:
 # -- mla ----------------------------------------------------------------------
 
 def mla(args: Args) -> NT:
-    """Latent K/V attention without positions, for training (K and V are
-    expanded from the latent; the absorbed form is a serving matter):
+    """Latent K/V attention, for training (K and V are expanded from the
+    latent; the absorbed form is a serving matter):
 
-        q = u W_q -> [heads, nope + rope]
-        c = u W_kva;  c_kv = rms(c[:rank]),  k_pe = c[rank:]   (no rotation)
+        q = u W_q -> [heads, nope + rope]:  q_nope, q_pe
+        c = u W_kva;  c_kv = rms(c[:rank]),  k_pe = c[rank:]   (one a token)
+        mla-rope: q_pe, k_pe = rot(q_pe, pos), rot(k_pe, pos)  (once a token)
         [k_nope, v] = c_kv W_kvb;  k_h = [k_nope_h, k_pe]
         y = concat_h softmax(q_h k_h^T / sqrt(nope + rope) + causal) v_h W_o
+
+    ``mla`` has no positions (``mla_use_nope`` true); ``mla-rope`` turns the
+    decoupled parts by the default rotary table at ``rope_theta``, in
+    ``(x_2i, x_2i+1)`` pairs under ``rope_interleave`` (``ops/rotary.py``,
+    float32; the scale goes into ``q`` before its one rounding), the shared
+    ``k_pe`` once, before it is broadcast over the heads.  The part's
+    spelling and ``mla_use_nope`` say the same thing twice on purpose, as
+    ``gqa``'s and ``use_rope`` do.  Sub-scopes of the trace (not of the
+    parameters' names): ``proj``, ``rotary``, ``attention`` (the softmax
+    alone, ``ops/block_attention.py``), ``out``.
     """
-    cfg, ctx, u = args.cfg, args.ctx, args.tensor
+    cfg, u = args.cfg, args.tensor
+    spelt = "-".join(["mla"] + args.name_extras)
+    if args.name_extras not in ([], ["rope"]) or (
+            not args.name_extras) != cfg.mla_use_nope:
+        raise ValueError(
+            f"{spelt} beside mla_use_nope={cfg.mla_use_nope}: latent "
+            f"attention without positions is spelt mla and has mla_use_nope "
+            f"true, with rotated decoupled keys mla-rope and mla_use_nope "
+            f"false")
+    rotated = not cfg.mla_use_nope
+    if rotated and (getattr(cfg, "rope_theta", None) is None
+                    or cfg.rope_parameters is not None):
+        raise ValueError(f"{spelt} turns its decoupled keys by the default "
+                         f"table at rope_theta: it needs rope_theta and "
+                         f"takes no rope_scaling or rope_parameters")
     nope, rope, v_dim, rank = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                                cfg.v_head_dim, cfg.kv_lora_rank)
-    heads = (MIXER_HEADS, cfg.heads)
+    heads = (MIXER_HEADS, cfg.num_attention_heads or cfg.heads)
     fdims = _fdims(args)
     kind = u.x.dtype
-    q = _project(args, "q_proj", u, fdims, [heads, (MIXER_KEY, nope + rope)])
-    c = _project(args, "kv_down", u, fdims, [(LATENT, rank + rope)])
-    scale = normal_var(args, [(LATENT, rank)], mean=1.0, name="latent_norm")
-    c_kv = NT(_rms(c.x[..., :rank], scale.x, cfg.rms_norm_eps).astype(kind),
-              c.names)
-    kv = _project(args, "kv_up", c_kv, [(LATENT, rank)],
-                  [heads, (MIXER_KEY, nope + v_dim)])
-    k_pe = jnp.broadcast_to(c.x[..., None, rank:],
-                            kv.x.shape[:-1] + (rope,))
-    k = jnp.concatenate([kv.x[..., :nope], k_pe], -1)
-    o = causal_attention(q.x * (nope + rope) ** -0.5, k, kv.x[..., nope:])
-    return _project(args, "out_proj", NT(o, kv.names), [heads,
-                                                        (MIXER_KEY, v_dim)],
-                    fdims).transpose_to(u.names)
+    scale = (nope + rope) ** -0.5
+    with jax.named_scope("proj"):
+        q = _project(args, "q_proj", u, fdims,
+                     [heads, (MIXER_KEY, nope + rope)])
+        c = _project(args, "kv_down", u, fdims, [(LATENT, rank + rope)])
+        norm = normal_var(args, [(LATENT, rank)], mean=1.0,
+                          name="latent_norm")
+        c_kv = NT(_rms(c.x[..., :rank], norm.x, cfg.rms_norm_eps
+                       ).astype(kind), c.names)
+        kv = _project(args, "kv_up", c_kv, [(LATENT, rank)],
+                      [heads, (MIXER_KEY, nope + v_dim)])
+        k_pe = c.x[..., None, rank:]
+        q_in, k_nope_v = q.x, kv.x
+        if not rotated:
+            q_in = q_in * scale
+    if rotated:
+        with jax.named_scope("rotary"):
+            cos, sin = rotary.table({"rope_theta": cfg.rope_theta}, rope,
+                                    q.dim_size(SEQUENCE))
+            q_pe = rotary.rotate(q_in[..., nope:], cos, sin,
+                                 cfg.rope_interleave)
+            q_in = (jnp.concatenate([q_in[..., :nope].astype(jnp.float32),
+                                     q_pe], -1) * scale).astype(kind)
+            k_pe = rotary.rotate(k_pe, cos, sin, cfg.rope_interleave
+                                 ).astype(kind)
+    with jax.named_scope("proj"):
+        k = jnp.concatenate([k_nope_v[..., :nope], jnp.broadcast_to(
+            k_pe, k_nope_v.shape[:-1] + (rope,))], -1)
+    with jax.named_scope("attention"):
+        o = causal_attention(q_in, k, k_nope_v[..., nope:])
+    with jax.named_scope("out"):
+        return _project(args, "out_proj", NT(o, kv.names),
+                        [heads, (MIXER_KEY, v_dim)], fdims
+                        ).transpose_to(u.names)
 
 
 # -- gqa ----------------------------------------------------------------------

@@ -28,7 +28,9 @@ than ``MIN_TRACE_S`` is counted in the registry and not kept; nearly all of
 them lie inside their caller's record anyway.
 
 The same numbers go to the registry (``hbnlp_jax_*``, ``hbnlp_recompiles_
-total{fun}``) and, with an ambient tracer, into ``trace.json`` as retroactive
+total{fun}``; and ``hbnlp_attention_path_total{path}``, the walk of each
+causal-attention call, which ``ops/block_attention.py`` emits as it is
+traced) and, with an ambient tracer, into ``trace.json`` as retroactive
 ``jax/<kind>`` spans on the building thread's track, where they nest by
 containment under the program span that caused them (docs/observability.md
 "Set-up and compiles").
@@ -50,6 +52,9 @@ KINDS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
 CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
                 "/jax/compilation_cache/cache_misses": "miss"}
 RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: ``ops/block_attention.py::WALK_EVENT``: a causal-attention call traced,
+#: with the walk it takes as ``path``
+WALK_EVENT = "/hbnlp/attention/walk"
 MAX_RECORDS = 4096
 MIN_TRACE_S = 1e-4
 
@@ -101,8 +106,15 @@ class CompileLog:
         self._recompiles = registry.counter(
             "hbnlp_recompiles_total",
             "builds of a function this process had built before", ("fun",))
+        self._walks = registry.counter(
+            "hbnlp_attention_path_total",
+            "causal attention calls traced, by the walk they take "
+            "(ops/block_attention.py::walk)", ("path",))
 
-    def on_event(self, event: str, **_) -> None:
+    def on_event(self, event: str, **kwargs) -> None:
+        if event == WALK_EVENT:
+            self._walks.labels(path=kwargs["path"]).inc()
+            return
         cache = CACHE_EVENTS.get(event)
         if cache is not None:
             self._inside.cache = cache

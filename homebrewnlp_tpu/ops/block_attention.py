@@ -12,31 +12,43 @@ head ``h`` reads K/V head ``h // group``) and whose sliding layers pass a
 ``window``: a row then sees its last ``window`` positions alone, itself
 among them, and only the key tiles that meet that band are computed.
 
-What runs where is decided by the operands' shape alone
-(:func:`takes_kernels`; any group and any window run on either path).  A
-sequence of whole blocks of 512 at head widths of whole or half lane tiles
-(``kimi_linear_48b``: 8,192 tokens, keys 192 wide, values 128;
-``mellum2_12b``: 8,192 tokens, 32 query heads over 4 K/V heads, 128 wide,
-window 1,024 on three layers of four) runs as the two Mosaic kernels of
-``ops/pallas_mla.py``,
+What runs where is decided by the operands' shape alone (:func:`walk`;
+any group and any window run on every path), counted as each call is
+traced (``WALK_EVENT``, which ``obs/compile_log.py`` counts as
+``hbnlp_attention_path_total{path}`` in the registry) and said in the log
+once a shape.  A sequence of whole blocks of 512 at head widths of
+whole or half lane tiles runs as Mosaic kernels of ``ops/pallas_mla.py``,
 forward and backward of one ``custom_vjp``: a tile's scores, running maximum
 and sum, exponentials and weights stay in VMEM, only ``q``, ``k``, ``v``, the
 output and one float32 statistic a row (the log of its sum of exponentials)
-cross HBM, and the backward recomputes each tile from them.  Their rounding
-points are ``_tile``'s: scores from operands in their own type added up in
-float32; mask, maximum, exponentials, sum and the output accumulator float32;
-the weights rounded to ``v``'s type before the second product; the output
-rounded once at the end; in the backward the weights, ``dS`` and ``dO``
-enter their products in the operands' type and every gradient is summed in
-float32 and rounded once (PERF.md, PR 30: 14.6 ms a forward and 44.2 forward
-and backward at the cell's shape, against 49.2 and 164.4 for the tiles).
+cross HBM, and the backward recomputes each tile from them.  Two walks:
+
+- ``resident`` where a head's keys, values and their gradients fit VMEM
+  whole (``pallas_mla.vmem_bytes``: up to 10,752 tokens at the latent
+  attention's 192 / 128 widths; ``kimi_linear_48b``: 8,192 tokens, keys 192
+  wide, values 128; ``mellum2_12b``: 8,192 tokens, 32 query heads over 4
+  K/V heads, 128 wide, window 1,024 on three layers of four): a cell a block
+  of rows, the head's keys held over its blocks (on one v5e, 14.6 ms a
+  forward and 44.2 forward and backward at the Kimi cell's shape, against
+  49.2 and 164.4 for the tiles);
+- ``key_blocks`` past that (``kanana2_30b``: 32,768 tokens): the grid walks
+  a head's keys ``pallas_mla.KEYS`` at a time, the forward and ``dQ`` a
+  block of rows against its cells of keys, ``dK`` and ``dV`` a cell of keys
+  against its blocks of rows; nothing held grows with the sequence.
+
+Both round where ``_tile`` does: scores from operands in their own type
+added up in float32; mask, maximum, exponentials, sum and the output
+accumulator float32; the weights rounded to ``v``'s type before the second
+product; the output rounded once at the end; in the backward the weights,
+``dS`` and ``dO`` enter their products in the operands' type and every
+gradient is summed in float32 and rounded once.
 
 Every other shape (the toy configurations, odd sequence lengths, narrow
-heads) takes ``rows x rows`` tiles unrolled in XLA, which are also the
-kernels' oracle: each tile is recomputed in the backward, so only the
-running triple outlives it, and the blocks' key counts differ, so the tiles
-are unrolled: ``S / rows`` blocks of rows, ``(S / rows + 1) / 2`` tiles each
-on average, every one through HBM several times.
+heads) takes ``rows x rows`` tiles unrolled in XLA (``unrolled``), which
+are also the kernels' oracle: each tile is recomputed in the backward, so
+only the running triple outlives it, and the blocks' key counts differ, so
+the tiles are unrolled: ``S / rows`` blocks of rows, ``(S / rows + 1) / 2``
+tiles each on average, every one through HBM several times.
 
 Square tiles are what the chip's compiler handles well in that form: with
 the softmax taken over whole rows of 8,192 keys it ran the row maximum and
@@ -46,12 +58,21 @@ forward of one layer at 2 x 8,192 tokens, 32 heads; my chip run, PR 27).
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 
 from ..nd import einsum_f32
-from .pallas_mla import BLOCK, VMEM_BYTES, flash_attention, vmem_bytes
+from .pallas_mla import (BLOCK, VMEM_BYTES, flash_attention,
+                         key_block_attention, key_chunk, vmem_bytes)
+
+#: the walks :func:`causal_attention` can take, by :func:`walk`
+WALKS = ("resident", "key_blocks", "unrolled")
+#: the ``jax.monitoring`` event each call emits as it is traced, with its
+#: walk as ``path``; ``obs/compile_log.py`` counts it in the registry as
+#: ``hbnlp_attention_path_total{path}``
+WALK_EVENT = "/hbnlp/attention/walk"
 
 
 @functools.partial(jax.checkpoint, static_argnums=(4, 5))
@@ -85,12 +106,49 @@ def _tile(carry, q, k, v, ahead: int, window=None):
 
 def takes_kernels(q, v) -> bool:
     """Whether ``ops/pallas_mla.py``'s kernels run this shape: the sequence a
-    whole number of their blocks (so a tile fills the matrix unit), the head
-    widths whole or half lane tiles, and a head's keys, values and their
-    gradients within the kernels' VMEM."""
+    whole number of their blocks (so a tile fills the matrix unit) and the
+    head widths whole or half lane tiles."""
     s, d, d_v = q.shape[1], q.shape[-1], v.shape[-1]
-    return (s % BLOCK == 0 and d % 64 == 0 and d_v % 64 == 0
-            and vmem_bytes(s, d, d_v, q.dtype.itemsize) <= VMEM_BYTES)
+    return s % BLOCK == 0 and d % 64 == 0 and d_v % 64 == 0
+
+
+def walk(q, v) -> str:
+    """Which of :data:`WALKS` this shape takes: the kernels that hold a
+    head's keys, values and their gradients in VMEM where those fit
+    (``pallas_mla.vmem_bytes``), else the kernels that walk the keys in
+    cells through the grid; the unrolled tiles where no kernel runs."""
+    if not takes_kernels(q, v):
+        return "unrolled"
+    s, d, d_v = q.shape[1], q.shape[-1], v.shape[-1]
+    if vmem_bytes(s, d, d_v, q.dtype.itemsize) <= VMEM_BYTES:
+        return "resident"
+    return "key_blocks"
+
+
+def _count(path: str, q, k, v, window) -> None:
+    """The walk of one call, emitted as it is traced, and said once a
+    shape."""
+    jax.monitoring.record_event(WALK_EVENT, path=path)
+    _say_once(path, tuple(q.shape), tuple(k.shape), tuple(v.shape),
+              str(q.dtype), window)
+
+
+@functools.lru_cache(maxsize=None)
+def _say_once(path: str, q, k, v, dtype: str, window) -> None:
+    what = (f"causal attention q {list(q)} k {list(k)} v {list(v)} {dtype}"
+            f"{'' if window is None else f' window {window}'}")
+    if path == "unrolled":
+        logging.getLogger(__name__).warning(
+            "%s runs as unrolled tiles in XLA: the sequence is no whole "
+            "number of %d-blocks or a head width no multiple of 64", what,
+            BLOCK)
+    elif path == "key_blocks":
+        logging.getLogger(__name__).info(
+            "%s runs as the key-block Mosaic kernels, %d keys a cell", what,
+            key_chunk(q[1]))
+    else:
+        logging.getLogger(__name__).info(
+            "%s runs as the resident Mosaic kernels", what)
 
 
 def causal_attention(q, k, v, rows: int = 1024, window=None):
@@ -98,13 +156,18 @@ def causal_attention(q, k, v, rows: int = 1024, window=None):
     ``k [B, S, H / group, D]`` and ``v [B, S, H / group, Dv]``: a row sees
     the keys up to itself and, with ``window``, only the last ``window`` of
     them; ``rows`` is the unrolled tiles' size."""
-    kernels = takes_kernels(q, v)
+    path = walk(q, v)
+    _count(path, q, k, v, window)
     q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    if kernels:
-        from . import pallas_interpret
-        out = flash_attention(q, k, v, BLOCK, window, pallas_interpret())
-    else:
+    if path == "unrolled":
         out = _unrolled_tiles(q, k, v, rows, window)
+    else:
+        from . import pallas_interpret
+        if path == "resident":
+            out = flash_attention(q, k, v, BLOCK, window, pallas_interpret())
+        else:
+            out = key_block_attention(q, k, v, BLOCK, key_chunk(q.shape[2]),
+                                      window, pallas_interpret())
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -1,8 +1,9 @@
 """Rotary positions: the table of a layer type and the rotation itself.
 
 A head of width ``d`` is ``d / 2`` pairs ``(x_i, x_{i + d/2})`` (rotate-half
-over the whole head); pair ``i`` of the token at position ``p`` is turned by
-the angle ``p * inv_freq_i``:
+over the whole head), or, interleaved, ``(x_2i, x_2i+1)`` (``rotate``'s
+``interleaved``: ``mla-rope`` under ``rope_interleave``); pair ``i`` of the
+token at position ``p`` is turned by the angle ``p * inv_freq_i``:
 
     rot(x, p) = x * cos(p f) + [-x_hi, x_lo] * sin(p f)
 
@@ -78,10 +79,17 @@ def table(entry: dict, dim: int, length: int):
     return jnp.cos(angle) * factor, jnp.sin(angle) * factor
 
 
-def rotate(x, cos, sin):
+def rotate(x, cos, sin, interleaved: bool = False):
     """``x [B, S, H, d]`` (any float type) turned by ``cos, sin [S, d / 2]``;
-    float32."""
+    float32.  ``interleaved``: the pairs are ``(x_2i, x_2i+1)`` (upstream's
+    ``rope_interleave``), and the result holds the turned pairs rotate-half
+    wise, ``[y_0, y_2, .., y_d-2, y_1, y_3, .., y_d-1]``, as upstream's
+    DeepSeek-V3 returns them: a query and a key turned alike keep their dot
+    product, which is all attention reads."""
     x = x.astype(jnp.float32)
+    if interleaved:
+        x = jnp.swapaxes(x.reshape(x.shape[:-1] + (-1, 2)), -1, -2).reshape(
+            x.shape)
     low, high = jnp.split(x, 2, -1)
     cos, sin = cos[:, None], sin[:, None]
     return jnp.concatenate([low * cos - high * sin, high * cos + low * sin],

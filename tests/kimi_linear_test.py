@@ -61,6 +61,7 @@ def toy(**over):
         linear_attn_config={"num_heads": 4, "head_dim": 8,
                             "short_conv_kernel_size": 4},
         kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+        mla_use_nope=True,
         tpu_size=1,
         calculation_dtype="float32", slice_dtype="float32",
         storage_dtype="float32", optimizer_slice_dtype="bfloat16",

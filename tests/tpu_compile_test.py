@@ -642,3 +642,87 @@ def test_sparse_attention_kernels_take_16384_rows_within_vmem(
     fn, args = calls[kernel]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def test_kanana2_step_fits_the_chip_through_the_key_block_kernels(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole update of kanana2_30b.train (575.96 M parameters, one
+    sequence of 32,768 tokens) compiled for the described v5e: its state,
+    gradients and scratch lie between a quarter of the chip and 95% of it
+    (13.00 GB; 11.64 when the remat ran the forward kernel again); every
+    one of its five latent-attention layers runs ops/pallas_mla.py's
+    key-block kernels (a head's keys do not fit VMEM whole at this length),
+    counted as they are traced, with no call left to
+    the unrolled tiles or the resident kernels, and nothing shaped like a
+    score tile is left under `mla_`; the experts run as the grouped
+    kernels."""
+    import homebrewnlp_tpu.ops as ops
+    from homebrewnlp_tpu.obs import compile_log
+    from homebrewnlp_tpu.obs.registry import REGISTRY
+    from homebrewnlp_tpu.ops.block_attention import WALKS
+    from homebrewnlp_tpu.ops.pallas_mla import BLOCK
+    compile_log.install()
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "kanana2_30b.json")) as f:
+        cfg = Config({k: v for k, v in json.load(f).items()
+                      if k != "benchmark"})
+    counter = REGISTRY.counter("hbnlp_attention_path_total",
+                               labelnames=("path",))
+    before = {p: counter.value(path=p) for p in WALKS}
+    compiled = compiled_step(cfg, next(iter(one_chip.device_set)))
+    walked = {p: counter.value(path=p) - before[p] for p in WALKS}
+    # the abstract trace of the initialiser and the update's own trace
+    assert walked == {"resident": 0, "key_blocks": 2 * 5, "unrolled": 0}
+    memory = compiled.memory_analysis()
+    need = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert 0.25 * 16e9 < need < 0.95 * 16e9, need
+    hlo = compiled.as_text()
+    assert sorted(set(re.findall(r'jit\((_\w+)\)[^"]*pallas_call', hlo))) == [
+        "_gmm_rows", "_gmm_weights", "_key_blocks_bwd", "_key_blocks_fwd"]
+    calls = re.findall(r'jit\((_\w+)\)[^"]*pallas_call', "\n".join(
+        line for line in hlo.splitlines() if "tpu_custom_call" in line))
+    # a forward a layer (the part's checkpoint keeps its output and row
+    # statistic, so the remat does not run it again); the backward as two
+    # kernels (dQ, then dK and dV) under one jit
+    assert calls.count("_key_blocks_fwd") == 5
+    assert calls.count("_key_blocks_bwd") == 2 * 5
+    tiles = [line.strip()[:160] for line in hlo.splitlines()
+             if re.search(r"= \(?f32\[\d+,\d+,(%d,%d|1024,1024)\]"
+                          % (BLOCK, BLOCK), line) and "mla_" in line]
+    assert not tiles, tiles
+
+
+@pytest.mark.parametrize("h,g,window", [(32, 32, None), (32, 4, 1024)],
+                         ids=["latent", "grouped_window"])
+def test_key_block_kernels_take_32768_rows_within_vmem(h, g, window,
+                                                       one_chip,
+                                                       no_compile_cache):
+    """Mosaic accepts the key-block kernels' forward and backward at 32,768
+    tokens (the latent attention's 192 / 128 widths; grouped K/V heads of
+    128 under a window), where the resident kernels' VMEM need (167 MiB)
+    passes the 64 MiB limit: a cell holds `KEYS` keys and nothing grows
+    with the sequence."""
+    from homebrewnlp_tpu.ops import pallas_mla
+    s = 32768
+    d, d_v = (192, 128) if g == h else (128, 128)
+    assert pallas_mla.vmem_bytes(s, d, d_v, 2) > pallas_mla.VMEM_BYTES
+    shape = lambda *x: jax.ShapeDtypeStruct(x, jnp.bfloat16,
+                                            sharding=one_chip)
+    keys = pallas_mla.key_chunk(s)
+
+    def loss(q, k, v):
+        return jnp.sum(pallas_mla.key_block_attention(
+            q, k, v, pallas_mla.BLOCK, keys, window, False
+        ).astype(jnp.float32))
+
+    hlo = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shape(1, h, s, d), shape(1, g, s, d), shape(1, g, s, d_v)
+    ).compile().as_text()
+    calls = re.findall(r'jit\((_\w+)\)[^"]*pallas_call', "\n".join(
+        line for line in hlo.splitlines() if "tpu_custom_call" in line))
+    # the forward, then the backward's two kernels (dQ; dK and dV)
+    assert sorted(calls) == ["_key_blocks_bwd", "_key_blocks_bwd",
+                             "_key_blocks_fwd"], calls
